@@ -246,7 +246,6 @@ def step_hlo(fn, arg_structs, group: int = 0, compiled: bool = False) -> str:
     from horovod_tpu.core import context as _ctx
     from horovod_tpu.core.state import AXIS_NAME
     from horovod_tpu.ops import collectives as _coll
-    from horovod_tpu.utils import jax_compat as _compat
 
     grp = hvd.get_group(group)
     structs = [jax.ShapeDtypeStruct((grp.size,) + tuple(a.shape), a.dtype)
@@ -257,7 +256,7 @@ def step_hlo(fn, arg_structs, group: int = 0, compiled: bool = False) -> str:
             out = fn(*[a[0] for a in args])
         return jnp.asarray(out).reshape(-1)[:1]
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh,
         in_specs=tuple(P(AXIS_NAME) for _ in structs),
         out_specs=P(AXIS_NAME), check_vma=False))

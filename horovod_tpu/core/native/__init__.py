@@ -6,50 +6,69 @@ register (XLA provides the data plane), so a single ctypes binding carries
 the whole native surface: request table + validation, fusion planning, stall
 detection, and the timeline writer.
 
-The library is compiled lazily with g++ on first import and cached next to
-the source; if no toolchain is available the callers fall back to the pure
-Python implementations (core/negotiate.py, ops/fusion.py), which implement
+The library is compiled lazily with g++ on first use and cached next to
+the source under a name that carries the source's hash (``_so_path``); if
+no toolchain is available the callers fall back to the pure Python
+implementations (core/negotiate.py, ops/fusion.py), which implement
 identical semantics and produce byte-identical error messages.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "hvd_core.cc")
-_SO = os.path.join(_HERE, "_hvd_core.so")
+_CXX = ["g++", "-std=c++17", "-O2", "-fPIC", "-shared"]
 
 _build_lock = threading.Lock()
 _lib = None
 _load_failed = False
 
 
-def _build() -> bool:
-    """Compile hvd_core.cc → _hvd_core.so if missing or stale."""
+def _so_path() -> str:
+    """``_hvd_core.<hash>.so`` — the hash covers hvd_core.cc and the
+    compile command, so the only binary this module ever opens is one
+    built from the source that sits next to it. A stale or foreign
+    ``_hvd_core*.so`` (copied along with a working tree, left by another
+    checkout) has another name and is never loaded."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_HERE, f"_hvd_core.{h.hexdigest()[:16]}.so")
+
+
+def _build() -> str | None:
+    """Path of the library built from the current hvd_core.cc, compiling
+    it if that exact build is not there yet; None when it cannot be."""
     try:
-        if (os.path.exists(_SO)
-                and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return True
-        cmd = ["g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-o", _SO, _SRC]
-        res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+        so = _so_path()
+        if os.path.exists(so):
+            return so
+        # Compile beside the target and rename: a concurrent process
+        # (multi-host tests) never opens a half-written library.
+        tmp = f"{so}.{os.getpid()}.tmp"
+        res = subprocess.run(_CXX + ["-o", tmp, _SRC], capture_output=True,
+                             text=True, timeout=120)
         if res.returncode != 0:
             import warnings
 
             warnings.warn(
                 f"hvd_core native build failed, using pure-Python control "
                 f"plane: {res.stderr[-500:]}")
-            return False
-        return True
+            return None
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError) as e:
         import warnings
 
         warnings.warn(f"hvd_core native build unavailable ({e}); using "
                       f"pure-Python control plane.")
-        return False
+        return None
 
 
 def _load():
@@ -57,11 +76,12 @@ def _load():
     with _build_lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not _build():
+        so = _build()
+        if so is None:
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as e:
             import warnings
 

@@ -403,6 +403,27 @@ def fusion_threshold() -> int:
     return _require_init().fusion_threshold
 
 
+def target_device() -> jax.Device:
+    """The device compiled programs are built for: rank 0 of the world
+    ``hvd.init`` was given, else JAX's first default device (code that
+    runs without a runtime — a bare kernel call, the serving engine).
+
+    Every implementation choice that depends on the hardware (compiled or
+    interpreted Pallas, flash or blockwise attention, device or host
+    clock) asks HERE and not ``jax.default_backend()``: a process whose
+    default backend is the CPU can build programs for TPU devices (AOT
+    topologies, ``hvd.init(devices=...)``), and a choice keyed on the
+    process would silently compile the CPU fallbacks into them."""
+    if _state.initialized and _state.devices:
+        return _state.devices[0]
+    return jax.devices()[0]
+
+
+def target_platform() -> str:
+    """``target_device().platform`` — ``"tpu"``, ``"cpu"``, ..."""
+    return target_device().platform
+
+
 # ---------------------------------------------------------------------------
 # Rank/size queries: the ctypes surface of the reference (mpi_ops.cc:1905-2001).
 # On TPU a "rank" is a device; the per-process eager answer is the rank of the

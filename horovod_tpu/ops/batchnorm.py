@@ -35,7 +35,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.utils import jax_compat as _compat
+from horovod_tpu.core import state as _state
+
 
 
 def _pick_block(n: int, c: int) -> int:
@@ -90,7 +91,7 @@ def channel_sums(x, interpret: bool | None = None):
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _state.target_platform() != "tpu"
         if interpret:
             # Interpreter is too slow for real sizes; the math is 2 reduces.
             xf = x2.astype(jnp.float32)
@@ -104,7 +105,7 @@ def channel_sums(x, interpret: bool | None = None):
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((2, c), jnp.float32)],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
@@ -157,7 +158,7 @@ def channel_grad_sums(dy, x, mean, rstd, interpret: bool | None = None):
         dy2 = jnp.pad(dy2, ((0, pad), (0, 0)))
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _state.target_platform() != "tpu"
         if interpret:
             dyf = dy2.astype(jnp.float32)
             xhat = (x2.astype(jnp.float32) - mean) * rstd
@@ -174,7 +175,7 @@ def channel_grad_sums(dy, x, mean, rstd, interpret: bool | None = None):
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((2, c), jnp.float32)],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,

@@ -55,7 +55,6 @@ from horovod_tpu.core import state as _state
 from horovod_tpu.core.state import AXIS_NAME, HorovodError
 from horovod_tpu.ops import compression as _compression
 from horovod_tpu.ops import strategy as _strategy
-from horovod_tpu.utils import jax_compat as _compat
 
 _name_counters: dict[str, int] = {}  # next index per op-type prefix
 _name_lock = threading.Lock()
@@ -181,7 +180,7 @@ def _validate(xs, op: _neg.CollectiveOp, name: str, g: _state.Group,
 def _psum_fn(mesh_key, ndim: int):
     group = _state.get_group(mesh_key)
     spec = P(AXIS_NAME, *([None] * ndim))
-    f = _compat.shard_map(
+    f = jax.shard_map(
         lambda x: lax.psum(x, AXIS_NAME),
         mesh=group.mesh, in_specs=spec, out_specs=spec)
     return jax.jit(f)
@@ -201,7 +200,7 @@ def _alltoall_device_fn(mesh_key, ndim: int):
                            tiled=True)
         return y[None]
 
-    return jax.jit(_compat.shard_map(f, mesh=group.mesh, in_specs=spec,
+    return jax.jit(jax.shard_map(f, mesh=group.mesh, in_specs=spec,
                                  out_specs=spec, check_vma=False))
 
 
@@ -215,7 +214,7 @@ def _allgather_fn(mesh_key, ndim: int):
         g = lax.all_gather(x, AXIS_NAME)  # (size, 1, *shape)
         return jnp.squeeze(g, axis=1)
 
-    return jax.jit(_compat.shard_map(f, mesh=group.mesh, in_specs=in_spec,
+    return jax.jit(jax.shard_map(f, mesh=group.mesh, in_specs=in_spec,
                                  out_specs=out_spec, check_vma=False))
 
 

@@ -38,7 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.utils import jax_compat as _compat
+from horovod_tpu.core import state as _state
 
 _NEG_INF = -1e30
 # lse padding for query rows beyond Tq: exp(s - 1e30) == 0, so padded rows
@@ -58,7 +58,7 @@ _LN2 = math.log(2.0)
 # intermediates; the 48 MB budget admits the 2048×2048 default blocks
 # (32 MB of score tiles — the r4 device-timed optimum on v5e), where the
 # 16 MB default scoped budget stopped at 1024×1024.
-_FWD_SEMANTICS = _compat.tpu_compiler_params(
+_FWD_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=48 * 1024 * 1024)
 
@@ -66,18 +66,17 @@ _FWD_SEMANTICS = _compat.tpu_compiler_params(
 def _small_vmem_chip() -> bool:
     """TPU v2/v3 cores have 16 MB VMEM — the 2048×2048 forward default
     (32 MB of fp32 score tiles) cannot allocate there; v4+ carry 128 MB."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # uninitialized/exotic backends: be conservative
-        return True
-    return ("v2" in kind or "v3" in kind) and "tpu" in (
-        jax.default_backend() or "")
+    dev = _state.target_device()
+    kind = dev.device_kind.lower()
+    return dev.platform == "tpu" and ("v2" in kind or "v3" in kind)
+
+
 # bwd grid (b, kv-mem-block, q-head, q-block): dk/dv accumulate across
 # (q-head-in-group, q-block); the kv dimension reuses the scratch buffers.
 # The fused kernel's resident K/V block + two kv-sized fp32 accumulators
 # need more than the conservative 16 MB default scoped-vmem budget; v5e
 # has 128 MB physical VMEM.
-_BWD_SEMANTICS = _compat.tpu_compiler_params(
+_BWD_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
     vmem_limit_bytes=100 * 1024 * 1024)
 
@@ -748,7 +747,7 @@ def _resolve(sm_scale, interpret, d):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _state.target_platform() != "tpu"
     return sm_scale, interpret
 
 

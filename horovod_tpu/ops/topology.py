@@ -51,28 +51,50 @@ class Link:
     gbps: float
 
 
-# Seed constants by chip generation (substring-matched on device_kind,
-# longest key first — the bench.py _chip_peak_tflops convention). ICI
-# numbers are ring busbw per chip derived from public per-chip aggregate
-# interconnect specs; DCN is a conservative per-host figure. They only
-# need to be right enough to ORDER the algorithms; --calibrate measures
-# the real ones.
-_ICI_SEED = {
-    "v4": Link(alpha_us=1.0, gbps=100.0),
-    "v5 lite": Link(alpha_us=1.0, gbps=90.0),
-    "v5e": Link(alpha_us=1.0, gbps=90.0),
-    "v5litepod": Link(alpha_us=1.0, gbps=90.0),
-    "v5p": Link(alpha_us=1.0, gbps=180.0),
-    "v5": Link(alpha_us=1.0, gbps=180.0),
-    "v6e": Link(alpha_us=1.0, gbps=180.0),
-    "v6 lite": Link(alpha_us=1.0, gbps=180.0),
+@dataclasses.dataclass(frozen=True)
+class ChipSpec:
+    """What the repo knows about one TPU generation: the published bf16
+    peak (the MFU denominator) and the ICI seed link of the α–β model."""
+
+    peak_bf16_tflops: float
+    ici: Link
+
+
+# THE chip table, substring-matched on ``device_kind`` (longest key
+# first). Peaks are the public per-chip bf16 spec-sheet figures (Google
+# Cloud TPU documentation). ICI numbers are ring busbw per chip derived
+# from public per-chip aggregate interconnect specs; they only need to be
+# right enough to ORDER the algorithms — ``--calibrate`` measures the
+# real ones. A TPU that is not in this table is an error, never a
+# default: a guessed peak or link rate would print as if measured.
+_V5E = ChipSpec(197.0, Link(alpha_us=1.0, gbps=90.0))
+_V6E = ChipSpec(918.0, Link(alpha_us=1.0, gbps=180.0))
+_V5P = ChipSpec(459.0, Link(alpha_us=1.0, gbps=180.0))
+_CHIP_SPECS = {
+    "v4": ChipSpec(275.0, Link(alpha_us=1.0, gbps=100.0)),
+    "v5 lite": _V5E, "v5e": _V5E, "v5litepod": _V5E,
+    "v5p": _V5P, "v5": _V5P,
+    "v6e": _V6E, "v6 lite": _V6E,
 }
-_ICI_DEFAULT_TPU = Link(alpha_us=1.0, gbps=90.0)
 # CPU-simulated meshes: "bandwidth" is host memcpy; the numbers exist so
 # the cost model stays total-ordered during harness validation (ICI
 # faster than DCN, as on every real TPU topology), nothing more.
 _ICI_CPU = Link(alpha_us=5.0, gbps=20.0)
 _DCN_SEED = Link(alpha_us=25.0, gbps=12.5)
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """The :class:`ChipSpec` of a TPU ``device_kind``; an unknown kind
+    raises — add the chip's published figures to ``_CHIP_SPECS``."""
+    kind = device_kind.lower()
+    for key in sorted(_CHIP_SPECS, key=len, reverse=True):
+        if key in kind:
+            return _CHIP_SPECS[key]
+    raise HorovodError(
+        f"TPU device_kind {device_kind!r} is not in the chip table "
+        f"(ops/topology.py _CHIP_SPECS: {sorted(_CHIP_SPECS)}). Add its "
+        f"published bf16 peak and ICI seed there — an unknown chip gets "
+        f"no default peak or link rate.")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,11 +131,7 @@ class Topology:
 def _ici_link(device_kind: str, platform: str) -> Link:
     if platform != "tpu":
         return _ICI_CPU
-    kind = device_kind.lower()
-    for key in sorted(_ICI_SEED, key=len, reverse=True):
-        if key in kind:
-            return _ICI_SEED[key]
-    return _ICI_DEFAULT_TPU
+    return chip_spec(device_kind).ici
 
 
 def seed_links(device_kind: str) -> tuple[Link, Link]:

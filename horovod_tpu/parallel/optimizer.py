@@ -36,7 +36,6 @@ from horovod_tpu.ops import topology as _topology
 from horovod_tpu.tune import apply as _tune_apply
 from horovod_tpu.utils import costs as _costs
 from horovod_tpu.utils import env as _env
-from horovod_tpu.utils import jax_compat as _compat
 
 
 class ErrorFeedbackState(typing.NamedTuple):
@@ -265,7 +264,7 @@ def allreduce_gradients(grads, group: int = 0, average: bool = True,
 
     is_sparse = lambda leaf: isinstance(leaf, _sparse.IndexedSlices)
     leaves, treedef = jax.tree.flatten(grads, is_leaf=is_sparse)
-    paths = [_compat.keystr_simple(p, separator="/")
+    paths = [jax.tree_util.keystr(p, simple=True, separator="/")
              for p, _ in jax.tree_util.tree_flatten_with_path(
                  grads, is_leaf=is_sparse)[0]]
     dense_idx = [i for i, l in enumerate(leaves) if not is_sparse(l)]
@@ -847,7 +846,7 @@ def _fsdp_resolve_comp(compression):
 
 
 def _fsdp_labels(tree, is_leaf=None):
-    return [_compat.keystr_simple(p, separator="/")
+    return [jax.tree_util.keystr(p, simple=True, separator="/")
             for p, _ in jax.tree_util.tree_flatten_with_path(
                 tree, is_leaf=is_leaf)[0]]
 

@@ -249,7 +249,7 @@ def ring_attention(q, k, v, group=0, causal: bool = True,
         raise HorovodError(f"Unknown ring_attention layout {layout!r}.")
     if layout == "zigzag":
         if impl == "auto":
-            impl = "flash" if jax.default_backend() == "tpu" else "blockwise"
+            impl = "flash" if _state.target_platform() == "tpu" else "blockwise"
         if impl not in ("flash", "blockwise"):
             raise HorovodError(f"Unknown ring_attention impl {impl!r}.")
         if block_k is not None:
@@ -267,7 +267,7 @@ def ring_attention(q, k, v, group=0, causal: bool = True,
     if impl == "auto":
         # An explicit block_k is a blockwise-tuning request; otherwise the
         # pallas kernel wins on TPU.
-        if block_k is not None or jax.default_backend() != "tpu":
+        if block_k is not None or _state.target_platform() != "tpu":
             impl = "blockwise"
         else:
             impl = "flash"
@@ -727,7 +727,7 @@ def local_attention(q, k, v, causal: bool = True,
         if t <= 2048:
             impl = "xla"
         else:
-            impl = "flash" if jax.default_backend() == "tpu" else "blockwise"
+            impl = "flash" if _state.target_platform() == "tpu" else "blockwise"
 
     if impl == "flash":
         return _fa.flash_attention(q, k, v, causal, sm_scale,

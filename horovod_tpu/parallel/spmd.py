@@ -22,6 +22,7 @@ import functools
 from typing import Callable
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -31,7 +32,6 @@ from horovod_tpu.core import state as _state
 from horovod_tpu.core import timeline as _timeline
 from horovod_tpu.core.state import AXIS_NAME, HorovodError
 from horovod_tpu.utils import env as _env
-from horovod_tpu.utils import jax_compat as _compat
 
 
 def spmd(fn: Callable, group: int = 0,
@@ -70,6 +70,52 @@ def spmd(fn: Callable, group: int = 0,
     # flip would silently serve executables built under the old options.
     xla_opts = _env.xla_compiler_options()
 
+    def build(g, nargs):
+        """``(jitted, schedule)``: the program for ``nargs`` arguments over
+        ``g``'s mesh, and the list its trace fills with the collective
+        schedule."""
+        in_specs = tuple(P() if i in repl else P(AXIS_NAME)
+                         for i in range(nargs))
+        # Trace-time collective schedule, captured for multi-host
+        # validation (the analog of per-tensor negotiation, hoisted to
+        # compile time — see core/multihost.py).
+        schedule: list = []
+
+        def shard_fn(*sargs):
+            rank_view = []
+            for i, a in enumerate(sargs):
+                if i in repl:
+                    rank_view.append(a)
+                else:
+                    # shard_map hands each device a (1, *s) slice; present
+                    # the natural per-rank shape (*s) to the user function.
+                    rank_view.append(jax.tree.map(lambda t: t[0], a))
+            with _ctx.enter(AXIS_NAME, group) as tctx:
+                out = fn(*rank_view)
+            schedule.clear()
+            for nm, meta in tctx.names.items():
+                op, dtype, shape, grp, root = meta
+                # Group families register as tuples; serialize as lists
+                # so the JSON round-trip compares clean across processes.
+                grp = grp if isinstance(grp, int) else list(grp)
+                # Trailing element: fusion-bucket member labels (empty
+                # for plain collectives) — deterministic from the traced
+                # gradient pytree, so multi-host schedule validation
+                # still compares byte-identical payloads.
+                schedule.append([nm, op, dtype, list(shape), grp,
+                                 -1 if root is None else root,
+                                 list(tctx.members.get(nm, ()))])
+            return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
+
+        # check_vma=False: jax 0.9's varying-manual-axes checker does not
+        # support axis_index_groups (parallel.py bind_psum_invariant),
+        # which grouped collectives — the fork's core feature — depend on.
+        jitted = jax.jit(jax.shard_map(
+            shard_fn, mesh=g.mesh, in_specs=in_specs,
+            out_specs=P(AXIS_NAME), check_vma=False),
+            donate_argnums=tuple(donate_argnums))
+        return jitted, schedule
+
     @functools.wraps(fn)
     def wrapper(*args):
         g = _state.get_group(group)
@@ -96,48 +142,7 @@ def spmd(fn: Callable, group: int = 0,
                 del compiled[stale]
                 schedules.pop(stale, None)
                 device_exec_count.pop(stale, None)
-            in_specs = tuple(P() if i in repl else P(AXIS_NAME)
-                             for i in range(len(args)))
-            # Trace-time collective schedule, captured for multi-host
-            # validation (the analog of per-tensor negotiation, hoisted to
-            # compile time — see core/multihost.py).
-            schedule: list = []
-
-            def shard_fn(*sargs):
-                rank_view = []
-                for i, a in enumerate(sargs):
-                    if i in repl:
-                        rank_view.append(a)
-                    else:
-                        # shard_map hands each device a (1, *s) slice; present
-                        # the natural per-rank shape (*s) to the user function.
-                        rank_view.append(jax.tree.map(lambda t: t[0], a))
-                with _ctx.enter(AXIS_NAME, group) as tctx:
-                    out = fn(*rank_view)
-                schedule.clear()
-                for nm, meta in tctx.names.items():
-                    op, dtype, shape, grp, root = meta
-                    # Group families register as tuples; serialize as lists
-                    # so the JSON round-trip compares clean across processes.
-                    grp = grp if isinstance(grp, int) else list(grp)
-                    # Trailing element: fusion-bucket member labels (empty
-                    # for plain collectives) — deterministic from the traced
-                    # gradient pytree, so multi-host schedule validation
-                    # still compares byte-identical payloads.
-                    schedule.append([nm, op, dtype, list(shape), grp,
-                                     -1 if root is None else root,
-                                     list(tctx.members.get(nm, ()))])
-                import jax.numpy as jnp
-
-                return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
-
-            # check_vma=False: jax 0.9's varying-manual-axes checker does not
-            # support axis_index_groups (parallel.py bind_psum_invariant),
-            # which grouped collectives — the fork's core feature — depend on.
-            jitted = jax.jit(_compat.shard_map(
-                shard_fn, mesh=g.mesh, in_specs=in_specs,
-                out_specs=P(AXIS_NAME), check_vma=False),
-                donate_argnums=tuple(donate_argnums))
+            jitted, schedule = build(g, len(args))
             tag = f"{getattr(fn, '__qualname__', 'fn')}/{len(args)}"
             # HOROVOD_XLA_OPTIONS (e.g. pinning the CRS combiner to the
             # framework's fusion buckets for comm/compute overlap —
@@ -205,6 +210,17 @@ def spmd(fn: Callable, group: int = 0,
             return out
         return compiled[key](*args)
 
+    def lower(*args):
+        """``jax.stages.Lowered`` of the program ``wrapper(*args)`` runs —
+        for inspection (``.compile().as_text()``, memory analysis), as
+        ``jax.jit(f).lower`` is. A fresh trace: the wrapper's program cache
+        and the auto-name counters are left as they were."""
+        from horovod_tpu.ops import collectives as _coll
+
+        with _coll.preserve_auto_names():
+            return build(_state.get_group(group), len(args))[0].lower(*args)
+
+    wrapper.lower = lower
     return wrapper
 
 
@@ -263,58 +279,51 @@ def _args_signature(args):
 
 def _global_from_local_rows(g, local_rows_per_leaf):
     """Assemble a (g.size, *s) global array from this process's per-local-rank
-    rows: row i lives on group device i; non-addressable rows are provided by
-    the other processes' identical calls."""
+    rows: row i is put on group device i and nowhere else, so no device
+    ever holds the whole stack. Non-addressable rows (multi-host) are
+    provided by the other processes' identical calls."""
     lranks = g.local_member_ranks()
+    sharding = NamedSharding(g.mesh, P(AXIS_NAME))
 
     def build(*rows):  # one row per local member rank, natural shape (*s)
-        rows = [np.asarray(r) for r in rows]
-        shape = (g.size,) + rows[0].shape
-        sharding = NamedSharding(g.mesh, P(AXIS_NAME))
-        shards = [jax.device_put(rows[j][None], g.devices[i])
-                  for j, i in enumerate(lranks)]
+        # replicate() passes ONE row len(lranks) times: lift it once.
+        lifted = {id(r): jnp.asarray(r)[None] for r in rows}
+        shards = [jax.device_put(lifted[id(r)], g.devices[i])
+                  for r, i in zip(rows, lranks)]
         return jax.make_array_from_single_device_arrays(
-            shape, sharding, shards)
+            (g.size,) + shards[0].shape[1:], sharding, shards)
 
     return jax.tree.map(build, *local_rows_per_leaf)
 
 
 def rank_stack(values, group: int = 0):
-    """Stack a per-rank list into the leading rank axis expected by ``spmd``.
+    """Stack a per-rank list into the leading rank axis expected by ``spmd``,
+    each row placed on its rank's device.
 
     Single-controller: ``values`` has one entry per group rank. Multi-host:
     one entry per rank THIS process drives (``hvd.local_member_ranks``
     order); the result is a global array spanning all hosts.
     """
-    import jax.numpy as jnp
-
-    if _mh.active():
-        g = _state.get_group(group)
-        if len(values) != len(g.local_member_ranks()):
-            raise HorovodError(
-                f"rank_stack: expected one value per local member rank "
-                f"({len(g.local_member_ranks())}), got {len(values)}.")
-        return _global_from_local_rows(g, values)
-    return jax.tree.map(lambda *leaves: jnp.stack(leaves, axis=0), *values)
+    g = _state.get_group(group)
+    nloc = len(g.local_member_ranks())
+    if len(values) != nloc:
+        raise HorovodError(
+            f"rank_stack: expected one value per local member rank "
+            f"({nloc}), got {len(values)}.")
+    return _global_from_local_rows(g, values)
 
 
 def replicate(value, group: int = 0):
     """Tile a single pytree into the rank-stacked layout (g, ...) — one
-    replica per device once sharded, the DP parameter layout. In multi-host
+    replica per device, the DP parameter layout, built in place: each
+    device receives its own copy and none holds g of them. In multi-host
     mode every process must call this with the same value; the result is a
     global array."""
-    import jax.numpy as jnp
-
     g = _state.get_group(group)
-    if _mh.active():
-        nloc = len(g.local_member_ranks())
-        if nloc == 0:
-            return value  # no local members: nothing to place
-        return jax.tree.map(
-            lambda t: _global_from_local_rows(g, [t] * nloc), value)
-    return jax.tree.map(
-        lambda t: jnp.broadcast_to(jnp.asarray(t)[None],
-                                   (g.size,) + jnp.asarray(t).shape), value)
+    nloc = len(g.local_member_ranks())
+    if nloc == 0:
+        return value  # no local members: nothing to place
+    return _global_from_local_rows(g, [value] * nloc)
 
 
 def device_put_ranked(value, group: int = 0):

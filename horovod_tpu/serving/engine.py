@@ -498,9 +498,9 @@ EngineStalled` instead of hanging; ``min_accept``
             return pools, first, t1 - t0
 
         # Pools are donated so XLA updates the cache in place instead of
-        # double-buffering it every token (CPU ignores donation with a
-        # warning, so gate it).
-        donate = () if jax.default_backend() == "cpu" else (1,)
+        # double-buffering it every token — on every backend, so the
+        # tests run the same aliasing the chip does.
+        donate = (1,)
         self._decode = jax.jit(decode_fn, donate_argnums=donate)
         self._prefill = jax.jit(prefill_fn, donate_argnums=donate)
 

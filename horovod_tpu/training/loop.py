@@ -490,12 +490,15 @@ class Trainer(LRControlMixin):
 
     def _adapt_batch(self, batch):
         """Slice a full-world rank-stacked batch down to the rows of the
-        current (post-shrink) membership. Identity at full world."""
+        current (post-shrink) membership and place them on its mesh (the
+        full-world stack lives on the full world's devices). Identity at
+        full world."""
         rows = getattr(self, "_elastic_rows", None)
         if rows is None:
             return batch
         idx = np.asarray(rows)
-        return jax.tree.map(lambda t: t[idx], batch)
+        return hvd.device_put_ranked(
+            jax.tree.map(lambda t: t[idx], batch), self.group)
 
     def _elastic_shrink(self, err: _res.WorkerLost) -> None:
         """Execute the pre-verified shrink contract in-process: snapshot

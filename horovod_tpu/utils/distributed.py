@@ -28,12 +28,7 @@ def init_distributed(coordinator_address: str | None = None,
     call when the distributed service is already up (re-initialization is
     skipped, matching InitializeHorovodOnce semantics).
     """
-    try:
-        already = jax.distributed.is_initialized()  # jax >= 0.4.34
-    except AttributeError:
-        already = getattr(
-            jax._src.distributed.global_state, "client", None) is not None
-    if not already:
+    if not jax.distributed.is_initialized():
         kwargs = {}
         if coordinator_address is not None:
             kwargs["coordinator_address"] = coordinator_address

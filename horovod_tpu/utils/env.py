@@ -858,12 +858,14 @@ def apply_platform_overrides() -> None:
 
     The launcher-agnostic analog of the reference's ``mpirun -np N`` test
     worlds (SURVEY §4): a TPU-less machine gets an N-device SPMD mesh via
-    XLA host devices. We use our own env var because plugin registration in
-    some containers rewrites ``JAX_PLATFORMS`` at interpreter start, making
-    that variable unreliable as a statement of user intent. A no-op when
-    unset or < 1. Applied at ``import horovod_tpu`` time, so it takes
-    precedence over earlier ``jax.config`` calls in the same process — unset
-    the variable if that is not what you want.
+    XLA host devices (``jax_platforms=cpu`` + ``jax_num_cpu_devices=N``).
+    One variable carries both settings, so a test world never depends on
+    ``JAX_PLATFORMS`` and ``XLA_FLAGS`` agreeing with each other. A no-op
+    when unset or < 1 — JAX then picks its own default backend, which on a
+    machine with a chip is the TPU. Applied at ``import horovod_tpu``
+    time, so it takes precedence over earlier ``jax.config`` calls in the
+    same process; once the backend exists it is too late and the call
+    changes nothing.
     """
     raw = os.environ.get("HOROVOD_CPU_DEVICES")
     if not raw:
@@ -876,13 +878,44 @@ def apply_platform_overrides() -> None:
         return
     import jax
 
-    from horovod_tpu.utils import jax_compat as _compat
-
     try:
         jax.config.update("jax_platforms", "cpu")
-        _compat.set_cpu_devices(n)
+        jax.config.update("jax_num_cpu_devices", n)
     except RuntimeError:
         pass  # backend already initialized; too late to simulate
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a FIXED place and
+    return it — for entry scripts (``chip_smoke.py``, ``bench.py``, the
+    bench tools) to call before their first compile; ``import
+    horovod_tpu`` never does.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no directory is set here. Otherwise the cache goes to ``<checkout>/
+    .jax_cache`` (git-ignored). A path with a pid, a time or a
+    ``mkdtemp`` in it would never hit, so it is never derived from
+    anything that moves.
+
+    Either way MLIR locations are cut to the innermost frame: a Pallas
+    kernel's Mosaic body is serialized WITH its locations, which by
+    default hold the whole Python stack of the trace — so the cache key
+    of any program with a kernel in it would change with the caller, and
+    a second trace of the same step in one process (``step.lower`` after
+    ``step(...)``) would compile again from scratch (measured: 40 s for
+    the smoke's LM step).
+    """
+    import jax
+
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    preset = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if preset:
+        return preset
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def xla_compiler_options() -> dict[str, str] | None:

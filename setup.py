@@ -9,41 +9,43 @@ channel the reference uses, mpi_ops.py:68-77). If no compiler is available
 the package still works: every native path has a pure-Python fallback with
 identical semantics.
 
-    pip install .            # builds _hvd_core.so alongside hvd_core.cc
+    pip install .            # builds _hvd_core.<hash>.so beside hvd_core.cc
     python setup.py build    # same, in-place tree
 """
 
 from __future__ import annotations
 
+import importlib.util
 import os
-import subprocess
 
-from setuptools import Command, find_packages, setup
+from setuptools import find_packages, setup
 from setuptools.command.build_py import build_py
 
 
-def _compile_core(src: str, out: str) -> bool:
-    cmd = ["g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-o", out, src]
-    try:
-        res = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-        return res.returncode == 0
-    except (OSError, subprocess.SubprocessError):
-        return False
+def _build_core(base: str) -> str | None:
+    """Run the package's own native build (core/native/__init__.py
+    ``_build`` — stdlib only, so it loads without jax) inside ``base``:
+    one build recipe, one naming rule (the library's name carries the
+    hash of hvd_core.cc, and only that name is ever loaded)."""
+    init = os.path.join(base, "horovod_tpu", "core", "native", "__init__.py")
+    if not os.path.exists(init):
+        return None
+    spec = importlib.util.spec_from_file_location("_hvd_native_build", init)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod._build()
 
 
 class BuildWithNativeCore(build_py):
     def run(self):
         super().run()
         for base in ([self.build_lib] if not self.editable_mode else ["."]):
-            src = os.path.join(base, "horovod_tpu", "core", "native",
-                               "hvd_core.cc")
-            if os.path.exists(src):
-                out = os.path.join(os.path.dirname(src), "_hvd_core.so")
-                if _compile_core(src, out):
-                    print(f"built native control-plane core: {out}")
-                else:
-                    print("WARNING: native core build failed; the "
-                          "pure-Python control plane will be used.")
+            out = _build_core(base)
+            if out:
+                print(f"built native control-plane core: {out}")
+            else:
+                print("WARNING: native core build failed; the "
+                      "pure-Python control plane will be used.")
 
 
 setup(

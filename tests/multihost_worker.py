@@ -19,25 +19,13 @@ DEVS = int(os.environ.get("HOROVOD_TEST_DEVS_PER_PROC", "4"))
 
 os.environ.setdefault("HOROVOD_STALL_CHECK_TIME", "2")
 
-# jax_num_cpu_devices is absent on jax < 0.5: set the XLA flag before jax
-# imports so the device count takes effect there too. REPLACE any
-# inherited device-count flag (the parent pytest's conftest exports an
-# 8-device XLA_FLAGS that every worker would otherwise pick up).
-import re as _re
-
-_flags = os.environ.get("XLA_FLAGS", "")
-_flags = _re.sub(r"--xla_force_host_platform_device_count=\d+", "", _flags)
-os.environ["XLA_FLAGS"] = (
-    _flags + f" --xla_force_host_platform_device_count={DEVS}").strip()
-
 import jax  # noqa: E402
 
+# jax_num_cpu_devices beats any device-count XLA_FLAGS the environment
+# carries (CI exports an 8-device one for the in-process suite).
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
-try:
-    jax.config.update("jax_num_cpu_devices", DEVS)
-except AttributeError:
-    pass  # absent on jax < 0.5; the XLA_FLAGS replacement above covers it
+jax.config.update("jax_num_cpu_devices", DEVS)
 
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

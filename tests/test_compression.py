@@ -357,7 +357,6 @@ class TestWireDtypeInProgramHLO:
 
         from horovod_tpu.core import context as _ctx
         from horovod_tpu.core.state import AXIS_NAME
-        from horovod_tpu.utils import jax_compat as _compat
 
         grp = hvd.get_group(0)
 
@@ -368,7 +367,7 @@ class TestWireDtypeInProgramHLO:
                     gv, fusion_threshold=0, compression=compression_spec)
             return jax.tree.map(lambda t: t[None], out)
 
-        jitted = jax.jit(_compat.shard_map(
+        jitted = jax.jit(jax.shard_map(
             shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
             out_specs=P(AXIS_NAME), check_vma=False))
         g = {f"w{i}": jax.ShapeDtypeStruct((grp.size, 64), jnp.float32)
@@ -415,7 +414,6 @@ class TestCompressedAllreduceAOT:
 
         from horovod_tpu.core import context as _ctx
         from horovod_tpu.core.state import AXIS_NAME
-        from horovod_tpu.utils import jax_compat as _compat
 
         hvd.shutdown()
         hvd.init(devices=devices)
@@ -439,7 +437,7 @@ class TestCompressedAllreduceAOT:
                 out = ({k: pv[k] - 0.1 * grads[k] for k in pv}, loss)
             return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
 
-        jitted = jax.jit(_compat.shard_map(
+        jitted = jax.jit(jax.shard_map(
             shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
             out_specs=P(AXIS_NAME), check_vma=False))
         shard = NamedSharding(grp.mesh, P(AXIS_NAME))

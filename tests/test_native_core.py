@@ -484,3 +484,66 @@ class TestXprofSpanMapping:
             hvd.shutdown()
         finally:
             os.environ.pop("HOROVOD_TIMELINE", None)
+
+
+# ---------------------------------------------------------------------------
+# Only a library built from the hvd_core.cc beside it is ever loaded
+# ---------------------------------------------------------------------------
+
+
+def _native_copy(tmp_path):
+    """The native package loaded from a scratch copy of its directory, so
+    planted files never touch the real one."""
+    import importlib.util
+    import shutil
+
+    src_dir = os.path.dirname(native.__file__)
+    for name in ("__init__.py", "hvd_core.cc"):
+        shutil.copy(os.path.join(src_dir, name), tmp_path / name)
+    spec = importlib.util.spec_from_file_location(
+        "_native_copy", tmp_path / "__init__.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_planted_library_is_never_loaded(tmp_path, monkeypatch):
+    from unittest import mock
+
+    mod = _native_copy(tmp_path)
+    # A stale binary under the old fixed name, and one under a hash that
+    # is not this source's: neither may ever reach dlopen.
+    planted = [tmp_path / "_hvd_core.so",
+               tmp_path / "_hvd_core.0123456789abcdef.so"]
+    for path in planted:
+        path.write_bytes(b"not built from this hvd_core.cc")
+    compiled, opened = [], []
+
+    def fake_compile(cmd, **kw):
+        compiled.append(cmd)
+        out = cmd[cmd.index("-o") + 1]
+        with open(out, "wb") as f:
+            f.write(b"fresh build")
+        return mock.Mock(returncode=0, stderr="")
+
+    monkeypatch.setattr(mod.subprocess, "run", fake_compile)
+    monkeypatch.setattr(mod.ctypes, "CDLL",
+                        lambda path: opened.append(path) or mock.MagicMock())
+    assert mod._load() is not None
+    want = mod._so_path()
+    assert os.path.dirname(want) == str(tmp_path)
+    assert len(compiled) == 1 and compiled[0][-1].endswith("hvd_core.cc")
+    assert opened == [want]
+    assert want not in [str(p) for p in planted]
+    for path in planted:
+        assert path.read_bytes() == b"not built from this hvd_core.cc"
+
+
+def test_library_name_follows_the_source(tmp_path):
+    mod = _native_copy(tmp_path)
+    before = mod._so_path()
+    with open(tmp_path / "hvd_core.cc", "a") as f:
+        f.write("\n// edited\n")
+    assert mod._so_path() != before
+    # The real package loaded exactly the library its own source names.
+    assert os.path.exists(native._so_path())

@@ -36,7 +36,6 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
-from horovod_tpu.utils import jax_compat as _compat
 
 
 def _topo(n=8, name="v5e:2x4"):
@@ -79,7 +78,7 @@ def _compile_dp_step(devices, n, compiler_options=None):
             out = ({k: pv[k] - 0.1 * grads[k] for k in pv}, loss)
         return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     shard = NamedSharding(grp.mesh, P(AXIS_NAME))
@@ -200,7 +199,7 @@ class TestSubsetCollectivesTpuLowering:
                 out = (a, b, c, d)
             return jax.tree.map(lambda t: t[None], out)
 
-        jitted = jax.jit(_compat.shard_map(
+        jitted = jax.jit(jax.shard_map(
             shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
             out_specs=P(AXIS_NAME), check_vma=False))
         x = jax.ShapeDtypeStruct(

@@ -46,7 +46,6 @@ def _lowered_hlo(algo, nbytes=4096, compression_spec=None, grads=False,
 
     from horovod_tpu.core import context as _ctx
     from horovod_tpu.core.state import AXIS_NAME
-    from horovod_tpu.utils import jax_compat as _compat
 
     if slices and monkeypatch is not None:
         monkeypatch.setenv("HOROVOD_TOPOLOGY_SLICES", str(slices))
@@ -67,7 +66,7 @@ def _lowered_hlo(algo, nbytes=4096, compression_spec=None, grads=False,
                                     name="payload")
         return out[None]
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     x = jax.ShapeDtypeStruct((grp.size, nbytes // 4), jnp.float32)
@@ -563,7 +562,6 @@ def _aot_grad_program(devices, algo, n=8, compile_=True):
 
     from horovod_tpu.core import context as _ctx
     from horovod_tpu.core.state import AXIS_NAME
-    from horovod_tpu.utils import jax_compat as _compat
 
     hvd.shutdown()
     hvd.init(devices=devices)
@@ -576,7 +574,7 @@ def _aot_grad_program(devices, algo, n=8, compile_=True):
                                           algo=algo)
         return jax.tree.map(lambda t: t[None], out)
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     shard = NamedSharding(grp.mesh, P(AXIS_NAME))

@@ -133,7 +133,6 @@ def count_collective_ops(nbytes: int, compression: str,
 
     from horovod_tpu.core import context as _ctx
     from horovod_tpu.core.state import AXIS_NAME
-    from horovod_tpu.utils import jax_compat as _compat
 
     grp = hvd.get_group(0)
     comp = _comp_arg(compression)
@@ -145,7 +144,7 @@ def count_collective_ops(nbytes: int, compression: str,
                                 name="bench_payload")
         return out[None]
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     x = jax.ShapeDtypeStruct((grp.size, nbytes // 4), jnp.float32)
@@ -575,6 +574,7 @@ def main() -> None:
                              "reduced steps/trials (the workflow gate)")
     args = parser.parse_args()
 
+    _envmod.use_compile_cache()
     hvd.init()
     world = hvd.size()
     if world < 2:

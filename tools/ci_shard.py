@@ -20,7 +20,12 @@ import sys
 # Balanced by measured wall-clock (docs/ci.md records the timings), not by
 # test count — test_sequence.py alone is ~9 min on the simulated mesh.
 SHARDS = {
-    "unit-1": ["tests/test_sequence.py"],
+    "unit-1": [
+        "tests/test_sequence.py",
+        # chip_smoke.py's phase functions at toy width on the CPU mesh,
+        # its gate and exit codes, the compile-cache helper.
+        "tests/test_chip_smoke.py",
+    ],
     "unit-2": [
         "tests/test_basics.py",
         "tests/test_collectives.py",

@@ -36,8 +36,8 @@ import sys
 
 # Metrics distilled into a baseline by --make-baseline, with their
 # tolerance bands. Absolute CPU wall-clock numbers jitter hard on
-# shared CI hosts (observed r5: 20-26x episodes under co-tenancy) AND
-# the committed baseline's host is not the CI runner — throughput
+# shared CI hosts AND the committed baseline's host is not the CI
+# runner — throughput
 # bands are deliberately wide; the tuned-vs-default SPEEDUP is a
 # same-host same-process A/B ratio, so its band can be much tighter
 # than either absolute number. direction: "higher" = higher is better.

@@ -36,7 +36,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.utils import jax_compat as _compat
 from horovod_tpu.core import context as _ctx
 from horovod_tpu.core.state import AXIS_NAME
 
@@ -82,7 +81,7 @@ def subset_collective_case(n_chips: int, g_members: int, op: str) -> dict:
                 out = hvd.reducescatter(v, group=sub)
         return out[None]
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     # 4 MB fp32 per rank — a realistic fusion-bucket-sized payload.
@@ -132,7 +131,7 @@ def train_step_case(n_chips: int) -> dict:
             out = (v, o, loss_sub)
         return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     shard = NamedSharding(grp.mesh, P(AXIS_NAME))
@@ -169,7 +168,7 @@ def ring_attention_case(n_chips: int) -> dict:
             g1, g2, g3 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         return g1, g2, g3
 
-    jitted = jax.jit(_compat.shard_map(
+    jitted = jax.jit(jax.shard_map(
         shard_fn, mesh=grp.mesh, in_specs=P(AXIS_NAME),
         out_specs=P(AXIS_NAME), check_vma=False))
     shard = NamedSharding(grp.mesh, P(AXIS_NAME))

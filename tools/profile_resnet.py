@@ -104,15 +104,8 @@ def analyze(trace_dir: str, top: int = 15,
     """``n_steps_hint``: executions in the capture window — used to
     normalize per-step figures when the xplane carries no 'Steps' line
     (otherwise the window would be misread as one step)."""
-    from horovod_tpu.utils import jax_compat as _compat
+    from jax.profiler import ProfileData
 
-    ProfileData = _compat.profile_data()
-    if ProfileData is None:
-        # Same graceful-degrade contract as a CPU capture: report, don't
-        # crash — the capture itself is still valid for external viewers.
-        return ("no device plane readable: this jax has no "
-                "jax.profiler.ProfileData (xplane analysis needs a newer "
-                "jax); open the trace in TensorBoard/Perfetto instead")
     path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                             recursive=True))[-1]
     pd = ProfileData.from_file(path)

@@ -455,6 +455,9 @@ def main() -> None:
                              "throughput sweep")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
+    from horovod_tpu.utils import env as _env
+
+    _env.use_compile_cache()
     if args.shared_prefix_len < 0:
         raise SystemExit("--shared-prefix-len must be >= 0")
     if 0 < args.shared_prefix_len < args.block_size:
