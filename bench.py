@@ -50,6 +50,7 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
+from horovod_tpu.core import state as _state
 from horovod_tpu.core.xprof import timed_steps as _timed_steps
 from horovod_tpu.models import resnet
 
@@ -72,7 +73,7 @@ def _chip_peak_tflops() -> float | None:
     the table raises — MFU fields never silently vanish."""
     from horovod_tpu.ops import topology
 
-    dev = jax.devices()[0]
+    dev = _state.target_device()
     if dev.platform != "tpu":
         return None
     return topology.chip_spec(dev.device_kind).peak_bf16_tflops
@@ -319,7 +320,7 @@ def main() -> None:
                   "serve_journal_overhead_ms"):
         result.setdefault(field, None)
     sanity_post = _device_sanity_tflops()
-    dev = jax.devices()[0]
+    dev = _state.target_device()
     result["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": len(jax.devices())}
     if dev.platform != "tpu":
@@ -474,7 +475,7 @@ def _exchange_extra() -> dict:
         source = "wall-diff"
         exposed = {m: max(0.0, (times[m] - times[None]) * 1e3)
                    for m in ("enum", "priority")}
-        if jax.default_backend() == "tpu":
+        if _state.target_platform() == "tpu":
             # Span-level truth where the profiler has a device plane.
             for mode in ("enum", "priority"):
                 step = make_step(mode)
@@ -941,7 +942,7 @@ def _device_sanity_tflops() -> float | None:
     are read against. None off-TPU (a wall-clocked probe would charge
     host dispatch to sub-ms matmul steps and fabricate a 'degraded'
     verdict) or when the probe raised (recorded as a failed leg)."""
-    if jax.default_backend() != "tpu":
+    if _state.target_platform() != "tpu":
         return None
     try:
         from jax import lax
@@ -972,7 +973,7 @@ def _flash_attention_extra(peak: float | None) -> dict:
     one chip (the long-context hot op — docs/sequence-parallelism.md's
     table). Scanned steps, all three gradients consumed, device-timeline
     timing (`_timed_steps`). Skipped off-TPU (interpret mode)."""
-    if jax.default_backend() != "tpu":
+    if _state.target_platform() != "tpu":
         return {}
     from jax import lax
 
@@ -1016,7 +1017,7 @@ def _lm_extra(peak: float | None) -> dict:
     kernel, rotary transformer, AdamW update). T=8k, ~160M params, bf16.
     FLOPs come from XLA's own cost analysis of the compiled step (the
     same convention as the ResNet number). Skipped off-TPU."""
-    if jax.default_backend() != "tpu":
+    if _state.target_platform() != "tpu":
         return {}
     try:
         from jax import lax

@@ -166,6 +166,13 @@ def describe_installation() -> None:
     _say("gate", f"compile cache: {env.use_compile_cache()} "
                  f"(JAX_COMPILATION_CACHE_DIR="
                  f"{os.environ.get('JAX_COMPILATION_CACHE_DIR')!r})")
+    # The train phase traces its step twice: to run it, then to read its
+    # HLO. A Pallas kernel's Mosaic body is serialized WITH its MLIR
+    # locations, which by default hold the whole Python stack of the
+    # trace, so the second trace would be another cache key and compile
+    # from scratch (40 s for the LM step; my chip run, PR 21). Keep the
+    # innermost frame only.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
     hvd.init()
     plane = "native (hvd_core.cc)" if _state.native_core() else "python"
     model = costs.model_for(topology.discover(hvd.get_group(0)))
@@ -282,6 +289,7 @@ def phase_train(cfg: SmokeConfig, ctx: dict) -> None:
     from horovod_tpu.core import state as _state
     from horovod_tpu.models import transformer
     from horovod_tpu.ops import optim
+    from horovod_tpu.utils import env as _env
 
     hvd.shutdown()
     hvd.init()
@@ -362,7 +370,9 @@ def phase_train(cfg: SmokeConfig, ctx: dict) -> None:
     t0 = time.perf_counter()
     lowered = step.lower(*specs)
     lower_s = time.perf_counter() - t0
-    hlo = lowered.compile().as_text()  # the same program: a cache hit
+    # The same program, built as the wrapper builds it: a cache hit.
+    hlo = lowered.compile(
+        compiler_options=_env.xla_compiler_options()).as_text()
     inspect_s = time.perf_counter() - t0
     pallas = hlo.count('custom_call_target="tpu_custom_call"')
     allreduces = hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(")

@@ -239,36 +239,20 @@ def step_hlo(fn, arg_structs, group: int = 0, compiled: bool = False) -> str:
     lowering.
     """
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
 
     import horovod_tpu as hvd
-    from horovod_tpu.core import context as _ctx
-    from horovod_tpu.core.state import AXIS_NAME
-    from horovod_tpu.ops import collectives as _coll
 
-    grp = hvd.get_group(group)
-    structs = [jax.ShapeDtypeStruct((grp.size,) + tuple(a.shape), a.dtype)
+    size = hvd.get_group(group).size
+    structs = [jax.ShapeDtypeStruct((size,) + tuple(a.shape), a.dtype)
                for a in arg_structs]
-
-    def shard_fn(*args):
-        with _ctx.enter(AXIS_NAME, group):
-            out = fn(*[a[0] for a in args])
-        return jnp.asarray(out).reshape(-1)[:1]
-
-    jitted = jax.jit(jax.shard_map(
-        shard_fn, mesh=grp.mesh,
-        in_specs=tuple(P(AXIS_NAME) for _ in structs),
-        out_specs=P(AXIS_NAME), check_vma=False))
-    # The analysis trace must not advance the live process's auto-name
-    # counters: verifying a step mid-job would otherwise shift this
-    # process's later collective names — the exact drift hvd-lint HVD003
-    # exists to catch.
-    with _coll.preserve_auto_names():
-        lowered = jitted.lower(*structs)
-        if compiled:
-            try:
-                return lowered.compile().as_text()
-            except Exception:  # backend without text support: lowered view
-                pass
+    # ``hvd.spmd(...).lower`` is the one place the SPMD program is built;
+    # it leaves the live process's auto-name counters alone (verifying a
+    # step mid-job would otherwise shift this process's later collective
+    # names — the exact drift hvd-lint HVD003 exists to catch).
+    lowered = hvd.spmd(fn, group=group).lower(*structs)
+    if compiled:
+        try:
+            return lowered.compile().as_text()
+        except Exception:  # backend without text support: lowered view
+            pass
     return lowered.as_text(dialect="hlo")

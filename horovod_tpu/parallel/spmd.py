@@ -211,10 +211,17 @@ def spmd(fn: Callable, group: int = 0,
         return compiled[key](*args)
 
     def lower(*args):
-        """``jax.stages.Lowered`` of the program ``wrapper(*args)`` runs —
-        for inspection (``.compile().as_text()``, memory analysis), as
-        ``jax.jit(f).lower`` is. A fresh trace: the wrapper's program cache
-        and the auto-name counters are left as they were."""
+        """``jax.stages.Lowered`` of the program ``wrapper(*args)`` traces —
+        for inspection (``.as_text()``, ``.compile().as_text()``, memory
+        analysis), as ``jax.jit(f).lower`` is. ``args`` may be
+        rank-stacked ``jax.ShapeDtypeStruct``s. A fresh trace: the
+        wrapper's program cache and the auto-name counters are left as
+        they were.
+
+        The wrapper compiles with ``HOROVOD_XLA_OPTIONS`` where that is
+        set; a bare ``.compile()`` on the result does not. To inspect that
+        build, pass them:
+        ``.compile(compiler_options=env.xla_compiler_options())``."""
         from horovod_tpu.ops import collectives as _coll
 
         with _coll.preserve_auto_names():

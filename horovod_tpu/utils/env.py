@@ -893,26 +893,25 @@ def use_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
     no directory is set here. Otherwise the cache goes to ``<checkout>/
-    .jax_cache`` (git-ignored). A path with a pid, a time or a
-    ``mkdtemp`` in it would never hit, so it is never derived from
-    anything that moves.
-
-    Either way MLIR locations are cut to the innermost frame: a Pallas
-    kernel's Mosaic body is serialized WITH its locations, which by
-    default hold the whole Python stack of the trace — so the cache key
-    of any program with a kernel in it would change with the caller, and
-    a second trace of the same step in one process (``step.lower`` after
-    ``step(...)``) would compile again from scratch (measured: 40 s for
-    the smoke's LM step).
+    .jax_cache`` (git-ignored), the checkout being the directory that
+    holds this package beside its ``setup.py``. A path with a pid, a time
+    or a ``mkdtemp`` in it would never hit, so it is never derived from
+    anything that moves; and an installed package has no checkout (its
+    parent is ``site-packages``), so there the variable must be set.
     """
-    import jax
-
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
     preset = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if preset:
         return preset
     checkout = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    if not os.path.isfile(os.path.join(checkout, "setup.py")):
+        raise ValueError(
+            f"use_compile_cache: {checkout} is not a checkout of this "
+            f"repository (no setup.py beside the package); set "
+            f"JAX_COMPILATION_CACHE_DIR to say where the compile cache "
+            f"goes.")
+    import jax
+
     path = os.path.join(checkout, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

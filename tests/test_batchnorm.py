@@ -165,8 +165,10 @@ class TestResNetNormImpl:
             variables = resnet.init_variables(model, image_size=32, seed=0)
             loss_fn = resnet.make_loss_fn(model)
             imgs, labels = resnet.synthetic_imagenet(4, 32, num_classes=10)
-            (loss, _), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(variables, (imgs, labels))
+            # jitted: op-by-op dispatch of a ResNet18 fwd+bwd is a minute
+            # of one-primitive compiles on the CPU.
+            (loss, _), grads = jax.jit(jax.value_and_grad(
+                loss_fn, has_aux=True))(variables, (imgs, labels))
             # Key by path with the module-class name normalized, so the
             # two trees align (FusedBatchNorm_i vs BatchNorm_i).
             flat = {

@@ -106,12 +106,9 @@ def test_all_phases_passing_prints_the_result_line(monkeypatch, capsys):
 
 @pytest.fixture
 def restore_cache_dir():
-    names = ("jax_compilation_cache_dir",
-             "jax_include_full_tracebacks_in_locations")
-    before = {name: getattr(jax.config, name) for name in names}
+    before = jax.config.jax_compilation_cache_dir
     yield
-    for name, value in before.items():
-        jax.config.update(name, value)
+    jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_compile_cache_leaves_a_set_directory_alone(monkeypatch,
@@ -129,3 +126,16 @@ def test_compile_cache_defaults_into_the_checkout(monkeypatch,
     assert _env.use_compile_cache() == want
     assert _env.use_compile_cache() == want  # fixed: no pid, no time
     assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_compile_cache_refuses_an_installed_package(monkeypatch, tmp_path,
+                                                    restore_cache_dir):
+    # An installed package's parent is site-packages, not a checkout:
+    # nothing is written there, the caller is told to say where.
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(_env, "__file__", str(
+        tmp_path / "site-packages" / "horovod_tpu" / "utils" / "env.py"))
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        _env.use_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == before

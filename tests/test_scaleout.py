@@ -24,8 +24,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 import __graft_entry__ as g  # noqa: E402
 
 
-@pytest.mark.slow  # ~40 s child process; the CI multihost shard runs it
 def test_dryrun_16_devices():
+    # The real child on a real 16-device mesh: the only run of
+    # ``_dryrun_impl`` under the environment ``dryrun_multichip`` builds.
     g.dryrun_multichip(16)
 
 
