@@ -57,6 +57,9 @@ _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[(?P<g>\d+),(?P<s>\d+)\]<=\[(?P<w>\d+)\]"
     r"(?P<t>T\(1,0\))?")
 _OPNAME_RE = re.compile(r'op_name="(?P<op_name>[^"]*)"')
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<iname>[\w.\-]+)\s*=\s")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?(?P<cname>[\w.\-]+)\s*\(.*\{\s*$")
+_CALLS_RE = re.compile(r"\bfusion\(.*\bcalls=%?(?P<callee>[\w.\-]+)")
 
 # HLO element-type byte widths (pred is bit-packed conceptually but moves
 # as a byte on the wire).
@@ -163,6 +166,40 @@ def _parse_scope(line: str) -> tuple[str | None, str | None]:
             scope = part
             break
     return scope, op_name
+
+
+def scope_map(hlo_text: str) -> dict:
+    """``{instruction name: [op_name, [its fusion's members' op_names]]}``
+    of a COMPILED module's text — the join from a profiler capture's
+    device events (named by instruction; this libtpu gives them no
+    ``tf_op``) to the ``jax.named_scope``s the program was traced under.
+    Every instruction outside a fused computation is a key (``""`` where
+    it carries no ``op_name``); a fusion lists the distinct ``op_name``s
+    of the computation it calls, in order."""
+    bodies: dict = {}  # computation → [(instruction, op_name, callee)]
+    body = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION_RE.match(line)
+        if m:
+            body = bodies.setdefault(m.group("cname"), [])
+            continue
+        m = _INSTR_RE.match(line) if body is not None else None
+        if m:
+            name = _OPNAME_RE.search(line)
+            calls = _CALLS_RE.search(line)
+            body.append((m.group("iname"),
+                         name.group("op_name") if name else "",
+                         calls.group("callee") if calls else None))
+    fused = {c for rows in bodies.values() for _, _, c in rows if c}
+    out = {}
+    for cname, rows in bodies.items():
+        if cname in fused:
+            continue
+        for iname, op_name, callee in rows:
+            members = dict.fromkeys(
+                n for _, n, _ in bodies.get(callee, ()) if n)
+            out[iname] = [op_name, list(members)]
+    return out
 
 
 def extract_schedule(hlo_text: str) -> list[CollectiveInstr]:

@@ -52,8 +52,11 @@ def _build() -> str | None:
         # Compile beside the target and rename: a concurrent process
         # (multi-host tests) never opens a half-written library.
         tmp = f"{so}.{os.getpid()}.tmp"
-        res = subprocess.run(_CXX + ["-o", tmp, _SRC], capture_output=True,
-                             text=True, timeout=120)
+        from horovod_tpu.core import timeline as _timeline
+
+        with _timeline.span("hvd/init/native_build"):  # a checkout's first
+            res = subprocess.run(_CXX + ["-o", tmp, _SRC],
+                                 capture_output=True, text=True, timeout=120)
         if res.returncode != 0:
             import warnings
 

@@ -153,9 +153,21 @@ def init(group_ranks: Sequence[Sequence[int]] | None = None,
     ``devices`` overrides the device list (testing); defaults to
     ``jax.devices()``.
     """
+    from horovod_tpu.core import timeline as _timeline
+
+    if _state.initialized:
+        return  # InitializeHorovodOnce semantics (mpi_ops.cc:1815)
+    # A new world starts a new record (the last one stayed readable
+    # after shutdown); its first span is the program's share of set-up.
+    _timeline.session().clear_record()
+    with _timeline.span("hvd/init"):
+        _init(group_ranks, devices)
+
+
+def _init(group_ranks, devices) -> None:
     with _state.lock:
         if _state.initialized:
-            return  # InitializeHorovodOnce semantics (mpi_ops.cc:1815)
+            return
         # Unknown HOROVOD_* variables are almost certainly typo'd knob
         # names (HOROVOD_COMPRESION=int8), which — unlike typo'd values —
         # would otherwise be silently ignored. hvd-lint flags the same
@@ -284,7 +296,12 @@ def shutdown() -> None:
     from horovod_tpu.core import resilience as _res
     from horovod_tpu.core import timeline as _timeline
 
-    _res.stop_heartbeat()
+    tl = _timeline.session()
+    with tl.span("hvd/shutdown"):
+        # First, before any state goes: the scope maps a capture will ask
+        # for are read from the live programs, which are then let go.
+        tl.resolve_scopes(shutdown=True)
+        _res.stop_heartbeat()
     _timeline.stop()
     # Drop any applied tuned configuration with the world it was tuned
     # for — a re-init at a different world must not inherit its knobs.

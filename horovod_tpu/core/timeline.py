@@ -27,16 +27,31 @@ mapped to their XLA equivalents:
                              HLO scopes for xplane mapping)
     DEQUANTIZE               summed wire dtype → original dtype
     MEMCPY_OUT_FUSION_BUFFER unpack
+
+File or no file, the same session keeps the program's account of itself:
+:func:`span` (host spans on the profiler's clock, in a bounded in-memory
+ring, B/E pairs on the ``_hvd`` row), and per compiled ``hvd.spmd``
+program its dispatches, its exchange plan's counters and — lazily, never
+by compiling — its instructions' named scopes.
+:func:`record` snapshots it: readable after ``hvd.shutdown()``, cleared
+by the next ``hvd.init()``.
 """
 
 from __future__ import annotations
 
 import atexit
+import collections
 import json
 import threading
 import time
+import weakref
+
+import jax
 
 from horovod_tpu.utils import env as _env
+
+RING_SPANS = 4096  # newest spans kept once the first step was dispatched
+SPAN_ROW = "_hvd"  # the Chrome file's row of span() pairs
 
 
 class _PyTimeline:
@@ -108,6 +123,40 @@ class _PyTimeline:
         atexit.unregister(self.close)
 
 
+class _Span:
+    """One :meth:`Timeline.span`."""
+
+    __slots__ = ("_tl", "name", "_ann", "_t0", "_parent", "_in_file")
+
+    def __init__(self, tl: "Timeline", name: str):
+        self._tl, self.name = tl, name
+        self._ann = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self):
+        tl = self._tl
+        stack = tl._stack()
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._in_file = tl._active  # hvd/init opens the file inside itself
+        tl.event(SPAN_ROW, self.name, "B")
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        tl = self._tl
+        tl._stack().pop()
+        if self._in_file:
+            tl.event(SPAN_ROW, self.name, "E")
+        row = (self.name, self._t0, end, self._parent)
+        if tl.dispatched:
+            tl._ring.append(row)
+        elif len(tl._setup) < RING_SPANS:
+            tl._setup.append(row)  # set-up is never evicted by the steps
+
+
 class Timeline:
     """Session timeline: prefers the native writer, falls back to Python."""
 
@@ -115,7 +164,89 @@ class Timeline:
         self._py: _PyTimeline | None = None
         self._native = None  # NativeCore owning the writer
         self._active = False
-        self._device_mode = False
+        # True when ``HOROVOD_TIMELINE_DEVICE=1`` was set when the timeline
+        # started (latched in :meth:`start`): per-step spans come from a
+        # sampled ``jax.profiler`` capture with device timestamps.
+        self.device_mode = False
+        self._local = threading.local()
+        self.clear_record()
+
+    def clear_record(self) -> None:
+        """Forget spans and programs (``hvd.init`` does)."""
+        self._setup: list = []
+        self._ring: collections.deque = collections.deque(maxlen=RING_SPANS)
+        self.dispatched = False  # set by hvd.spmd's first dispatch
+        self.programs: dict = {}
+        self.building: str | None = None  # the program being traced
+        self._texts: dict = {}  # tag → weak or pinned owner of hlo_text()
+
+    def _stack(self) -> list:  # the open spans of this thread
+        return self._local.__dict__.setdefault("stack", [])
+
+    def span(self, name: str) -> _Span:
+        """Context manager: ``name`` as a ``jax.profiler.TraceAnnotation``
+        (in whatever capture runs, on the device planes' clock; an atomic
+        load when none does), a ``(name, start_ns, end_ns, parent)`` row
+        of :meth:`record` and a B/E pair where the file is on."""
+        return _Span(self, name)
+
+    def count_plan(self, name: str, n: int) -> None:
+        """Add ``n`` to counter ``name`` of the program being traced (its
+        plan's numbers: a step, a rank) — dropped where none is (an
+        inspection's lowering)."""
+        counters = self.programs.get(self.building, {}).get("counters")
+        if counters is not None:
+            counters[name] = counters.get(name, 0) + n
+
+    def add_program(self, tag: str, owner) -> str:
+        """Open the record of one compiled program; ``owner.hlo_text()``
+        gives its optimized text or None (held weakly until a dispatch of
+        it is profiled: :meth:`pin`). Returns the tag made unique."""
+        base, k = tag, 1
+        while tag in self.programs:
+            k += 1
+            tag = f"{base}#{k}"
+        self.programs[tag] = {"dispatches": 0, "counters": {},
+                              "scopes": None}
+        self._texts[tag] = weakref.ref(owner)
+        return tag
+
+    def pin(self, tag: str, owner) -> None:
+        """A capture holds events of this program: keep it until its
+        scope map is resolved (at ``hvd.shutdown()`` at the latest)."""
+        if tag in self._texts:
+            self._texts[tag] = lambda: owner
+
+    def resolve_scopes(self, shutdown: bool = False) -> None:
+        """Fill ``programs[tag]["scopes"]`` from the programs' compiled
+        text (``analysis/hlo.scope_map``). Never traces or compiles: a
+        program JAX's caches no longer hold keeps None. At ``shutdown``
+        only pinned programs are read, and all are let go."""
+        from horovod_tpu.analysis import hlo as _hlo
+
+        for tag, ref in list(self._texts.items()):
+            owner = ref()
+            if owner is None or (shutdown and isinstance(ref, weakref.ref)):
+                continue
+            text = owner.hlo_text()
+            if text is not None:
+                self.programs[tag]["scopes"] = _hlo.scope_map(text)
+            del self._texts[tag]
+        if shutdown:
+            self._texts.clear()
+
+    def record(self, scopes: bool = False) -> dict:
+        """A plain, JSON-able snapshot: ``spans`` (set-up's, then the
+        newest ``RING_SPANS``) and ``programs`` (each with its
+        ``dispatches``, its plan's ``counters`` and its ``scopes``).
+        ``scopes`` resolves the live programs' scope maps first; else they
+        hold what ``hvd.shutdown()`` resolved (profiled programs only) or
+        None."""
+        if scopes:
+            self.resolve_scopes()
+        return {"spans": [list(s) for s in self._setup + list(self._ring)],
+                "programs": {t: dict(p, counters=dict(p["counters"]))
+                             for t, p in self.programs.items()}}
 
     def start(self, path: str, native_core=None) -> None:
         if self._active:
@@ -126,24 +257,13 @@ class Timeline:
         # latched HERE: flipping HOROVOD_TIMELINE_DEVICE after start()
         # cannot change the writer choice, so honoring a late flip would
         # silently drop every device span into a native-only timeline.
-        self._device_mode = _env.timeline_device_mode()
-        if (native_core is not None and not self._device_mode
+        self.device_mode = _env.timeline_device_mode()
+        if (native_core is not None and not self.device_mode
                 and native_core.timeline_start(path)):
             self._native = native_core
         else:
             self._py = _PyTimeline(path)
         self._active = True
-
-    @property
-    def device_mode(self) -> bool:
-        """True when ``HOROVOD_TIMELINE_DEVICE=1`` was set when the
-        timeline started (latched in :meth:`start`; before that, the live
-        env var): per-step spans come from a sampled ``jax.profiler``
-        capture with device timestamps instead of host
-        ``block_until_ready`` timing."""
-        if self._active:
-            return self._device_mode
-        return _env.timeline_device_mode()
 
     @property
     def active(self) -> bool:
@@ -171,20 +291,10 @@ class Timeline:
 
     def event_at(self, tensor: str, activity: str, ts_us: float,
                  dur_us: float) -> None:
-        """Explicit-timestamp complete event (device-true spans). Only the
-        Python writer carries these; device mode forces it in start()."""
-        if not self._active:
-            return
-        if self._py is None:
-            import warnings
-
-            warnings.warn(
-                "Timeline.event_at called while only the native writer is "
-                "active (HOROVOD_TIMELINE_DEVICE was not set when the "
-                "timeline started) — device-true span dropped. Set the "
-                "variable before horovod_tpu.init().", stacklevel=2)
-            return
-        self._py.event_at(tensor, activity, ts_us, dur_us)
+        """Explicit-timestamp complete event (device-true spans), by the
+        Python writer: device mode, its only caller, always has one."""
+        if self._py is not None:
+            self._py.event_at(tensor, activity, ts_us, dur_us)
 
     def stop(self) -> None:
         if not self._active:
@@ -214,3 +324,11 @@ def maybe_start(native_core=None) -> None:
 
 def stop() -> None:
     _session.stop()
+
+
+def span(name: str) -> _Span:
+    return _session.span(name)
+
+
+def record(scopes: bool = False) -> dict:
+    return _session.record(scopes)

@@ -4,10 +4,12 @@ The reference's timeline stamps its hot-path activities on the coordinator
 thread as the ops execute (mpi_ops.cc:741-753, 1238-1281). The XLA analog
 cannot hook into a compiled program, so the device-fidelity mode samples
 instead: one execution of the compiled step runs under ``jax.profiler``,
-the captured xplane's ``XLA Ops`` timeline is mapped back onto the
+the ``XLA Ops`` timeline of the captured xplane's slowest device plane
+(:func:`slowest_plane`: every plane is read) is mapped back onto the
 negotiated collective schedule, and the spans are written into the Chrome
-timeline with **device** timestamps — no ``block_until_ready`` distortion
-of the step being measured (the old host mode forced exactly that).
+timeline with **device** timestamps — no
+``block_until_ready`` distortion of the steps being measured (the old host
+mode forced exactly that; only the sampled execution is waited for).
 
 Mapping rules (pure, unit-tested):
 
@@ -27,7 +29,8 @@ Mapping rules (pure, unit-tested):
   device). (A heuristic: XLA may fuse packs away entirely, in which case
   no span is emitted — the timeline reports what the device actually
   ran.)
-* the whole execution appears as ``DEVICE_STEP`` on the ``_device`` row.
+* the whole execution appears as ``DEVICE_STEP`` on the ``_device`` row,
+  its longest idle gaps as ``IDLE [<hvd/* span covering most of it>]``.
 """
 
 from __future__ import annotations
@@ -62,8 +65,27 @@ _UNPACK_BASES = {"slice", "dynamic-slice"}
 
 
 def hlo_base(name: str) -> str:
-    """HLO opcode from an ``XLA Ops`` event name (``%all-reduce-start.1 =
-    ...`` → ``all-reduce-start``)."""
+    """HLO opcode of an ``XLA Ops`` event. Its name is the instruction's
+    text, ``%psum.168 = f32[8]{0} all-reduce(f32[8]{0} %x), ...``: the
+    opcode follows the result's shape and is NOT the instruction's name
+    (the JAX primitive's: on the chip an all-reduce is ``%psum.168``). A
+    bare name (``fusion.12``) gives its own stem. Held to
+    ``benchmark/trace.py``'s answers by tests/test_tracing.py."""
+    _, eq, rest = name.partition(" = ")
+    if eq:
+        rest = rest.lstrip()
+        if rest.startswith("("):  # a tuple shape: skip to its closing paren
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    rest = rest[i + 1:]
+                    break
+        else:
+            rest = rest.partition(" ")[2]
+        m = re.match(r"\s*([a-zA-Z][\w-]*)\(", rest)
+        if m:
+            return m.group(1)
     m = re.match(r"%?([a-zA-Z][a-zA-Z0-9_-]*?)[.\d]*(\s*=|$)", name)
     return m.group(1) if m else name
 
@@ -79,32 +101,68 @@ def _planes(trace_dir: str):
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                             recursive=True))
+                             recursive=True), key=os.path.getmtime)
     if not paths:
         return []
     return list(ProfileData.from_file(paths[-1]).planes)
 
 
-def device_op_events(trace_dir: str):
-    """[(name, start_us, dur_us)] from the xplane's device ``XLA Ops``
-    line, sorted by start; [] when the trace has no device plane (CPU).
-
-    Reads the FIRST device plane that carries an op timeline: a
-    single-controller world runs one program on every local chip, so on a
-    multi-chip host this is chip 0's timeline and the others are not
-    consulted."""
-    out = []
+def device_planes(trace_dir: str) -> dict:
+    """{plane name: [(name, start_us, dur_us)] by start} for EVERY device
+    plane with an ``XLA Ops`` line (one a chip; auxiliary device planes
+    carry none); {} when the trace has no device plane (CPU)."""
+    out = {}
     for plane in _planes(trace_dir):
-        if not plane.name.startswith("/device:"):
-            continue
-        ops_line = next((ln for ln in plane.lines if ln.name == "XLA Ops"),
-                        None)
-        if ops_line is None:
-            continue  # auxiliary device planes carry no op timeline
-        for ev in ops_line.events:
-            out.append((ev.name, ev.start_ns / 1e3, ev.duration_ns / 1e3))
-        break
-    out.sort(key=lambda t: t[1])
+        if plane.name.startswith("/device:"):
+            for ln in plane.lines:
+                if ln.name == "XLA Ops":
+                    out[plane.name] = sorted(
+                        ((ev.name, ev.start_ns / 1e3, ev.duration_ns / 1e3)
+                         for ev in ln.events), key=lambda t: t[1])
+    return out
+
+
+def slowest_plane(planes: dict) -> list:
+    """Events of the ONE device plane a reader reports (a single-controller
+    world runs one program on every chip, and the slowest holds the step):
+    most time in collectives, then the longest window. [] where the
+    capture has no device plane."""
+    def cost(events):
+        coll = sum(d for n, _, d in events if is_collective(hlo_base(n)))
+        return coll, _window_us(events)
+
+    return max(planes.values(), key=cost, default=[])
+
+
+def _window_us(events) -> float:
+    return max(s + d for _, s, d in events) - min(s for _, s, _ in events)
+
+
+def host_spans(trace_dir: str, prefix: str = "hvd/"):
+    """[(name, start_us, end_us)] of the host planes' events named
+    ``prefix``... (``core/timeline.span``), on the device planes' clock."""
+    return sorted(
+        (ev.name, ev.start_ns / 1e3, (ev.start_ns + ev.duration_ns) / 1e3)
+        for plane in _planes(trace_dir) if plane.name.startswith("/host:")
+        for ln in plane.lines for ev in ln.events
+        if ev.name.startswith(prefix))
+
+
+def idle_spans(events, host, k: int = 10):
+    """The ``k`` longest gaps between one plane's events, each named by
+    the host span that covers most of it (``none`` where none does):
+    ``[("_device", "IDLE [<span>]", start_us, dur_us)]``."""
+    gaps, end = [], None
+    for _, s, d in sorted(events, key=lambda t: t[1]):
+        if end is not None and s > end:
+            gaps.append((s - end, end))
+        end = max(s + d, end or 0.0)
+    out = []
+    for dur, lo in sorted(gaps, reverse=True)[:k]:
+        cover, best = max(((min(e, lo + dur) - max(s, lo), n)
+                           for n, s, e in host), default=(0.0, "none"))
+        out.append(("_device", f"IDLE [{best if cover > 0 else 'none'}]",
+                    lo, dur))
     return out
 
 
@@ -115,8 +173,9 @@ def timed_steps(run_once, steps: int, trials: int = 3,
     via a scalar transfer).
 
     When the programs run on a TPU (``core/state.target_platform``): the
-    device op-timeline window (max end − min start of ``XLA Ops`` events)
-    of a profiler capture — what the chip spent, without host dispatch.
+    device op-timeline window (max end − min start of ``XLA Ops`` events,
+    on the device plane where it is longest) of a profiler capture — what
+    the chip spent, without host dispatch.
     A TPU capture with no device op timeline RAISES, naming the planes it
     did find: a host-clocked number must never appear where a device
     number is expected. Elsewhere: wall clock.
@@ -152,8 +211,8 @@ def timed_steps(run_once, steps: int, trials: int = 3,
                                 (time.perf_counter() - t0) / steps)
             finally:
                 jax.profiler.stop_trace()
-            evs = device_op_events(d)
-            if not evs:
+            planes = device_planes(d)
+            if not planes:
                 found = {pl.name: [ln.name for ln in pl.lines]
                          for pl in _planes(d)}
                 raise RuntimeError(
@@ -163,17 +222,22 @@ def timed_steps(run_once, steps: int, trials: int = 3,
                     f"found: {found}")
         finally:
             shutil.rmtree(d, ignore_errors=True)
-        start = min(s for _, s, _ in evs)
-        end = max(s + dur for _, s, dur in evs)
-        best = min(best, (end - start) / 1e6 / steps)
+        window_us = max(_window_us(evs) for evs in planes.values())
+        best = min(best, window_us / 1e6 / steps)
     if info is not None:
         info["timing"] = "device" if on_tpu else "host"
         info["host_s"] = host_best
     return best if on_tpu else host_best
 
 
+def is_collective(base: str) -> bool:
+    return any(base == c or base.startswith(c + "-") for c in _COLL_KIND)
+
+
 def _merge_async(events):
-    """Merge ``-start``/``-done`` pairs into one span; pass others through.
+    """Each COLLECTIVE's ``-start``/``-done`` pair as one span from the
+    start's beginning to the done's end; everything else passes through
+    (between a ``slice-start`` and its done the device does other work).
 
     Returns [(base, start_us, end_us)] sorted by start.
     """
@@ -181,11 +245,11 @@ def _merge_async(events):
     pending = {}  # instr suffix key → (base, start)
     for name, start, dur in events:
         base = hlo_base(name)
-        if base.endswith("-start"):
-            key = _instr_key(name).replace("-start", "")
-            pending[key] = (base[:-6], start)
+        if is_collective(base) and base.endswith("-start"):
+            pending[_instr_key(name).replace("-start", "")] = (base[:-6],
+                                                               start)
             continue
-        if base.endswith("-done"):
+        if is_collective(base) and base.endswith("-done"):
             key = _instr_key(name).replace("-done", "")
             if key in pending:
                 b, s = pending.pop(key)
@@ -204,7 +268,8 @@ def map_device_spans(schedule, events):
     """Map xplane events onto the negotiated schedule.
 
     ``schedule``: [[name, op, dtype, shape, group, root], ...] in trace
-    order. ``events``: [(hlo_name, start_us, dur_us)] in device order.
+    order. ``events``: ONE plane's [(hlo_name, start_us, dur_us)] in
+    device order (:func:`slowest_plane` of a capture's planes).
     Returns [(row, activity, start_us, dur_us)], device-relative times.
     """
     if not events:

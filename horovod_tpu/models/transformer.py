@@ -282,9 +282,10 @@ class Block(nn.Module):
         x = x + Attention(cfg, name="attn")(y, positions, segment_ids,
                                             kv_view=kv_view)
         y = nn.RMSNorm(dtype=cfg.dtype)(x)
-        y = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, use_bias=False)(y)
-        y = nn.gelu(y)
-        y = nn.Dense(cfg.embed_dim, dtype=cfg.dtype, use_bias=False)(y)
+        with jax.named_scope("mlp"):
+            y = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, use_bias=False)(y)
+            y = nn.gelu(y)
+            y = nn.Dense(cfg.embed_dim, dtype=cfg.dtype, use_bias=False)(y)
         return x + y
 
 
@@ -386,7 +387,7 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
     zigzag = (config.sp_layout == "zigzag"
               and config.attention == "ring")
 
-    def loss_fn(params, batch):
+    def _loss(params, batch):
         tokens = batch  # (B, T_local) int32
         t_local = tokens.shape[1]
         if zigzag:
@@ -418,18 +419,28 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
 
             hidden = model.apply({"params": params}, tokens,
                                  shard_offset=offset, return_hidden=True)
-            w = params["lm_head"]["kernel"].astype(config.dtype)
-            x2 = hidden[:, :-1].reshape(-1, hidden.shape[-1])
-            tgt = tokens[:, 1:].reshape(-1)
-            return fused_cross_entropy(x2, w, tgt,
-                                       chunk=default_chunk(w.shape[1]))
+            with jax.named_scope("head"):
+                w = params["lm_head"]["kernel"].astype(config.dtype)
+                x2 = hidden[:, :-1].reshape(-1, hidden.shape[-1])
+                tgt = tokens[:, 1:].reshape(-1)
+                return fused_cross_entropy(x2, w, tgt,
+                                           chunk=default_chunk(w.shape[1]))
         logits = model.apply({"params": params}, tokens,
                              shard_offset=offset)
-        # Shift within the shard: predict token[t+1] from position t.
-        targets = tokens[:, 1:]
-        pred = logits[:, :-1]
-        loss = optax.softmax_cross_entropy_with_integer_labels(pred, targets)
-        return loss.mean()
+        with jax.named_scope("head"):
+            # Shift within the shard: predict token[t+1] from position t.
+            targets = tokens[:, 1:]
+            pred = logits[:, :-1]
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                pred, targets)
+            return loss.mean()
+
+    def loss_fn(params, batch):
+        # The root of every op_name of the loss, whatever flax calls its
+        # modules: under value_and_grad JAX makes it jvp(hvd.model) for the
+        # forward and transpose(jvp(hvd.model)) for the backward.
+        with jax.named_scope("hvd.model"):
+            return _loss(params, batch)
 
     return loss_fn
 

@@ -98,6 +98,7 @@ def channel_sums(x, interpret: bool | None = None):
             return jnp.sum(xf, axis=0), jnp.sum(xf * xf, axis=0)
     s1, s2 = pl.pallas_call(
         functools.partial(_sums_kernel, nsteps=nsteps),
+        name="hvd_bn_sums",
         grid=(nsteps,),
         in_specs=[pl.BlockSpec((bn, c), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((1, c), lambda i: (0, 0)),
@@ -165,6 +166,7 @@ def channel_grad_sums(dy, x, mean, rstd, interpret: bool | None = None):
             return jnp.sum(dyf, axis=0), jnp.sum(dyf * xhat, axis=0)
     sdy, sdx = pl.pallas_call(
         functools.partial(_grad_sums_kernel, nsteps=nsteps),
+        name="hvd_bn_grad_sums",
         grid=(nsteps,),
         in_specs=[pl.BlockSpec((bn, c), lambda i: (i, 0)),
                   pl.BlockSpec((bn, c), lambda i: (i, 0)),

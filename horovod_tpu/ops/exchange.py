@@ -751,8 +751,9 @@ def exposed_comm_from_spans(comm_spans, compute_spans) -> float:
 
 def measured_exposed_comm_ms(run_once, steps: int = 1) -> float | None:
     """Device-true exposed comm per step: profile one execution, classify
-    device ops into communication (collective opcodes) vs compute
-    (everything else), and return the non-overlapped comm ms via
+    each device's ops into communication (collective opcodes, read from
+    the instruction's text: core/xprof.hlo_base) vs compute (everything
+    else), and return the worst device's non-overlapped comm ms via
     :func:`exposed_comm_from_spans`. None when the capture has no device
     plane (CPU backends) — callers fall back to the planned estimate."""
     import shutil
@@ -769,18 +770,25 @@ def measured_exposed_comm_ms(run_once, steps: int = 1) -> float | None:
             run_once()
         finally:
             jax.profiler.stop_trace()
-        events = _xprof.device_op_events(d)
+        planes = _xprof.device_planes(d)
     finally:
         shutil.rmtree(d, ignore_errors=True)
-    if not events:
+    if not planes:
         return None
+    return max(exposed_comm_ms_of_events(ev) for ev in planes.values()) \
+        / max(1, steps)
+
+
+def exposed_comm_ms_of_events(events) -> float:
+    """Exposed comm ms of one device plane's ``[(name, start_us,
+    dur_us)]`` (core/xprof.device_planes)."""
+    from horovod_tpu.core import xprof as _xprof
+
     comm, compute = [], []
     for name, start, dur in events:
-        base = _xprof.hlo_base(name)
-        base = base.removesuffix("-start").removesuffix("-done")
-        (comm if base in _xprof._COLL_KIND else compute).append(
-            (start, dur))
-    return exposed_comm_from_spans(comm, compute) / 1e3 / max(1, steps)
+        is_comm = _xprof.is_collective(_xprof.hlo_base(name))
+        (comm if is_comm else compute).append((start, dur))
+    return exposed_comm_from_spans(comm, compute) / 1e3
 
 
 # ---------------------------------------------------------------------------

@@ -388,6 +388,7 @@ def _flash_fwd(q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
     lse_rows = block_q // 128 if compact_lse else block_q
     out, lse = pl.pallas_call(
         kernel,
+        name="hvd_flash_fwd",
         grid=(b, h, nq, nk),
         compiler_params=_FWD_SEMANTICS,
         in_specs=in_specs,
@@ -673,6 +674,7 @@ def _flash_bwd(q, k, v, out, lse_c, g_out, qseg, kvseg, causal, sm_scale,
         may_have_dead=may_have_dead, window=window)
     dq_part, dk, dv = pl.pallas_call(
         kernel,
+        name="hvd_flash_bwd",
         grid=(b, nkm, h, nq),
         compiler_params=_BWD_SEMANTICS,
         in_specs=in_specs,

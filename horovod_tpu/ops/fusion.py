@@ -452,6 +452,12 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
         for bucket in buckets:
             tl.event("_fusion_buffer", bucket.describe(), "X")
         tl.end_activity("_fusion_buffer", "SCHEDULE")
+    # The plan is final here: the bytes it will put on the wire a step, a
+    # rank, go into the record of the program being traced
+    # (core/timeline.py; the collectives it becomes are the capture's to
+    # count: XLA's combiner merges them).
+    tl.count_plan("exchange.wire_bytes",
+                  sum(b.bytes_on_wire for b in buckets))
     for bucket in buckets:
         if len(bucket.indices) == 1:
             i = bucket.indices[0]

@@ -38,6 +38,14 @@ from horovod_tpu.utils import costs as _costs
 from horovod_tpu.utils import env as _env
 
 
+# The named scopes every optimizer here traces its two halves under: the
+# leading components of each instruction's op_name in the compiled step
+# (pack, convert, collective, unpack | the inner transformation's pass),
+# by which a profiler capture's device time is told apart.
+EXCHANGE_SCOPE = "hvd.exchange"
+UPDATE_SCOPE = "hvd.update"
+
+
 class ErrorFeedbackState(typing.NamedTuple):
     """Optimizer-state wrapper carrying the error-feedback residual
     pytree alongside the inner optimizer's state. A plain pytree, so the
@@ -591,24 +599,29 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     def update_fn(updates, opt_state, params=None, **kwargs):
         key = kwargs.pop("compression_key", None)
         if error_feedback:
-            updates, new_residual = allreduce_gradients(
+            with jax.named_scope(EXCHANGE_SCOPE):
+                updates, new_residual = allreduce_gradients(
+                    updates, group=group, average=average,
+                    fusion_threshold=fusion_threshold,
+                    compression=compression,
+                    compression_key=key, algo=algo, schedule=schedule,
+                    cross_compression=cross_compression,
+                    error_residual=opt_state.residual,
+                    channels=channels, sparse_algo=sparse_algo)
+            with jax.named_scope(UPDATE_SCOPE):
+                inner_updates, inner_state = optimizer.update(
+                    updates, opt_state.inner, params, **kwargs)
+            return inner_updates, ErrorFeedbackState(inner_state,
+                                                     new_residual)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            updates = allreduce_gradients(
                 updates, group=group, average=average,
                 fusion_threshold=fusion_threshold, compression=compression,
                 compression_key=key, algo=algo, schedule=schedule,
-                cross_compression=cross_compression,
-                error_residual=opt_state.residual,
-                channels=channels, sparse_algo=sparse_algo)
-            inner_updates, inner_state = optimizer.update(
-                updates, opt_state.inner, params, **kwargs)
-            return inner_updates, ErrorFeedbackState(inner_state,
-                                                     new_residual)
-        updates = allreduce_gradients(
-            updates, group=group, average=average,
-            fusion_threshold=fusion_threshold, compression=compression,
-            compression_key=key, algo=algo, schedule=schedule,
-            cross_compression=cross_compression, channels=channels,
-            sparse_algo=sparse_algo)
-        return optimizer.update(updates, opt_state, params, **kwargs)
+                cross_compression=cross_compression, channels=channels,
+                sparse_algo=sparse_algo)
+        with jax.named_scope(UPDATE_SCOPE):
+            return optimizer.update(updates, opt_state, params, **kwargs)
 
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -734,36 +747,39 @@ def sharded_optimizer(optimizer: optax.GradientTransformation,
                 flat = jnp.pad(flat, (0, pad))
             return flat
 
-        gshards, pshards = {}, ({} if pleaves is not None else None)
-        for dt, idx, total, shard_len in buckets:
-            # Reduce in the gradients' own (promoted) dtype — casting bf16ward
-            # BEFORE the sum would accumulate across ranks at bf16 precision,
-            # which the unsharded allreduce path never does. The cast to the
-            # bucket's param dtype happens after the collective. With wire
-            # compression on, reduced-precision accumulation IS the
-            # requested trade (same as the compressed allreduce path).
-            reduce_dt = jnp.result_type(*[leaves[i].dtype for i in idx])
-            gflat = flat_pad(leaves, idx, total, shard_len, reduce_dt)
-            if comp is not None and comp.applies_to(gflat.dtype):
-                wctx = _compression.WireContext(group_size=gsize)
-                with jax.named_scope("QUANTIZE"):
-                    gwire, gmeta = comp.compress(gflat, wctx)
-                gshard = _coll.reducescatter(gwire, group=group)
-                with jax.named_scope("DEQUANTIZE"):
-                    gshard = comp.decompress(gshard, gmeta,
-                                             jnp.dtype(reduce_dt), wctx)
-            else:
-                gshard = _coll.reducescatter(gflat, group=group)
-            if average:
-                gshard = gshard / gsize
-            gshards[dt] = gshard.astype(dt)
-            if pleaves is not None:
-                pflat = flat_pad(pleaves, idx, total, shard_len, dt)
-                pshards[dt] = jax.lax.dynamic_slice_in_dim(
-                    pflat, grank_c * shard_len, shard_len)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            gshards, pshards = {}, ({} if pleaves is not None else None)
+            for dt, idx, total, shard_len in buckets:
+                # Reduce in the gradients' own (promoted) dtype — casting
+                # bf16ward BEFORE the sum would accumulate across ranks at
+                # bf16 precision, which the unsharded allreduce path never
+                # does. The cast to the bucket's param dtype happens after
+                # the collective. With wire compression on,
+                # reduced-precision accumulation IS the requested trade
+                # (same as the compressed allreduce path).
+                reduce_dt = jnp.result_type(*[leaves[i].dtype for i in idx])
+                gflat = flat_pad(leaves, idx, total, shard_len, reduce_dt)
+                if comp is not None and comp.applies_to(gflat.dtype):
+                    wctx = _compression.WireContext(group_size=gsize)
+                    with jax.named_scope("QUANTIZE"):
+                        gwire, gmeta = comp.compress(gflat, wctx)
+                    gshard = _coll.reducescatter(gwire, group=group)
+                    with jax.named_scope("DEQUANTIZE"):
+                        gshard = comp.decompress(gshard, gmeta,
+                                                 jnp.dtype(reduce_dt), wctx)
+                else:
+                    gshard = _coll.reducescatter(gflat, group=group)
+                if average:
+                    gshard = gshard / gsize
+                gshards[dt] = gshard.astype(dt)
+                if pleaves is not None:
+                    pflat = flat_pad(pleaves, idx, total, shard_len, dt)
+                    pshards[dt] = jax.lax.dynamic_slice_in_dim(
+                        pflat, grank_c * shard_len, shard_len)
 
-        upd_shards, new_state = optimizer.update(
-            gshards, opt_state, pshards, **kwargs)
+        with jax.named_scope(UPDATE_SCOPE):
+            upd_shards, new_state = optimizer.update(
+                gshards, opt_state, pshards, **kwargs)
 
         # Subset groups: non-members get zero updates (params hold still —
         # see the docstring; raw-gradient passthrough would be applied
@@ -771,31 +787,33 @@ def sharded_optimizer(optimizer: optax.GradientTransformation,
         program_size = _state.get_group(tctx.group_index).size
         member = None if gsize == program_size else (grank >= 0)
 
-        out = list(leaves)
-        for dt, idx, total, shard_len in buckets:
-            upd = upd_shards[dt]
-            if comp is not None and comp.applies_to(upd.dtype):
-                # The allgather moves each rank's shard once; a bf16 wire
-                # halves it. Deterministic cast only (int8 refused above).
-                wctx = _compression.WireContext(group_size=gsize)
-                with jax.named_scope("QUANTIZE"):
-                    uwire, umeta = comp.compress(upd, wctx)
-                gathered = _coll.allgather(uwire, group=group)
-                with jax.named_scope("DEQUANTIZE"):
-                    full = comp.decompress(gathered, umeta,
-                                           upd.dtype, wctx)[:total]
-            else:
-                full = _coll.allgather(upd, group=group)[:total]
-            off = 0
-            for i in idx:
-                n = int(np.prod(leaves[i].shape))
-                new_leaf = full[off:off + n].reshape(
-                    leaves[i].shape).astype(leaves[i].dtype)
-                if member is not None:
-                    new_leaf = jnp.where(member, new_leaf,
-                                         jnp.zeros_like(new_leaf))
-                out[i] = new_leaf
-                off += n
+        with jax.named_scope(EXCHANGE_SCOPE):
+            out = list(leaves)
+            for dt, idx, total, shard_len in buckets:
+                upd = upd_shards[dt]
+                if comp is not None and comp.applies_to(upd.dtype):
+                    # The allgather moves each rank's shard once; a bf16
+                    # wire halves it. Deterministic cast only (int8 refused
+                    # above).
+                    wctx = _compression.WireContext(group_size=gsize)
+                    with jax.named_scope("QUANTIZE"):
+                        uwire, umeta = comp.compress(upd, wctx)
+                    gathered = _coll.allgather(uwire, group=group)
+                    with jax.named_scope("DEQUANTIZE"):
+                        full = comp.decompress(gathered, umeta,
+                                               upd.dtype, wctx)[:total]
+                else:
+                    full = _coll.allgather(upd, group=group)[:total]
+                off = 0
+                for i in idx:
+                    n = int(np.prod(leaves[i].shape))
+                    new_leaf = full[off:off + n].reshape(
+                        leaves[i].shape).astype(leaves[i].dtype)
+                    if member is not None:
+                        new_leaf = jnp.where(member, new_leaf,
+                                             jnp.zeros_like(new_leaf))
+                    out[i] = new_leaf
+                    off += n
         return jax.tree.unflatten(treedef, out), new_state
 
     return optax.GradientTransformation(init_fn, update_fn)
@@ -900,11 +918,18 @@ def _fsdp_register_plan(mode, leaves, labels, comp, fmesh, topo,
 
 
 def _fsdp_grad_shard(leaf, label, comp, key, fmesh, topo, average):
-    shard, _ = _strategy.lower_fsdp_grad_exchange(
-        leaf, fmesh, label, comp, key, topo=topo)
-    if average:
-        shard = _coll._divide_avg(shard, fmesh.group_size, shard.dtype)
-    return shard
+    with jax.named_scope(EXCHANGE_SCOPE):
+        shard, _ = _strategy.lower_fsdp_grad_exchange(
+            leaf, fmesh, label, comp, key, topo=topo)
+        if average:
+            shard = _coll._divide_avg(shard, fmesh.group_size, shard.dtype)
+        return shard
+
+
+def _fsdp_gather(shard, fmesh, label, topo):
+    with jax.named_scope(EXCHANGE_SCOPE):
+        return _strategy.lower_fsdp_param_gather(shard, fmesh, label,
+                                                 topo=topo)
 
 
 def _fsdp_pad_flat(leaf, padded: int):
@@ -993,9 +1018,10 @@ def sharded_zero2_optimizer(optimizer: optax.GradientTransformation,
                     _fsdp_pad_flat(pleaves[i], P), f_idx * L, L))
         pshard_tree = (jax.tree.unflatten(treedef, pshards)
                        if pshards is not None else None)
-        upd_shards, new_state = optimizer.update(
-            jax.tree.unflatten(treedef, gshards), opt_state,
-            pshard_tree, **kwargs)
+        with jax.named_scope(UPDATE_SCOPE):
+            upd_shards, new_state = optimizer.update(
+                jax.tree.unflatten(treedef, gshards), opt_state,
+                pshard_tree, **kwargs)
         upd_leaves = jax.tree.leaves(upd_shards)
         if fsdp_apply:
             # Shard-side apply, then gather the NEW PARAMS (docstring:
@@ -1005,16 +1031,14 @@ def sharded_zero2_optimizer(optimizer: optax.GradientTransformation,
                 optax.apply_updates(pshard_tree, upd_shards))
             out = []
             for i, pleaf in enumerate(pleaves):
-                full = _strategy.lower_fsdp_param_gather(
-                    new_pshards[i], fmesh, labels[i], topo=topo)
+                full = _fsdp_gather(new_pshards[i], fmesh, labels[i], topo)
                 n = int(np.prod(pleaf.shape))
                 out.append(full[:n].reshape(pleaf.shape)
                            .astype(pleaf.dtype))
             return jax.tree.unflatten(treedef, out), new_state
         out = []
         for i, leaf in enumerate(leaves):
-            full = _strategy.lower_fsdp_param_gather(
-                upd_leaves[i], fmesh, labels[i], topo=topo)
+            full = _fsdp_gather(upd_leaves[i], fmesh, labels[i], topo)
             n = int(np.prod(leaf.shape))
             out.append(full[:n].reshape(leaf.shape).astype(leaf.dtype))
         return jax.tree.unflatten(treedef, out), new_state
@@ -1155,8 +1179,7 @@ class Zero3Optimizer:
         leaves = jax.tree.leaves(param_shards)
         out = [None] * len(leaves)
         for i in self._order:
-            full = _strategy.lower_fsdp_param_gather(
-                leaves[i], fmesh, self._labels[i], topo=topo)
+            full = _fsdp_gather(leaves[i], fmesh, self._labels[i], topo)
             n = int(np.prod(self._shapes[i]))
             out[i] = full[:n].reshape(self._shapes[i])
         return jax.tree.unflatten(self._treedef, out)
@@ -1188,8 +1211,9 @@ class Zero3Optimizer:
                                      self.average)
             gshards.append(shard.astype(self._dtypes[i]))
         gtree = jax.tree.unflatten(self._treedef, gshards)
-        upd_shards, new_state = self.inner.update(
-            gtree, opt_state, param_shards, **kwargs)
+        with jax.named_scope(UPDATE_SCOPE):
+            upd_shards, new_state = self.inner.update(
+                gtree, opt_state, param_shards, **kwargs)
         new_shards = optax.apply_updates(param_shards, upd_shards)
         return new_shards, new_state
 
@@ -1211,13 +1235,15 @@ def broadcast_variables(variables, root_rank: int = 0, group: int = 0):
             lambda t: _coll.broadcast(t, root_rank=root_rank, group=group),
             variables)
 
+    from horovod_tpu.core import timeline as _timeline
     from horovod_tpu.parallel import spmd as _spmd
 
-    broadcast_step = _spmd.spmd(
-        lambda v: jax.tree.map(
-            lambda t: _coll.broadcast(t, root_rank=root_rank, group=group), v),
-        group=group)
-    return broadcast_step(variables)
+    def broadcast_step(v):
+        return jax.tree.map(
+            lambda t: _coll.broadcast(t, root_rank=root_rank, group=group), v)
+
+    with _timeline.span("hvd/broadcast"):
+        return _spmd.spmd(broadcast_step, group=group)(variables)
 
 
 # Alias matching the reference's TF-level name.

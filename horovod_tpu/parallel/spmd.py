@@ -32,6 +32,7 @@ from horovod_tpu.core import state as _state
 from horovod_tpu.core import timeline as _timeline
 from horovod_tpu.core.state import AXIS_NAME, HorovodError
 from horovod_tpu.utils import env as _env
+from horovod_tpu.utils import jax_compat as _compat
 
 
 def spmd(fn: Callable, group: int = 0,
@@ -48,22 +49,19 @@ def spmd(fn: Callable, group: int = 0,
     step where the old state is dead after the update). Donated inputs must
     not be used again by the caller — the step-loop pattern
     ``params, ... = step(params, ...)`` is exactly safe.
+
+    Every program is lowered under ``utils/jax_compat.named_locations``,
+    for every caller and whatever
+    ``jax_include_full_tracebacks_in_locations`` says: each instruction's
+    ``op_name`` holds its whole name stack (what ``core/timeline.record``'s
+    scope map and hvd-lint read) and its location one frame, so the
+    compile cache's key holds no call stack.
     """
     repl = set(replicated_argnums)
-    # One compiled program per (mesh, arg count); jit's own cache handles
-    # shape/dtype changes. Rebuilding shard_map per call would defeat the jit
-    # cache (it is keyed on function identity) and retrace every step.
+    # One compiled program per (init generation, mesh, argument
+    # signature). Rebuilding shard_map per call would defeat the jit cache
+    # (it is keyed on function identity) and retrace every step.
     compiled: dict = {}
-    # Per-key trace-time collective schedule — the rows the timeline
-    # instruments on the compiled hot path (the per-step B/E block at the
-    # end of wrapper()).
-    schedules: dict = {}
-    # Executions per compiled program, for the device-fidelity timeline
-    # mode's sampling policy (first execution always; every N-th when
-    # HOROVOD_TIMELINE_DEVICE_INTERVAL=N — steady-state drift like
-    # donation kicking in or input-bound stalls is invisible to a
-    # first-execution-only sample).
-    device_exec_count: dict = {}
 
     # HOROVOD_XLA_OPTIONS is latched when the step function is wrapped:
     # the compiled-program cache is not keyed on it, so honoring a mid-run
@@ -107,6 +105,10 @@ def spmd(fn: Callable, group: int = 0,
                                  list(tctx.members.get(nm, ()))])
             return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
 
+        # The program carries the user's function's name: the capture's
+        # ``XLA Modules`` line and JAX's compile log say ``jit_train_step``.
+        shard_fn.__name__ = shard_fn.__qualname__ = getattr(
+            fn, "__name__", "shard_fn")
         # check_vma=False: jax 0.9's varying-manual-axes checker does not
         # support axis_index_groups (parallel.py bind_psum_invariant),
         # which grouped collectives — the fork's core feature — depend on.
@@ -116,99 +118,99 @@ def spmd(fn: Callable, group: int = 0,
             donate_argnums=tuple(donate_argnums))
         return jitted, schedule
 
+    def compile_program(g, args, tl, multihost) -> _Program:
+        """Build the program of this argument signature by one of the
+        four compile paths (inside the caller's ``hvd/spmd/build`` span,
+        which also holds its first call: the lazy path compiles there)."""
+        jitted, schedule = build(g, len(args))
+        prog = _Program(jitted, schedule, args)
+        prog.tag = tl.add_program(
+            f"{getattr(fn, '__qualname__', 'fn')}/{len(args)}", prog)
+        tl.building = prog.tag
+        # HOROVOD_XLA_OPTIONS (e.g. pinning the CRS combiner to the
+        # framework's fusion buckets for comm/compute overlap —
+        # docs/tensor-fusion.md) requires the explicit compile path.
+        copts = dict(compiler_options=xla_opts) if xla_opts else {}
+        if multihost:
+            # Explicit lower → validate → compile: every process must
+            # have traced the identical collective schedule BEFORE the
+            # program may execute; a divergence raises on all processes
+            # instead of hanging in a mismatched XLA collective.
+            lowered = jitted.lower(*args)
+            _mh.negotiator().validate_schedule(prog.tag, schedule)
+            prog.call = lowered.compile(**copts)
+        elif tl.active:
+            # With the timeline on, compile explicitly so the trace-time
+            # schedule exists BEFORE the first execution — negotiation
+            # and compilation become visible timeline spans (the analog
+            # of the reference's per-step NEGOTIATE_* phases, hoisted to
+            # compile time like the negotiation itself).
+            prog_row = f"_program/{prog.tag}"
+            tl.start_activity(prog_row, "TRACE_AND_COMPILE")
+            prog.call = jitted.lower(*args).compile(**copts)
+            tl.end_activity(prog_row, "TRACE_AND_COMPILE")
+            for nm, op, *_ in schedule:
+                tl.start_activity(nm, f"NEGOTIATE_{op}")
+                tl.end_activity(nm, f"NEGOTIATE_{op}")
+        elif xla_opts:
+            prog.call = jitted.lower(*args).compile(**copts)
+        return prog
+
+    def dispatch(prog, args, tl):
+        """One call of the program: never waits for the device (the
+        device-fidelity timeline's sampled executions apart)."""
+        n, prog.runs = prog.runs, prog.runs + 1
+        tl.dispatched = True
+        entry = tl.programs.get(prog.tag)
+        if entry is not None:
+            entry["dispatches"] += 1
+            if _compat.profiler_session_active():
+                tl.pin(prog.tag, prog)  # a capture will ask for its scopes
+        with jax.profiler.StepTraceAnnotation("hvd/step", step_num=n), \
+                tl.span("hvd/spmd/dispatch"):
+            if tl.active and tl.device_mode and prog.schedule:
+                # Device-fidelity mode: sample executions under
+                # jax.profiler, map the xplane back onto the schedule
+                # (core/xprof.py), and emit spans with device timestamps.
+                # The first execution is always sampled; with
+                # HOROVOD_TIMELINE_DEVICE_INTERVAL=N every N-th re-samples
+                # so steady-state regressions show up. Unsampled steps
+                # dispatch untouched.
+                interval = _env.timeline_device_interval()
+                if n == 0 or (interval > 0 and n % interval == 0):
+                    return _sample_device_step(tl, prog, args)
+            return prog.call(*args)
+
     @functools.wraps(fn)
     def wrapper(*args):
         g = _state.get_group(group)
-        multihost = _mh.active()
         tl = _timeline.session()
         # The generation component invalidates entries across
         # shutdown()/init() cycles: an equal mesh can carry a different
         # group layout, and the closed-over group index must not replay
-        # against it. Multi-host adds the argument signature: the schedule is
-        # validated per traced program, so each shape signature is its own
-        # entry.
-        key = (_state.generation(), g.mesh, len(args))
-        if multihost or tl.active or xla_opts:
-            # Both paths compile ahead-of-time (schedule validation /
-            # timeline schedule capture), so the executable is pinned to
-            # one argument signature — key on it, where the lazy jit path
-            # would just retrace.
-            key = key + (_args_signature(args),)
-        if key not in compiled:
-            # Programs from earlier init generations can never be hit again;
-            # drop them so shutdown()/init() cycles don't pin dead
-            # executables (host + device memory) in this closure forever.
-            for stale in [k for k in compiled if k[0] != key[0]]:
-                del compiled[stale]
-                schedules.pop(stale, None)
-                device_exec_count.pop(stale, None)
-            jitted, schedule = build(g, len(args))
-            tag = f"{getattr(fn, '__qualname__', 'fn')}/{len(args)}"
-            # HOROVOD_XLA_OPTIONS (e.g. pinning the CRS combiner to the
-            # framework's fusion buckets for comm/compute overlap —
-            # docs/tensor-fusion.md) requires the explicit compile path.
-            copts = dict(compiler_options=xla_opts) if xla_opts else {}
-            if multihost:
-                # Explicit lower → validate → compile: every process must
-                # have traced the identical collective schedule BEFORE the
-                # program may execute; a divergence raises on all processes
-                # instead of hanging in a mismatched XLA collective.
-                lowered = jitted.lower(*args)
-                _mh.negotiator().validate_schedule(tag, schedule)
-                compiled[key] = lowered.compile(**copts)
-            elif tl.active:
-                # With the timeline on, compile explicitly so the trace-time
-                # schedule exists BEFORE the first execution — negotiation
-                # and compilation become visible timeline spans (the analog
-                # of the reference's per-step NEGOTIATE_* phases, hoisted to
-                # compile time like the negotiation itself).
-                prog_row = f"_program/{tag}"
-                tl.start_activity(prog_row, "TRACE_AND_COMPILE")
-                lowered = jitted.lower(*args)
-                compiled[key] = lowered.compile(**copts)
-                tl.end_activity(prog_row, "TRACE_AND_COMPILE")
-            elif xla_opts:
-                compiled[key] = jitted.lower(*args).compile(**copts)
-            else:
-                compiled[key] = jitted
-            schedules[key] = schedule
-            if tl.active:
-                for nm, op, *_ in schedule:
-                    tl.start_activity(nm, f"NEGOTIATE_{op}")
-                    tl.end_activity(nm, f"NEGOTIATE_{op}")
-        sched = schedules.get(key)
-        if tl.active and sched:
-            if tl.device_mode:
-                # Device-fidelity mode: sample executions under
-                # jax.profiler, map the xplane back onto the schedule
-                # (core/xprof.py), and emit spans with device timestamps.
-                # Unsampled steps dispatch untouched — no
-                # block_until_ready distorting what is measured. The first
-                # execution is always sampled; with
-                # HOROVOD_TIMELINE_DEVICE_INTERVAL=N every N-th execution
-                # re-samples so steady-state regressions show up.
-                n = device_exec_count.get(key, 0)
-                device_exec_count[key] = n + 1
-                interval = _env.timeline_device_interval()
-                if n == 0 or (interval > 0 and n % interval == 0):
-                    return _sample_device_step(tl, compiled[key], args,
-                                               sched)
-                return compiled[key](*args)
-            # Host mode: B on every negotiated collective row at dispatch,
-            # E when the step's results are ready — the SPMD analog of
-            # PerformOperation's ACTIVITY_START/END hooks (reference
-            # mpi_ops.cc:741-753). Blocking on the result gives the E
-            # timestamps device-execution meaning; this mode pays dispatch
-            # fidelity for per-step coverage (HOROVOD_TIMELINE_DEVICE=1
-            # trades coverage for device-true timing).
-            for nm, op, *_ in sched:
-                tl.start_activity(nm, f"XLA_{op}")
-            out = compiled[key](*args)
-            jax.block_until_ready(out)
-            for nm, op, *_ in reversed(sched):
-                tl.end_activity(nm, f"XLA_{op}")
-            return out
-        return compiled[key](*args)
+        # against it. The argument signature makes every traced program
+        # its own entry: its build is a span and a count of the record,
+        # its schedule is validated (multi-host) and its executable may
+        # be pinned to one signature (the ahead-of-time paths).
+        key = (_state.generation(), g.mesh, _args_signature(args))
+        prog = compiled.get(key)
+        if prog is not None:
+            return dispatch(prog, args, tl)
+        # Programs from earlier init generations can never be hit again;
+        # drop them so shutdown()/init() cycles don't pin dead
+        # executables (host + device memory) in this closure forever.
+        for stale in [k for k in compiled if k[0] != key[0]]:
+            del compiled[stale]
+        # Lowered with the whole name stack in every op_name and one frame
+        # in every location, whatever the caller's settings: the record's
+        # scope map is read from the names (utils/jax_compat).
+        with tl.span("hvd/spmd/build"), _compat.named_locations():
+            try:
+                prog = compiled[key] = compile_program(g, args, tl,
+                                                       _mh.active())
+                return dispatch(prog, args, tl)
+            finally:
+                tl.building = None
 
     def lower(*args):
         """``jax.stages.Lowered`` of the program ``wrapper(*args)`` traces —
@@ -224,22 +226,61 @@ def spmd(fn: Callable, group: int = 0,
         ``.compile(compiler_options=env.xla_compiler_options())``."""
         from horovod_tpu.ops import collectives as _coll
 
-        with _coll.preserve_auto_names():
+        with _coll.preserve_auto_names(), _compat.named_locations():
             return build(_state.get_group(group), len(args))[0].lower(*args)
 
     wrapper.lower = lower
     return wrapper
 
 
-def _sample_device_step(tl, program, args, sched):
+class _Program:
+    """One compiled program of a wrapper: what a dispatch calls (the
+    jitted function, or the executable an ahead-of-time path compiled),
+    its trace-time collective schedule, and the shapes and shardings of
+    its first call (no arrays) — enough to read its optimized text again
+    without compiling."""
+
+    __slots__ = ("tag", "call", "jitted", "schedule", "structs", "runs",
+                 "__weakref__")
+
+    def __init__(self, jitted, schedule, args):
+        self.call = self.jitted = jitted
+        self.schedule, self.runs = schedule, 0
+        self.structs = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None),
+                weak_type=getattr(a, "weak_type", False))
+            if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+
+    def hlo_text(self) -> str | None:
+        """The optimized program's text, from the executable the first
+        call built (``lower`` and ``compile`` on the first call's shapes
+        and shardings are two cache hits); None where JAX's caches no
+        longer hold it and asking would trace and compile anew."""
+        try:
+            if self.call is not self.jitted:
+                return self.call.as_text()
+            if not _compat.jit_cache_size(self.jitted):
+                return None
+            return self.jitted.lower(*self.structs).compile().as_text()
+        except Exception:  # the record is best effort: shutdown goes on
+            import traceback
+
+            traceback.print_exc()
+            return None
+
+
+def _sample_device_step(tl, prog, args):
     """One profiled execution for the device-fidelity timeline mode.
 
-    Runs the compiled step under ``jax.profiler``, maps the captured
-    ``XLA Ops`` events onto the negotiated schedule (core/xprof.py), and
-    writes the spans with device timestamps anchored at the host clock of
-    the capture start (sub-ms skew; the *relative* device timing is
-    exact). On backends whose profiler has no device plane (CPU) the
-    sample yields no spans — recorded as an instant note on ``_device``.
+    Runs the compiled step under ``jax.profiler``, maps the slowest device
+    plane's ``XLA Ops`` events (core/xprof.slowest_plane) onto the
+    negotiated schedule and writes its spans with device timestamps
+    anchored at the host clock of the capture start (sub-ms skew; the
+    *relative* device timing is exact), each of its idle gaps named by
+    the ``hvd/*`` span that covers most of it. On backends whose
+    profiler has no device plane (CPU) the sample yields no spans —
+    recorded as an instant note on ``_device``.
     """
     import shutil
     import tempfile
@@ -252,15 +293,20 @@ def _sample_device_step(tl, program, args, sched):
         anchor_us = _time.monotonic_ns() / 1e3
         jax.profiler.start_trace(trace_dir)
         try:
-            out = program(*args)
-            jax.block_until_ready(out)
+            out = prog.call(*args)
+            with tl.span("hvd/timeline/wait_sample"):
+                jax.block_until_ready(out)
         finally:
             # A failing step must not leave the global profiler session
             # open — that would break every later start_trace in-process.
             jax.profiler.stop_trace()
-        spans = _xprof.map_device_spans(
-            sched, _xprof.device_op_events(trace_dir))
+        tl.pin(prog.tag, prog)
+        # One plane for every row of the sample: its collectives, its
+        # packs and its idle gaps.
+        events = _xprof.slowest_plane(_xprof.device_planes(trace_dir))
+        spans = _xprof.map_device_spans(prog.schedule, events)
         if spans:
+            spans += _xprof.idle_spans(events, _xprof.host_spans(trace_dir))
             for row, activity, start_us, dur_us in spans:
                 tl.event_at(row, activity, anchor_us + start_us, dur_us)
             # Always-on α–β recalibration: measured collective spans
@@ -269,7 +315,7 @@ def _sample_device_step(tl, program, args, sched):
             # contract — never raises into the timeline path.
             from horovod_tpu.ops import exchange as _exchange
 
-            _exchange.observe_xla_spans(spans, sched)
+            _exchange.observe_xla_spans(spans, prog.schedule)
         else:
             tl.event("_device", "NO_DEVICE_PLANE", "X")
         return out
@@ -278,10 +324,16 @@ def _sample_device_step(tl, program, args, sched):
 
 
 def _args_signature(args):
+    """Hashable (shape, dtype) of every leaf. Computed on every dispatch
+    (≈ 45 µs for the 190 leaves of a training step: half of it
+    ``jax.tree.leaves``), so arrays take the short way and no string is
+    built."""
     leaves = jax.tree.leaves(args)
-    return tuple(
-        (tuple(np.shape(l)), str(getattr(l, "dtype", type(l).__name__)))
-        for l in leaves)
+    try:
+        return tuple([(l.shape, l.dtype) for l in leaves])
+    except AttributeError:  # a Python scalar or list among the leaves
+        return tuple([(l.shape, l.dtype) if hasattr(l, "dtype")
+                      else (np.shape(l), type(l)) for l in leaves])
 
 
 def _global_from_local_rows(g, local_rows_per_leaf):
@@ -317,7 +369,8 @@ def rank_stack(values, group: int = 0):
         raise HorovodError(
             f"rank_stack: expected one value per local member rank "
             f"({nloc}), got {len(values)}.")
-    return _global_from_local_rows(g, values)
+    with _timeline.span("hvd/rank_stack"):
+        return _global_from_local_rows(g, values)
 
 
 def replicate(value, group: int = 0):
@@ -330,7 +383,8 @@ def replicate(value, group: int = 0):
     nloc = len(g.local_member_ranks())
     if nloc == 0:
         return value  # no local members: nothing to place
-    return _global_from_local_rows(g, [value] * nloc)
+    with _timeline.span("hvd/replicate"):
+        return _global_from_local_rows(g, [value] * nloc)
 
 
 def device_put_ranked(value, group: int = 0):

@@ -1,0 +1,463 @@
+"""The training step named from the inside: ``hvd`` scopes in the compiled
+program, ``hvd/*`` spans and each program's plan in the timeline session's
+record,
+the lazy scope map that never compiles, and the program's own trace reader
+(core/xprof.py) on the capture recorded on four chips."""
+
+import collections
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import horovod_tpu as hvd
+from horovod_tpu.analysis import hlo
+from horovod_tpu.core import timeline, xprof
+from horovod_tpu.models import transformer
+from horovod_tpu.ops import exchange, optim
+from horovod_tpu.parallel import spmd as spmd_mod
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RECORDED = os.path.join(ROOT, "benchmark", "tests", "recorded")
+CFG = transformer.TransformerConfig(
+    vocab_size=128, num_layers=2, num_heads=2, embed_dim=32, mlp_dim=64,
+    max_seq_len=16, dtype=jnp.float32)
+
+
+TAG = "_lm_step.<locals>.train_step/3"  # the wrapper's tag of the step
+
+
+def _world4():
+    hvd.shutdown()
+    hvd.init(devices=jax.devices()[:4])
+
+
+def _lm_step(compression=None):
+    """A tiny LM step as the benchmark's runner builds it: (step, params,
+    optimizer state, tokens), rank-stacked over four CPU devices."""
+    params = transformer.init_params(CFG)
+    opt = hvd.DistributedOptimizer(optim.adamw(1e-3),
+                                   compression=compression)
+    loss_fn = transformer.make_loss_fn(CFG, fused_head=True)
+
+    def train_step(p, s, toks):
+        loss, grads = jax.value_and_grad(loss_fn)(p, toks)
+        updates, s = opt.update(grads, s, p)
+        return optax.apply_updates(p, updates), s, hvd.allreduce(loss)
+
+    step = hvd.spmd(train_step, donate_argnums=(0, 1))
+    toks = hvd.rank_stack([
+        (np.arange(32, dtype=np.int32).reshape(2, 16) + r) % 128
+        for r in range(4)])
+    return (step, hvd.replicate(params), hvd.replicate(opt.init(params)),
+            toks, params)
+
+
+@pytest.fixture(scope="module", params=["full", "compact"])
+def compiled_text(request):
+    """The step's optimized text, under JAX's default locations and under
+    the compact ones ``benchmark/run.py`` sets (which alone would leave
+    ``op_name="sin"``: utils/jax_compat.named_locations)."""
+    # (The other half of named_locations, that no call stack reaches the
+    # program's text, is test_lowered_text_holds_no_call_stack.)
+    full = request.param == "full"
+    jax.config.update("jax_include_full_tracebacks_in_locations", full)
+    try:
+        _world4()
+        step, ps, ss, toks, _ = _lm_step()
+        if full:
+            text = step.lower(ps, ss, toks).compile().as_text()
+        else:  # as a run reads it: from the executable the call built
+            step(ps, ss, toks)
+            text = spmd_mod._Program.hlo_text(
+                timeline.session()._texts[TAG]())
+        hvd.shutdown()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    return text
+
+
+@pytest.mark.parametrize("scope", [
+    "jvp(hvd.model)/Transformer/block_0/attn",
+    "transpose(jvp(hvd.model))/Transformer/block_1/mlp",
+    "jvp(hvd.model)/head", "transpose(jvp(hvd.model))/head",
+    "hvd.exchange/MEMCPY_IN_FUSION_BUFFER",
+    "hvd.exchange/MEMCPY_OUT_FUSION_BUFFER", "hvd.exchange/psum",
+    "hvd.update/"])
+def test_scopes_in_the_compiled_step(compiled_text, scope):
+    assert f"/shard_map/{scope}" in compiled_text
+    # flax's own names are kept and not doubled; nothing of the loss is
+    # left under an empty scope
+    assert "jvp()/" not in compiled_text
+    assert "hvd.model/hvd.model" not in compiled_text
+
+
+def test_program_is_named_after_the_users_function(compiled_text):
+    assert compiled_text.startswith("HloModule jit_train_step")
+
+
+def _count(rec, name):
+    return sum(1 for s in rec["spans"] if s[0] == name)
+
+
+def test_record_after_shutdown_holds_spans_and_programs():
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+    n = 4
+    for _ in range(n):
+        ps, ss, loss = step(ps, ss, toks)
+    # builds are the build spans, dispatches the programs' own counts
+    rec = timeline.record()
+    assert _count(rec, "hvd/spmd/build") == 1
+    assert _count(rec, "hvd/spmd/dispatch") == n
+    assert {t: p["dispatches"] for t, p in rec["programs"].items()} == {
+        TAG: n}
+    ps, ss, loss = step(ps, ss, toks[:, :1])  # another shape: a build
+    hvd.shutdown()
+    rec = timeline.record()
+    names = collections.Counter(s[0] for s in rec["spans"])
+    assert names["hvd/init"] == 1 and names["hvd/shutdown"] == 1
+    assert names["hvd/replicate"] == 2 and names["hvd/rank_stack"] == 1
+    assert names["hvd/spmd/build"] == 2
+    assert names["hvd/spmd/dispatch"] == n + 1
+    assert sorted(rec) == ["programs", "spans"]
+    # the first call of a program lies inside its build
+    parents = [s[3] for s in rec["spans"] if s[0] == "hvd/spmd/dispatch"]
+    assert parents == ["hvd/spmd/build"] + [None] * (n - 1) + [
+        "hvd/spmd/build"]
+    assert all(s[1] <= s[2] for s in rec["spans"])
+    assert rec["programs"][TAG]["dispatches"] == n
+    assert rec["programs"][TAG + "#2"]["dispatches"] == 1
+    import json
+
+    json.dumps(rec)  # plain data
+    hvd.init()  # the next world starts a new record
+    assert [s[0] for s in timeline.record()["spans"]] == ["hvd/init"]
+    hvd.shutdown()
+
+
+@pytest.mark.parametrize("compression,share", [(None, 1.0), ("bf16", 0.5)])
+def test_exchange_wire_bytes_are_the_plan(compression, share):
+    _world4()
+    step, ps, ss, toks, params = _lm_step(compression)
+    step(ps, ss, toks)
+    nbytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
+    counters = timeline.record()["programs"][TAG]["counters"]
+    hvd.shutdown()
+    assert counters == {"exchange.wire_bytes": int(nbytes * share)}
+
+
+def test_lowered_text_holds_no_call_stack():
+    """``hvd.spmd`` lowers with the names in and ONE frame a location,
+    whatever the caller set: two call sites give the same text, so the
+    compile cache's key holds no call stack (PERF.md, PR 21 and PR 25)."""
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+
+    def text():
+        return step.lower(ps, ss, toks).compiler_ir().operation.get_asm(
+            enable_debug_info=True)
+
+    def from_deeper():
+        return text()
+
+    try:
+        for full in (True, False):
+            jax.config.update("jax_include_full_tracebacks_in_locations",
+                              full)
+            here = text()
+            assert here == from_deeper()
+            assert "hvd.exchange/psum" in here and "callsite(" not in here
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", True)
+        hvd.shutdown()
+
+
+@pytest.fixture
+def compile_events():
+    """Names of JAX's lowering and backend-compile events while the test
+    runs."""
+    from jax._src import monitoring
+
+    seen = []
+
+    def listen(name, _secs, **_kw):
+        if name.endswith(("jaxpr_to_mlir_module_duration",
+                          "backend_compile_duration")):
+            seen.append(name)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    yield seen
+    monitoring.unregister_event_duration_listener(listen)
+
+
+def test_scope_map_resolves_without_compiling(compile_events):
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+    ps, ss, _ = step(ps, ss, toks)
+    assert compile_events  # the first call did compile
+    del compile_events[:]
+    scopes = timeline.record(scopes=True)["programs"][TAG][
+        "scopes"]
+    hvd.shutdown()
+    assert compile_events == []
+    phases = collections.Counter()
+    for op_name, members in scopes.values():
+        for name in members or [op_name]:
+            for scope in ("transpose(jvp(hvd.model))", "jvp(hvd.model)",
+                          "hvd.exchange", "hvd.update"):
+                if f"/{scope}/" in name:
+                    phases[scope] += 1
+                    break
+    assert all(phases[s] for s in ("transpose(jvp(hvd.model))",
+                                   "jvp(hvd.model)", "hvd.exchange",
+                                   "hvd.update")), phases
+    names = {v[0] for v in scopes.values()}
+    assert any(n.endswith("/shard_map/hvd.exchange/psum") for n in names)
+    # the loss's own all-reduce lies outside the exchange
+    assert any(n.endswith("/shard_map/psum") for n in names)
+
+
+def test_nobody_pays_who_is_not_looking(monkeypatch):
+    """No profiler session, no HOROVOD_TIMELINE: the run resolves no
+    scope map and never asks a program for its text."""
+    asked = []
+    monkeypatch.setattr(spmd_mod._Program, "hlo_text",
+                        lambda self: asked.append(self.tag))
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+    for _ in range(2):
+        ps, ss, _ = step(ps, ss, toks)
+    hvd.shutdown()
+    assert asked == []
+    assert timeline.record()["programs"][TAG]["scopes"] is None
+
+
+def test_profiled_program_is_resolved_at_shutdown(tmp_path, compile_events):
+    """A dispatch under a profiler session pins the program: its map is
+    read at ``hvd.shutdown()`` though the wrapper is long gone — and
+    after ``jax.clear_caches()`` it gives up rather than compile."""
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+    other = hvd.spmd(lambda x: hvd.allreduce(x) + 1.0)
+    x = hvd.replicate(jnp.ones((8,)))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ps, ss, loss = step(ps, ss, toks)
+        jax.block_until_ready(loss)
+    finally:
+        jax.profiler.stop_trace()
+    x = other(x)  # never profiled: held weakly, dropped with its wrapper
+    del step, other
+    del compile_events[:]
+    hvd.shutdown()
+    programs = timeline.record()["programs"]
+    assert programs[TAG]["scopes"]
+    assert programs["test_profiled_program_is_resolved_at_shutdown."
+                    "<locals>.<lambda>/1"]["scopes"] is None
+
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+    ps, ss, _ = step(ps, ss, toks)
+    jax.clear_caches()
+    del compile_events[:]
+    assert timeline.record(scopes=True)["programs"][TAG][
+        "scopes"] is None
+    assert compile_events == []
+    hvd.shutdown()
+
+
+def test_ring_keeps_setup_and_the_newest_spans():
+    tl = timeline.Timeline()
+    with tl.span("hvd/init"):
+        with tl.span("hvd/init/native_build"):
+            pass
+    tl.dispatched = True
+    for _ in range(timeline.RING_SPANS + 100):
+        with tl.span("hvd/spmd/dispatch"):
+            pass
+    spans = tl.record()["spans"]
+    assert [s[0] for s in spans[:2]] == ["hvd/init/native_build",
+                                         "hvd/init"]
+    assert spans[0][3] == "hvd/init" and spans[1][3] is None
+    assert len(spans) == 2 + timeline.RING_SPANS
+    tl.count_plan("exchange.wire_bytes", 2)  # no program being traced
+    assert tl.record()["programs"] == {}
+
+
+def test_scope_map_joins_fusions_to_their_members():
+    text = """HloModule jit_step, is_scheduled=true
+
+%region_0.0 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0), metadata={op_name="hvd.exchange/psum"}
+  %b = f32[] parameter(1)
+  ROOT %add.0 = f32[] add(%a, %b), metadata={op_name="hvd.exchange/add"}
+}
+
+%fused_computation.4 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %mul.1 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/shard_map/transpose(jvp(hvd.model))/mlp/mul" stack_frame_id=3}
+  ROOT %add.9 = f32[8]{0} add(%mul.1, %p), metadata={op_name="jit(step)/shard_map/hvd.update/add"}
+}
+
+ENTRY %main.0_spmd (param.2: f32[8]) -> f32[8] {
+  %param.2 = f32[8]{0} parameter(0), metadata={op_name="w"}
+  %psum.7 = f32[8]{0} all-reduce(%param.2), channel_id=1, to_apply=%region_0.0, metadata={op_name="jit(step)/shard_map/hvd.exchange/psum"}
+  %hvd_flash_fwd.1 = (f32[8]{0}, f32[8]{0}) custom-call(%psum.7), custom_call_target="tpu_custom_call"
+  ROOT %fusion.3 = f32[8]{0} fusion(%psum.7), kind=kLoop, calls=%fused_computation.4, metadata={op_name="jit(step)/shard_map/hvd.update/add"}
+}
+"""
+    scopes = hlo.scope_map(text)
+    assert scopes["psum.7"] == ["jit(step)/shard_map/hvd.exchange/psum", []]
+    assert scopes["hvd_flash_fwd.1"] == ["", []]
+    assert scopes["fusion.3"] == [
+        "jit(step)/shard_map/hvd.update/add",
+        ["jit(step)/shard_map/transpose(jvp(hvd.model))/mlp/mul",
+         "jit(step)/shard_map/hvd.update/add"]]
+    assert "mul.1" not in scopes and "add.0" in scopes
+
+
+# --- the program's reader on the capture recorded on four v5e chips --------
+
+SCHED3 = [[f"HorovodAllreduce_{i}", "ALLREDUCE", "float32", [512, 512], 0,
+           -1] for i in range(3)]
+
+
+def test_xprof_reads_every_plane_and_maps_the_allreduces():
+    planes = xprof.device_planes(RECORDED)
+    assert sorted(planes) == [f"/device:TPU:{i}" for i in range(4)]
+    assert all(len(ev) == 9 for ev in planes.values())
+    # on the chip an all-reduce is named %psum_invariant.7: the opcode is
+    # read from the instruction's text
+    slowest = xprof.slowest_plane(planes)
+    assert slowest in planes.values() and xprof.slowest_plane({}) == []
+    spans = xprof.map_device_spans(SCHED3, slowest)
+    reduces = [s for s in spans if s[1] == "XLA_ALLREDUCE"]
+    assert [s[0] for s in reduces] == [r[0] for r in SCHED3]
+    assert all(s[3] > 0 for s in reduces)
+    # ... and the plane with most collective time is the one written
+    total = lambda sp: sum(s[3] for s in sp if s[1] == "XLA_ALLREDUCE")
+    assert total(spans) == max(
+        total(xprof.map_device_spans(SCHED3, ev)) for ev in planes.values())
+
+
+def test_device_mode_writes_one_planes_rows(tmp_path, monkeypatch):
+    """``_sample_device_step`` on the four-chip capture (the profiler
+    stands aside: its capture is the recorded one): the collectives, the
+    step and its idle gaps on the ``_device`` row are ONE plane's — every
+    row lies inside that plane's ``DEVICE_STEP``."""
+    import json
+    import shutil
+    import types
+
+    monkeypatch.setenv("HOROVOD_TIMELINE_DEVICE", "1")
+    monkeypatch.setattr(
+        jax.profiler, "start_trace", lambda d: shutil.copy(
+            os.path.join(RECORDED, "tiny_dp4.xplane.pb"), d))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    tl = timeline.Timeline()
+    path = str(tmp_path / "tl.json")
+    tl.start(path)
+    prog = types.SimpleNamespace(call=lambda x: x, schedule=SCHED3,
+                                 tag="step/1")
+    assert spmd_mod._sample_device_step(tl, prog, (jnp.ones(2),)) is not None
+    tl.stop()
+    events = [e for e in json.loads(open(path).read().rstrip().rstrip(",")
+                                    + "]") if e.get("ph") == "X"]
+    names = collections.Counter(e["name"] for e in events)
+    assert names["XLA_ALLREDUCE"] == 3 and names["DEVICE_STEP"] == 1
+    assert any(n.startswith("IDLE [") for n in names)
+    step = next(e for e in events if e["name"] == "DEVICE_STEP")
+    planes = xprof.device_planes(RECORDED)
+    slowest = xprof.slowest_plane(planes)
+    assert step["dur"] == pytest.approx(
+        max(s + d for _, s, d in slowest) - min(s for _, s, _ in slowest),
+        abs=2e-3)
+    for e in events:
+        assert step["ts"] - 1e-3 <= e["ts"] and \
+            e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 2e-3, e
+
+
+def test_exposed_comm_sees_the_collectives_of_the_capture():
+    for events in xprof.device_planes(RECORDED).values():
+        reduce_us = sum(d for n, _, d in events
+                        if xprof.hlo_base(n) == "all-reduce")
+        # nothing runs beside a synchronous all-reduce: all of it exposed
+        assert reduce_us > 0
+        assert exchange.exposed_comm_ms_of_events(events) == pytest.approx(
+            reduce_us / 1e3)
+
+
+def test_both_hlo_bases_agree_on_the_recorded_names():
+    spec = importlib.util.spec_from_file_location(
+        "bench_trace", os.path.join(ROOT, "benchmark", "trace.py"))
+    bench_trace = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench_trace)
+    names = {n for ev in xprof.device_planes(RECORDED).values()
+             for n, _, _ in ev}
+    names |= {
+        "fusion.12", "%all-reduce-start.3 = f32[4]", "%all-reduce-done.3",
+        "%psum.168 = f32[150994944]{0:T(1024)} all-reduce(f32[150994944]"
+        "{0:T(1024)} %bitcast.447), channel_id=3",
+        "%shard_map.1704 = (f32[2,1,24,8192,128]{4,3,2,1,0:T(8,128)}, "
+        "bf16[1,2,8192,128]{3,2,1,0:T(8,128)(2,1)}) custom-call("
+        "bf16[1,24,8192,128]{3,2,1,0} %x), "
+        'custom_call_target="tpu_custom_call"',
+        "%slice-start.9 = (f32[8], f32[4]) slice-start(f32[8] %x)"}
+    assert len(names) >= 9
+    for name in names:
+        assert xprof.hlo_base(name) == bench_trace.hlo_base(name), name
+    assert {xprof.hlo_base(n) for n in names} >= {
+        "all-reduce", "fusion", "custom-call", "all-reduce-start"}
+
+
+def test_idle_gaps_are_named_by_the_span_that_covers_them():
+    events = [("%fusion.1 = f32[8] fusion(...)", 0.0, 100.0),
+              ("%while.2 = f32[8] while(...)", 150.0, 100.0),
+              ("%fusion.3 = f32[8] fusion(...)", 160.0, 20.0),  # nested
+              ("%fusion.4 = f32[8] fusion(...)", 260.0, 10.0)]
+    host = [("hvd/spmd/dispatch", 90.0, 120.0),
+            ("hvd/timeline/wait_sample", 120.0, 400.0)]
+    assert xprof.idle_spans(events, host) == [
+        ("_device", "IDLE [hvd/timeline/wait_sample]", 100.0, 50.0),
+        ("_device", "IDLE [hvd/timeline/wait_sample]", 250.0, 10.0)]
+    assert xprof.idle_spans(events, [], k=1) == [
+        ("_device", "IDLE [none]", 100.0, 50.0)]
+
+
+@pytest.mark.parametrize("kwargs", [dict(sharded=True),
+                                    dict(sharding="zero2")],
+                         ids=["zero1", "zero2"])
+def test_sharded_optimizers_trace_their_halves_under_the_scopes(kwargs):
+    """The reduce and the gather half of a sharded optimizer are the
+    exchange, the inner transformation's pass the update."""
+    hvd.shutdown()
+    hvd.init()
+    opt = hvd.DistributedOptimizer(optax.adam(1e-3), **kwargs)
+    params = {"w": jnp.ones((16, 8), jnp.float32),
+              "b": jnp.ones((8,), jnp.float32)}
+
+    def step(p, s, x):
+        grads = jax.grad(lambda p: jnp.sum((x @ p["w"] + p["b"]) ** 2))(p)
+        updates, s = opt.update(grads, s, p)
+        return optax.apply_updates(p, updates), s
+
+    state = jax.eval_shape(opt.init, params)
+    stack = lambda t: jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((8,) + l.shape, l.dtype), t)
+    text = hvd.spmd(step).lower(
+        stack(params), stack(state),
+        jax.ShapeDtypeStruct((8, 4, 16), jnp.float32)).as_text(
+            debug_info=True)
+    hvd.shutdown()
+    scoped = [ln for ln in text.splitlines() if "hvd.exchange" in ln]
+    assert any("reduce_scatter" in ln or "psum_scatter" in ln
+               for ln in scoped), scoped[:5]
+    assert any("all_gather" in ln for ln in scoped)
+    assert "hvd.update/" in text
+    assert "hvd.exchange/hvd.update" not in text
+    assert "hvd.update/hvd.exchange" not in text

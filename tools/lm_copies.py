@@ -52,7 +52,7 @@ def main() -> None:
     params, opt_state, loss = step(params, opt_state, tokens)
     float(np.asarray(loss))
     jax.profiler.stop_trace()
-    evs = xprof.device_op_events(d)
+    evs = xprof.slowest_plane(xprof.device_planes(d))
     agg = collections.Counter()
     for name, _, dur in evs:
         base = xprof.hlo_base(name)

@@ -64,7 +64,7 @@ def main() -> None:
     params, opt_state, loss = step(params, opt_state, tokens)
     float(np.asarray(loss))
     jax.profiler.stop_trace()
-    evs = xprof.device_op_events(d)
+    evs = xprof.slowest_plane(xprof.device_planes(d))
     if not evs:
         print("no device plane — run on TPU")
         return

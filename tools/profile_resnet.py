@@ -113,7 +113,7 @@ def analyze(trace_dir: str, top: int = 15,
     # Pick the first device plane that actually carries an op timeline —
     # auxiliary device planes (e.g. a TPU backend initialized by an
     # earlier test in the process) have no "XLA Ops" line (the same rule
-    # core/xprof.device_op_events applies).
+    # core/xprof.device_planes applies).
     plane = ops_line = None
     for cand in device_planes:
         ops_line = next((ln for ln in cand.lines if ln.name == "XLA Ops"),

@@ -24,7 +24,7 @@ def main() -> None:
     jax.profiler.start_trace(d)
     run_once()
     jax.profiler.stop_trace()
-    evs = xprof.device_op_events(d)
+    evs = xprof.slowest_plane(xprof.device_planes(d))
     agg = collections.Counter()
     for name, _, dur in evs:
         base = xprof.hlo_base(name)
