@@ -41,7 +41,7 @@ class TraceContext:
     # different-metadata within one traced program.
     names: dict = dataclasses.field(default_factory=dict)
     # name -> tuple of member-tensor labels, for collectives that carry a
-    # fusion bucket (fused_apply packs several gradients into one flat
+    # fusion bucket (fused_apply reduces several gradients in one
     # allreduce); lets the device timeline map the bucket span back onto
     # its member rows. Not part of the metadata compare: a re-trace with
     # the same collective keeps the first registration's members.

@@ -14,7 +14,8 @@ mapped to their XLA equivalents:
     NEGOTIATE_<OP>           request submitted → all ranks matched
     QUEUE                    host-side dispatch queueing
     SCHEDULE                 fusion planning / bucket assembly
-    MEMCPY_IN_FUSION_BUFFER  pack into the flat fusion buffer
+    MEMCPY_IN_FUSION_BUFFER  pack into the flat fusion buffer (packed
+                             buckets only: ops/fusion.py Bucket.packed)
     QUANTIZE                 bucket → wire dtype (gradient compression,
                              ops/compression.py; trace-time stamp like
                              SCHEDULE — the device span carries the same

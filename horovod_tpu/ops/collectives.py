@@ -393,8 +393,8 @@ def _compressed_psum(x, comp, key, gsize, member, name, members=None,
     instance). Channelization composes with every compression shape —
     quantization always runs once, bucket-level, exactly as at
     ``channels=1``; only the wire movement splits."""
-    contrib = x if member is None else jnp.where(member, x,
-                                                 jnp.zeros_like(x))
+    contrib = x if member is None else jax.tree.map(
+        lambda v: jnp.where(member, v, jnp.zeros_like(v)), x)
     intra_comp, cross_comp, asym = _compression.resolve_phase_formats(
         comp, cross_spec)
     if algo == "hierarchical" and asym:
@@ -404,7 +404,7 @@ def _compressed_psum(x, comp, key, gsize, member, name, members=None,
         return _strategy.lower_hierarchical_asym(
             contrib, topo, name, intra_comp, cross_comp,
             _bucket_key(key, members, name), channels=channels)
-    if comp is None or not comp.applies_to(x.dtype):
+    if comp is None or not comp.applies_to(_leaf_dtype(x)):
         _compression.record_local(None)  # exact contribution
         return _strategy.lower_allreduce(contrib, algo, name, topo, gsize,
                                          channels=channels)
@@ -445,11 +445,32 @@ def _compressed_psum(x, comp, key, gsize, member, name, members=None,
     return out
 
 
+def _leaf_dtype(x):
+    """The dtype of one array, or of a bucket's leaves (one dtype a
+    bucket: ops/fusion.py)."""
+    return jax.tree.leaves(x)[0].dtype
+
+
 def _traced_allreduce(tctx, x, group, average, name, comp=None, key=None,
                       members=None, algo="flat", cross_spec=None,
                       channels=1):
+    """``x``: one array, or the tuple of a plain-sum fusion bucket's
+    leaves in their own shapes (ops/fusion.py ``fused_apply``): a sum
+    adds elementwise whatever the shape, so the tuple goes through the
+    same masks and divides leaf by leaf and ONE ``lax.psum`` (this JAX
+    binds a ``psum`` a leaf, adjacent; XLA's combiner merges them). A
+    wire that cuts or scales one flat buffer has no such form and
+    refuses a tuple."""
+    leaves = jax.tree.leaves(x)
+    dtype, size = leaves[0].dtype, sum(v.size for v in leaves)
+    applies = comp is not None and comp.applies_to(dtype)
+    if isinstance(x, tuple) and (applies or algo != "flat" or channels != 1):
+        raise HorovodError(
+            f"allreduce of a tuple of leaves (tensor {name}) is the plain "
+            f"sum only: compression, algo={algo!r} and channels={channels} "
+            f"work on one flat buffer (ops/fusion.py packs it).")
     if not _is_group_index(group):
-        if comp is not None and comp.applies_to(x.dtype):
+        if applies:
             raise HorovodError(
                 f"Gradient compression ({comp.name}) does not support "
                 f"group-family allreduce (tensor {name}): the slot-stacked "
@@ -463,9 +484,8 @@ def _traced_allreduce(tctx, x, group, average, name, comp=None, key=None,
         _check_restricted_channels(channels, name)
         return _traced_allreduce_family(tctx, x, tuple(group), average, name)
     positions, gsize = _traced_groups_arg(tctx, group)
-    applies = comp is not None and comp.applies_to(x.dtype)
     wire_nbytes = _compression.wire_bytes(
-        x.size, x.dtype, comp if applies else None, sum_width=gsize)
+        size, dtype, comp if applies else None, sum_width=gsize)
     if positions is None:
         # Price `auto` on what each candidate would actually move: the
         # gather-form flat for unsummable wire (int4), per-phase bytes
@@ -475,10 +495,10 @@ def _traced_allreduce(tctx, x, group, average, name, comp=None, key=None,
         if applies or cross_spec is not None:
             intra_c, cross_c, asym = _compression.resolve_phase_formats(
                 comp, cross_spec)
-            if asym and jnp.issubdtype(jnp.dtype(x.dtype), jnp.floating):
+            if asym and jnp.issubdtype(jnp.dtype(dtype), jnp.floating):
                 select_kw["phase_nbytes"] = (
-                    _compression.wire_bytes(x.size, x.dtype, intra_c),
-                    _compression.wire_bytes(x.size, x.dtype, cross_c))
+                    _compression.wire_bytes(size, dtype, intra_c),
+                    _compression.wire_bytes(size, dtype, cross_c))
             if applies and not comp.summable:
                 select_kw["gather"] = True
         concrete, topo = _strategy.select(
@@ -488,7 +508,7 @@ def _traced_allreduce(tctx, x, group, average, name, comp=None, key=None,
                                   algo=concrete, topo=topo,
                                   cross_spec=cross_spec,
                                   channels=channels)
-        return _divide_avg(summed, gsize, x.dtype) if average else summed
+        return _divide_avg(summed, gsize, dtype) if average else summed
     # Subset group: masked full-axis psum (see _traced_groups_arg for why
     # not replica_groups; phased algos have no uniform partition here, so
     # explicit rs_ag/hierarchical raise and auto degrades to flat).
@@ -499,8 +519,8 @@ def _traced_allreduce(tctx, x, group, average, name, comp=None, key=None,
     member = _traced_member_mask(tctx, group)
     summed = _compressed_psum(x, comp, key, gsize, member, name, members)
     if average:
-        summed = _divide_avg(summed, gsize, x.dtype)
-    return jnp.where(member, summed, x)
+        summed = _divide_avg(summed, gsize, dtype)
+    return jax.tree.map(lambda s, v: jnp.where(member, s, v), summed, x)
 
 
 def _check_restricted_channels(channels: int, name: str) -> None:
@@ -556,12 +576,13 @@ def _traced_allreduce_family(tctx, x, family, average, name):
             member_np[p] = True
             slot_np[p] = si
     idx = lax.axis_index(AXIS_NAME)
+    dtype = _leaf_dtype(x)
     uniform_cover = len(set(sizes)) == 1 and len(seen) == prog.size
     if uniform_cover:
         # XLA replica_groups fast path: uniform covering partition, ONE
         # AllReduce, no extra traffic.
         summed = lax.psum(x, AXIS_NAME, axis_index_groups=groups)
-        return _divide_avg(summed, sizes[0], x.dtype) if average else summed
+        return _divide_avg(summed, sizes[0], dtype) if average else summed
     # Non-uniform or non-covering family: replica_groups would not lower
     # on TPU (see _traced_groups_arg). Slot-stacked masked psum — each
     # rank contributes x into its group's slot of an (n_groups, *shape)
@@ -571,23 +592,29 @@ def _traced_allreduce_family(tctx, x, family, average, name):
     # covering families (the common TP/DP layout) never pay it.
     member = jnp.asarray(member_np)[idx]
     slot = jnp.asarray(slot_np)[idx]
-    buf = jnp.zeros((len(groups),) + x.shape, x.dtype)
-    contrib = jnp.where(member, x, jnp.zeros_like(x))
-    buf = lax.dynamic_update_slice(
-        buf, contrib[None], (slot,) + (jnp.zeros((), jnp.int32),) * x.ndim)
-    all_sums = lax.psum(buf, AXIS_NAME)
-    summed = lax.dynamic_slice(
-        all_sums, (slot,) + (jnp.zeros((), jnp.int32),) * x.ndim,
-        (1,) + tuple(x.shape))[0]
-    if average:
-        if len(set(sizes)) == 1:
-            summed = _divide_avg(summed, sizes[0], x.dtype)
-        else:
-            div = jnp.asarray(div_np)[idx]
-            summed = (summed // div
-                      if jnp.issubdtype(x.dtype, jnp.integer)
-                      else summed / div)
-    return jnp.where(member, summed, x)
+    zero = jnp.zeros((), jnp.int32)
+
+    def stack(v):
+        buf = jnp.zeros((len(groups),) + v.shape, v.dtype)
+        contrib = jnp.where(member, v, jnp.zeros_like(v))
+        return lax.dynamic_update_slice(buf, contrib[None],
+                                        (slot,) + (zero,) * v.ndim)
+
+    def unstack(sums, v):
+        summed = lax.dynamic_slice(sums, (slot,) + (zero,) * v.ndim,
+                                   (1,) + tuple(v.shape))[0]
+        if average:
+            if len(set(sizes)) == 1:
+                summed = _divide_avg(summed, sizes[0], dtype)
+            else:
+                div = jnp.asarray(div_np)[idx]
+                summed = (summed // div
+                          if jnp.issubdtype(dtype, jnp.integer)
+                          else summed / div)
+        return jnp.where(member, summed, v)
+
+    all_sums = lax.psum(jax.tree.map(stack, x), AXIS_NAME)
+    return jax.tree.map(unstack, all_sums, x)
 
 
 def _family_partition(tctx, family, opname):
@@ -674,9 +701,11 @@ def _traced_broadcast(tctx, x, group, root_rank, name):
 
 
 def _divide_avg(x, n: int, dtype):
+    """``x`` (one array, or a bucket's leaves: each where it lies) / n."""
     if jnp.issubdtype(dtype, jnp.integer):
-        return x // n  # reference averages via tf.div → integer division
-    return x / n
+        # reference averages via tf.div → integer division
+        return jax.tree.map(lambda v: v // n, x)
+    return jax.tree.map(lambda v: v / n, x)
 
 
 # ---------------------------------------------------------------------------
@@ -700,10 +729,13 @@ def allreduce(x, group: int = 0, average: bool = True, name: str | None = None,
     itself; see :func:`_traced_allreduce_family`). Traced-only: the family
     form exists for sharded-parameter gradient sync inside compiled steps.
 
-    ``members``: labels of the tensors packed into this call when it is a
-    fusion bucket (set by :func:`horovod_tpu.ops.fusion.fused_apply`) —
-    carried on the trace-time schedule so the device timeline can map a
-    bucket's span back onto its member tensor rows.
+    ``members``: labels of the tensors of this call when it is a fusion
+    bucket (set by :func:`horovod_tpu.ops.fusion.fused_apply`) — carried
+    on the trace-time schedule so the device timeline can map a bucket's
+    span back onto its member tensor rows. A packed bucket arrives as one
+    flat buffer; a plain-sum bucket as the TUPLE of its leaves in their
+    own shapes (traced-only, same dtype, no compression / phased algo /
+    channels): one ``lax.psum`` over the tuple, a tuple returned.
 
     ``compression``: a wire format name (``"bf16"``/``"int8"``) or
     :class:`~horovod_tpu.ops.compression.Compressor` — the collective then
@@ -758,7 +790,11 @@ def allreduce(x, group: int = 0, average: bool = True, name: str | None = None,
     if tctx is not None:
         reg_group = (int(group) if _is_group_index(group)
                      else tuple(group))
-        tctx.register(name, "ALLREDUCE", x.dtype, x.shape, reg_group,
+        # A bucket reduced in its leaves' own shapes is still one row:
+        # its shapes stand where a packed bucket's flat length does.
+        shape = (tuple(v.shape for v in x) if isinstance(x, tuple)
+                 else x.shape)
+        tctx.register(name, "ALLREDUCE", _leaf_dtype(x), shape, reg_group,
                       members=members)
         return _traced_allreduce(tctx, x, group, average, name,
                                  comp, compression_key, members,

@@ -1078,7 +1078,11 @@ def observe_xla_spans(spans, sched_entries) -> None:
             nbytes = wire_by_members.get(members)
             if nbytes is None:
                 shape, dtype = entry[3], entry[2]
-                nbytes = int(np.prod(shape or [1])) * np.dtype(dtype).itemsize
+                # One shape, or a plain-sum bucket's: one a leaf.
+                shapes = (shape if shape and not np.isscalar(shape[0])
+                          else [shape])
+                nbytes = (sum(int(np.prod(s or [1])) for s in shapes)
+                          * np.dtype(dtype).itemsize)
             ch = ch_by_members.get(members, 1)
             if ch > 1:
                 if len(row_spans) < ch:

@@ -5,14 +5,15 @@ and dtype into one flat 64 MB buffer before a single ``MPI_Allreduce``
 (planner at mpi_ops.cc:1604-1637, execution memcpy-in / reduce / memcpy-out at
 :1229-1310), tunable via ``HOROVOD_FUSION_THRESHOLD`` (0 disables). On TPU the
 motivation shifts — XLA already fuses elementwise work — but collective *count*
-still matters: each psum has fixed launch/latency cost on ICI, so flattening a
-pytree of N gradients into ≲threshold-sized flat buffers turns N collectives
-into ceil(total_bytes/threshold) and keeps each transfer large enough to hit
-peak ICI bandwidth.
+still matters: each psum has fixed launch/latency cost on ICI, so grouping a
+pytree of N gradients into ≲threshold-sized buckets turns N collectives into
+ceil(total_bytes/threshold) and keeps each transfer large enough to hit peak
+ICI bandwidth.
 
-The plan is computed host-side at trace time (shapes are static under jit),
-and the pack → psum → unpack all happens inside the compiled program, so XLA
-fuses the packing copies with neighbouring work.
+The plan is computed host-side at trace time (shapes are static under jit).
+The flat buffer itself is built only for a bucket whose wire needs one
+(:attr:`Bucket.packed`), inside the compiled program; a plain-sum bucket is
+one collective over its leaves where they lie.
 """
 
 from __future__ import annotations
@@ -81,8 +82,20 @@ class Bucket:
 
     @property
     def elems(self) -> int:
-        """Logical element count of the packed flat buffer."""
+        """Logical element count of the bucket (of its flat buffer, where
+        one is built)."""
         return self.total_bytes // jnp.dtype(self.dtype).itemsize
+
+    @property
+    def packed(self) -> bool:
+        """Whether the bucket's collective needs the leaves in one flat
+        buffer: a compressed wire scales the bucket as one vector, and
+        ``rs_ag`` / ``hierarchical`` / ``channels > 1`` cut one buffer
+        into shards. A plain sum (``flat``, the leaves' dtype on the
+        wire, one channel) adds elementwise whatever the shape, so it is
+        reduced in the leaves' own shapes (:func:`fused_apply`)."""
+        return (self.algo != "flat" or self.wire_dtype is not None
+                or self.channels != 1)
 
     @property
     def bytes_on_wire(self) -> int:
@@ -373,14 +386,25 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
                 labels: Sequence[str] | None = None, compression=None,
                 algo=None, schedule=None, group_size: int | None = None,
                 cross_compression=None):
-    """Apply ``collective(flat_1d_array) -> flat_1d_array`` bucket-wise.
+    """Apply ``collective`` bucket-wise: one call a bucket.
 
-    Pack each bucket's leaves into one flat buffer (MEMCPY_IN_FUSION_BUFFER,
-    mpi_ops.cc:1240-1259), run the collective once per bucket
-    (mpi_ops.cc:1274), then unpack (MEMCPY_OUT_FUSION_BUFFER, :1281-1302).
+    A bucket that needs the fusion buffer (:attr:`Bucket.packed`: a
+    compressed wire, ``rs_ag`` / ``hierarchical``, ``channels > 1``) is
+    packed into one flat buffer (MEMCPY_IN_FUSION_BUFFER,
+    mpi_ops.cc:1240-1259), ``collective(flat_1d_array) -> flat_1d_array``
+    runs once (mpi_ops.cc:1274), and the result is unpacked
+    (MEMCPY_OUT_FUSION_BUFFER, :1281-1302). A plain-sum bucket is handed
+    over as the tuple of its leaves in their own shapes,
+    ``collective(leaves) -> leaves``: still one call and one row of the
+    schedule, but no buffer is built — on a TPU a tiled gradient is not
+    a flat vector, and each ``reshape(-1)`` is a pass over the leaf.
+    (``lax.psum`` of a tuple is one all-reduce a leaf in the lowered
+    text, adjacent and in the plan's order; XLA's combiner merges them
+    into variadic all-reduces without a copy.) The plan
+    (``threshold_bytes`` or ``schedule``) decides the buckets either way.
 
     ``labels``: one display name per leaf (gradient pytree paths). When
-    given, the collective is invoked as ``collective(flat, members)`` with
+    given, the collective is invoked as ``collective(x, members)`` with
     the bucket's member labels so the schedule (and from it the device
     timeline) records which tensors each bucket carries — the analog of
     the reference timeline showing every fused tensor's own row.
@@ -410,7 +434,7 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
         raise ValueError(
             f"fused_apply: {len(labels)} labels for {len(leaves)} leaves.")
 
-    def run(flat, bucket):
+    def run(x, bucket):
         kwargs = {}
         if labels is not None:
             kwargs["members"] = tuple(labels[i] for i in bucket.indices)
@@ -422,9 +446,7 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
             # leaves channels=1, so plain collectives keep their
             # signature.
             kwargs["channels"] = bucket.channels
-        if not kwargs:
-            return collective(flat)
-        return collective(flat, **kwargs)
+        return collective(x, **kwargs)
 
     out: list[jax.Array | None] = [None] * len(leaves)
     tl = _timeline.session()
@@ -458,28 +480,32 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
     # count: XLA's combiner merges them).
     tl.count_plan("exchange.wire_bytes",
                   sum(b.bytes_on_wire for b in buckets))
+    tl.count_plan("exchange.unpacked_bytes",
+                  sum(b.bytes_on_wire for b in buckets if not b.packed))
     for bucket in buckets:
-        if len(bucket.indices) == 1:
-            i = bucket.indices[0]
-            leaf = leaves[i]
+        parts = [leaves[i] for i in bucket.indices]
+        if not bucket.packed:
+            for i, r in zip(bucket.indices, run(tuple(parts), bucket)):
+                out[i] = r
+            continue
+        if len(parts) == 1:
+            [i], [leaf] = bucket.indices, parts
             out[i] = run(leaf.reshape(-1), bucket).reshape(leaf.shape)
             continue
         with jax.named_scope("MEMCPY_IN_FUSION_BUFFER"):
-            flat = jnp.concatenate(
-                [leaves[i].reshape(-1) for i in bucket.indices], axis=0)
+            flat = jnp.concatenate([p.reshape(-1) for p in parts], axis=0)
         reduced = run(flat, bucket)
         offset = 0
         with jax.named_scope("MEMCPY_OUT_FUSION_BUFFER"):
-            for i in bucket.indices:
-                n = leaves[i].size
-                out[i] = reduced[offset: offset + n].reshape(
-                    leaves[i].shape)
-                offset += n
+            for i, p in zip(bucket.indices, parts):
+                out[i] = reduced[offset: offset + p.size].reshape(p.shape)
+                offset += p.size
     return out
 
 
 def fused_tree_apply(tree, collective, threshold_bytes: int):
-    """Pytree wrapper around :func:`fused_apply`."""
+    """Pytree wrapper around :func:`fused_apply` (its plan's buckets are
+    plain sums: ``collective`` gets each as the tuple of its leaves)."""
     leaves, treedef = jax.tree.flatten(tree)
     return jax.tree.unflatten(
         treedef, fused_apply(leaves, collective, threshold_bytes))

@@ -75,6 +75,14 @@ def allreduce_gradients(grads, group: int = 0, average: bool = True,
     the DP-family sync for tensor-parallel shards; fusion applies as
     usual. Sparse leaves do not support families.
 
+    ``fusion_threshold``: bytes a fusion bucket may hold (``None``: the
+    ``HOROVOD_FUSION_THRESHOLD`` default, 64 MB; 0 = a bucket a leaf).
+    It decides the buckets, one collective each, whatever their wire. The
+    flat fusion buffer is built only for a bucket that needs one (a
+    compressed wire, ``rs_ag`` / ``hierarchical``, ``channels > 1``); a
+    plain-sum bucket is reduced in its leaves' own shapes, one
+    ``lax.psum`` over the tuple (ops/fusion.py ``Bucket.packed``).
+
     ``compression``: wire compression for the dense buckets
     (``"bf16"``/``"int8"``/a :class:`~horovod_tpu.ops.compression.
     Compressor`; ops/compression.py). ``None`` defers to the
@@ -347,8 +355,10 @@ def allreduce_gradients(grads, group: int = 0, average: bool = True,
         # average is applied inside allreduce: the traced path masks
         # non-member devices back to their own gradient (subset groups),
         # which an outer divide would corrupt.
-        def reduce_flat(flat, members=None, algo="flat", channels=1):
-            return _coll.allreduce(flat, group=group, average=average,
+        # x: a packed bucket's flat buffer, or the tuple of a plain-sum
+        # bucket's leaves (ops/fusion.py fused_apply).
+        def reduce_bucket(x, members=None, algo="flat", channels=1):
+            return _coll.allreduce(x, group=group, average=average,
                                    members=members, compression=comp,
                                    compression_key=compression_key,
                                    algo=algo,
@@ -356,19 +366,21 @@ def allreduce_gradients(grads, group: int = 0, average: bool = True,
                                    channels=channels)
         if resid_leaves is None:
             reduced = _fusion.fused_apply(
-                dense, reduce_flat, fusion_threshold,
+                dense, reduce_bucket, fusion_threshold,
                 labels=dense_labels, compression=comp,
                 algo=bucket_algo, schedule=plan)
         else:
             with _compression.collect_local_contributions() as locals_:
                 reduced = _fusion.fused_apply(
-                    dense, reduce_flat, fusion_threshold,
+                    dense, reduce_bucket, fusion_threshold,
                     labels=dense_labels, compression=comp,
                     algo=bucket_algo, schedule=plan)
             # One recorded entry per bucket in issue order (the
             # fused_apply loop): slice each bucket's local dequantized
-            # contribution back onto its leaves. None = the leaf's
-            # contribution was exact — residual telescopes to zero.
+            # contribution back onto its leaves (a compressed bucket is
+            # always packed, so the offsets are the flat buffer's). None
+            # = the leaf's contribution was exact — residual telescopes
+            # to zero.
             dense_resid = [None] * len(dense)
             for bucket, local in zip(plan.buckets, locals_):
                 offset = 0

@@ -100,7 +100,11 @@ def spmd(fn: Callable, group: int = 0,
                 # for plain collectives) — deterministic from the traced
                 # gradient pytree, so multi-host schedule validation
                 # still compares byte-identical payloads.
-                schedule.append([nm, op, dtype, list(shape), grp,
+                # (A bucket reduced in its leaves' own shapes registers
+                # them all: lists too, as the JSON round-trip gives.)
+                shape = [list(d) if isinstance(d, tuple) else d
+                         for d in shape]
+                schedule.append([nm, op, dtype, shape, grp,
                                  -1 if root is None else root,
                                  list(tctx.members.get(nm, ()))])
             return jax.tree.map(lambda t: jnp.asarray(t)[None], out)

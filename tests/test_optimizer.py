@@ -46,9 +46,103 @@ class TestFusionPlanner:
     def test_fused_apply_roundtrip(self, world):
         leaves = [jnp.arange(5.0), jnp.arange(6.0).reshape(2, 3),
                   jnp.ones((4,))]
-        out = fusion.fused_apply(leaves, lambda f: f * 2, 1 << 20)
+        # a plain-sum bucket arrives as the tuple of its leaves
+        seen = []
+
+        def double(x):
+            seen.append([v.shape for v in x])
+            return jax.tree.map(lambda v: v * 2, x)
+
+        out = fusion.fused_apply(leaves, double, 1 << 20)
+        assert seen == [[(5,), (2, 3), (4,)]]
         for a, b in zip(leaves, out):
             np.testing.assert_allclose(np.asarray(b), np.asarray(a) * 2)
+
+    def test_fused_apply_packs_a_bucket_that_needs_the_buffer(self, world):
+        """``rs_ag`` cuts one flat buffer into shards: pack, one call,
+        unpack (a single-leaf bucket is flattened alone)."""
+        leaves = [jnp.arange(5.0), jnp.arange(6.0).reshape(2, 3),
+                  jnp.ones((4,), jnp.int32)]
+        seen = []
+
+        def double(flat, algo=None):
+            seen.append((flat.shape, algo))
+            return flat * 2
+
+        out = fusion.fused_apply(leaves, double, 1 << 20, algo="rs_ag")
+        assert seen == [((11,), "rs_ag"), ((4,), "rs_ag")]
+        for a, b in zip(leaves, out):
+            assert b.shape == a.shape and b.dtype == a.dtype
+            np.testing.assert_allclose(np.asarray(b), np.asarray(a) * 2)
+
+    @pytest.mark.parametrize("kw,packed", [
+        ({}, False), ({"algo": "rs_ag"}, True),
+        ({"algo": "hierarchical"}, True), ({"channels": 2}, True),
+        ({"wire_dtype": jnp.bfloat16}, True)])
+    def test_bucket_packed_follows_the_wire(self, kw, packed):
+        assert fusion.Bucket((0, 1), jnp.float32, 64, **kw).packed is packed
+
+
+class TestReducedWhereTheyLie:
+    """A plain-sum bucket reduced in its leaves' own shapes against the
+    same bucket packed by hand (flatten, concatenate, one allreduce of the
+    flat buffer, slice back), value for value on one seed."""
+
+    @pytest.mark.parametrize("average", [True, False])
+    @pytest.mark.parametrize("group", [0, 1, (2, 3), (1,)],
+                             ids=["full", "subset", "family", "slots"])
+    def test_matches_the_packed_bucket(self, group, average):
+        from horovod_tpu.ops import exchange
+
+        hvd.shutdown()
+        hvd.init([[0, 1, 2], [0, 1], [2, 3]], devices=jax.devices()[:4])
+        rng = np.random.RandomState(26)
+        shapes = {"a": (3, 5), "b": (7,), "c": (4, 2, 2), "d": (6,),
+                  "e": (2, 3), "f": (5,)}
+        dtypes = {"a": jnp.float32, "b": jnp.float32, "c": jnp.bfloat16,
+                  "d": jnp.bfloat16, "e": jnp.int32, "f": jnp.int32}
+        tree = {k: hvd.rank_stack([
+            jnp.asarray(rng.randn(*shapes[k]) * 100).astype(dtypes[k])
+            for _ in range(4)]) for k in shapes}
+
+        def packed(g):
+            out = {}
+            for keys in ("ab", "cd", "ef"):
+                flat = hvd.allreduce(
+                    jnp.concatenate([g[k].reshape(-1) for k in keys]),
+                    group=group, average=average)
+                o = 0
+                for k in keys:
+                    out[k] = flat[o:o + g[k].size].reshape(g[k].shape)
+                    o += g[k].size
+            return out
+
+        @hvd.spmd
+        def both(g):
+            return (hvd.allreduce_gradients(g, group=group, average=average),
+                    packed(g))
+
+        lie, flat = both(tree)
+        assert [b.indices for b in exchange.last_plan().buckets] == [
+            (0, 1), (2, 3), (4, 5)]
+        assert not any(b.packed for b in exchange.last_plan().buckets)
+        hvd.shutdown()
+        for k in shapes:
+            assert lie[k].dtype == dtypes[k] and lie[k].shape == flat[k].shape
+            np.testing.assert_array_equal(np.asarray(lie[k]),
+                                          np.asarray(flat[k]))
+            # and it did reduce: rank 0 is a member of every group tried
+            assert not np.array_equal(np.asarray(lie[k])[0],
+                                      np.asarray(tree[k])[0])
+
+    def test_a_tuple_refuses_a_wire_that_needs_one_buffer(self, world):
+        @hvd.spmd
+        def step(a, b):
+            return hvd.allreduce((a, b), algo="rs_ag")
+
+        x = hvd.replicate(jnp.ones((4,)))
+        with pytest.raises(hvd.HorovodError, match="plain sum only"):
+            step(x, x)
 
 
 class TestDistributedOptimizer:

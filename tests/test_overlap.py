@@ -47,16 +47,19 @@ def _topo(n=8, name="v5e:2x4"):
         pytest.skip(f"TPU AOT topology compiler unavailable: {e}")
 
 
-def _compile_dp_step(devices, n, compiler_options=None):
+def _compile_dp_step(devices, n, compiler_options=None, fusion_threshold=0):
     """The 4-layer-MLP DP train step: 4 same-shaped weight grads, each its
-    own fusion bucket (threshold 0 = bucket per tensor, mpi_ops.cc:1492),
-    reduced via hvd.allreduce_gradients, then SGD-updated."""
+    own fusion bucket (threshold 0 = bucket per tensor, mpi_ops.cc:1492;
+    ``None`` = the default plan: one 32 MB bucket of the four), reduced
+    via hvd.allreduce_gradients under the optimizer's exchange scope,
+    then SGD-updated."""
     import os
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.core import context as _ctx
     from horovod_tpu.core.state import AXIS_NAME
+    from horovod_tpu.parallel.optimizer import EXCHANGE_SCOPE
 
     hvd.shutdown()
     hvd.init(devices=devices)
@@ -74,7 +77,9 @@ def _compile_dp_step(devices, n, compiler_options=None):
             pv = jax.tree.map(lambda t: t[0], p)
             bv = jax.tree.map(lambda t: t[0], b)
             loss, grads = jax.value_and_grad(loss_fn)(pv, bv)
-            grads = hvd.allreduce_gradients(grads, fusion_threshold=0)
+            with jax.named_scope(EXCHANGE_SCOPE):
+                grads = hvd.allreduce_gradients(
+                    grads, fusion_threshold=fusion_threshold)
             out = ({k: pv[k] - 0.1 * grads[k] for k in pv}, loss)
         return jax.tree.map(lambda t: jnp.asarray(t)[None], out)
 
@@ -126,6 +131,23 @@ class TestGradientOverlapSchedule:
         # reductions (plus it may keep the fp32 loss reduce separate) —
         # XLA's fusion buffer doing the reference's job on device.
         assert 1 <= len(ars) < 4, ars
+
+    def test_default_plan_moves_no_gradient_through_a_buffer(self):
+        """A plain-sum bucket is reduced in its leaves' own shapes: the
+        compiled step holds no ``reshape`` and no ``copy`` of a whole
+        gradient's size under ``hvd.exchange`` (packed, each 2048 x 2048
+        gradient in the matmul's tiling was relaid into the flat buffer
+        and back: a pass over the leaf each way)."""
+        txt = _compile_dp_step(_topo(), 8, fusion_threshold=None)
+        moved = []
+        for line in txt.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]*)\]\S* "
+                         r"(reshape|copy)\(", line)
+            if m and "hvd.exchange" in line and np.prod(
+                    [int(d) for d in m.group(2).split(",") if d]) >= 2048**2:
+                moved.append(m.group(1))
+        assert not moved, moved
+        assert any(op == "all-reduce" for _, op in _schedule(txt))
 
     # Known pre-existing failure (tracked since r10, triaged r12): under
     # this container's XLA the combiner-pinned compile

@@ -7,6 +7,7 @@ the lazy scope map that never compiles, and the program's own trace reader
 import collections
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,42 +59,61 @@ def _lm_step(compression=None):
 
 
 @pytest.fixture(scope="module", params=["full", "compact"])
-def compiled_text(request):
-    """The step's optimized text, under JAX's default locations and under
-    the compact ones ``benchmark/run.py`` sets (which alone would leave
-    ``op_name="sin"``: utils/jax_compat.named_locations)."""
+def compiled_texts(request):
+    """The optimized text of the default step and of the step built with
+    ``compression="bf16"`` (whose buckets are packed: ops/fusion.py),
+    under JAX's default locations and under the compact ones
+    ``benchmark/run.py`` sets (which alone would leave ``op_name="sin"``:
+    utils/jax_compat.named_locations)."""
     # (The other half of named_locations, that no call stack reaches the
     # program's text, is test_lowered_text_holds_no_call_stack.)
     full = request.param == "full"
     jax.config.update("jax_include_full_tracebacks_in_locations", full)
+    texts = {}
     try:
-        _world4()
-        step, ps, ss, toks, _ = _lm_step()
-        if full:
-            text = step.lower(ps, ss, toks).compile().as_text()
-        else:  # as a run reads it: from the executable the call built
-            step(ps, ss, toks)
-            text = spmd_mod._Program.hlo_text(
-                timeline.session()._texts[TAG]())
-        hvd.shutdown()
+        for built in ("default", "bf16"):
+            _world4()
+            step, ps, ss, toks, _ = _lm_step(
+                None if built == "default" else built)
+            if full:
+                texts[built] = step.lower(ps, ss, toks).compile().as_text()
+            else:  # as a run reads it: from the executable the call built
+                step(ps, ss, toks)
+                texts[built] = spmd_mod._Program.hlo_text(
+                    timeline.session()._texts[TAG]())
+            hvd.shutdown()
     finally:
         jax.config.update("jax_include_full_tracebacks_in_locations", True)
-    return text
+    return texts
 
 
-@pytest.mark.parametrize("scope", [
-    "jvp(hvd.model)/Transformer/block_0/attn",
-    "transpose(jvp(hvd.model))/Transformer/block_1/mlp",
-    "jvp(hvd.model)/head", "transpose(jvp(hvd.model))/head",
-    "hvd.exchange/MEMCPY_IN_FUSION_BUFFER",
-    "hvd.exchange/MEMCPY_OUT_FUSION_BUFFER", "hvd.exchange/psum",
-    "hvd.update/"])
-def test_scopes_in_the_compiled_step(compiled_text, scope):
-    assert f"/shard_map/{scope}" in compiled_text
+@pytest.fixture(scope="module")
+def compiled_text(compiled_texts):
+    return compiled_texts["default"]
+
+
+# The fusion buffer is built only for a bucket whose wire needs one: the
+# MEMCPY scopes are the bf16 step's, and the default step has none.
+@pytest.mark.parametrize("built,scope", [
+    ("default", "jvp(hvd.model)/Transformer/block_0/attn"),
+    ("default", "transpose(jvp(hvd.model))/Transformer/block_1/mlp"),
+    ("default", "jvp(hvd.model)/head"),
+    ("default", "transpose(jvp(hvd.model))/head"),
+    ("bf16", "hvd.exchange/MEMCPY_IN_FUSION_BUFFER"),
+    ("bf16", "hvd.exchange/MEMCPY_OUT_FUSION_BUFFER"),
+    ("default", "hvd.exchange/psum"), ("bf16", "hvd.exchange/psum"),
+    ("default", "hvd.update/")])
+def test_scopes_in_the_compiled_step(compiled_texts, built, scope):
+    text = compiled_texts[built]
+    assert f"/shard_map/{scope}" in text
     # flax's own names are kept and not doubled; nothing of the loss is
     # left under an empty scope
-    assert "jvp()/" not in compiled_text
-    assert "hvd.model/hvd.model" not in compiled_text
+    assert "jvp()/" not in text
+    assert "hvd.model/hvd.model" not in text
+
+
+def test_default_step_builds_no_fusion_buffer(compiled_text):
+    assert "FUSION_BUFFER" not in compiled_text
 
 
 def test_program_is_named_after_the_users_function(compiled_text):
@@ -142,13 +162,71 @@ def test_record_after_shutdown_holds_spans_and_programs():
 
 @pytest.mark.parametrize("compression,share", [(None, 1.0), ("bf16", 0.5)])
 def test_exchange_wire_bytes_are_the_plan(compression, share):
+    """... and ``exchange.unpacked_bytes`` the part of them reduced in the
+    leaves' own shapes: all of the default step, none under bf16."""
     _world4()
     step, ps, ss, toks, params = _lm_step(compression)
     step(ps, ss, toks)
     nbytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
     counters = timeline.record()["programs"][TAG]["counters"]
     hvd.shutdown()
-    assert counters == {"exchange.wire_bytes": int(nbytes * share)}
+    assert counters == {
+        "exchange.wire_bytes": int(nbytes * share),
+        "exchange.unpacked_bytes": nbytes if compression is None else 0}
+
+
+def _stablehlo_ops(step, *args):
+    """``[(op, result type, location's name)]`` of the lowered module's
+    one-line operations (an ``all_reduce`` holds a region: not one)."""
+    asm = step.lower(*args).compiler_ir().operation.get_asm(
+        enable_debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", asm, re.M))
+    ops = re.findall(
+        r'= "?(stablehlo\.\w+)"?.* (tensor<[^ ]*>) loc\((#loc\d+)\)$',
+        asm, re.M)
+    return [(op, typ, locs.get(ref, "")) for op, typ, ref in ops]
+
+
+def _psums(jaxpr, out):
+    """The ``psum`` equations of ``jaxpr`` and its sub-jaxprs, in order."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "psum":
+            out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _psums(sub, out)
+    return out
+
+
+def test_default_step_reduces_the_gradients_where_they_lie():
+    """No flat buffer in the lowered text — no ``concatenate`` and no
+    rank-1 ``reshape`` under ``hvd.exchange`` — and in the jaxpr each
+    planned bucket is its members' ``psum``s, adjacent, in the plan's
+    order and in the leaves' own shapes (this JAX binds one ``psum`` a
+    leaf of ``lax.psum``'s tuple), then the loss's own."""
+    _world4()
+    step, ps, ss, toks, params = _lm_step()
+    under = [(op, typ) for op, typ, name in _stablehlo_ops(step, ps, ss, toks)
+             if "hvd.exchange" in name]
+    assert any(op == "stablehlo.divide" for op, _ in under)  # the average
+    assert not [o for o in under if o[0] == "stablehlo.concatenate"]
+    assert not [o for o in under if o[0] == "stablehlo.reshape"
+                and re.fullmatch(r"tensor<\d+x\w+>", o[1])]
+    psums = _psums(jax.make_jaxpr(step)(ps, ss, toks).jaxpr, [])
+    plan = exchange.last_plan()
+    shapes = [l.shape for l in jax.tree.leaves(params)]
+    assert [len(b.indices) for b in plan.buckets] == [len(shapes)]
+    assert [e.invars[0].aval.shape for e in psums] == [
+        shapes[i] for b in plan.buckets for i in b.indices] + [()]
+    hvd.shutdown()
+
+
+def test_packed_step_still_builds_the_buffer():
+    _world4()
+    step, ps, ss, toks, _ = _lm_step("bf16")
+    under = [op for op, _, name in _stablehlo_ops(step, ps, ss, toks)
+             if "hvd.exchange/MEMCPY_IN_FUSION_BUFFER" in name]
+    hvd.shutdown()
+    assert "stablehlo.concatenate" in under and "stablehlo.reshape" in under
 
 
 def test_lowered_text_holds_no_call_stack():
