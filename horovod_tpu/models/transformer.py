@@ -20,6 +20,26 @@ attention rides the SP group's ring.
 ring then carries only the Hkv heads), and ``segment_ids`` masks packed
 documents apart — both lower to the flash kernel's native GQA/segment
 support on every attention strategy.
+
+The block is a slot, not one block: ``ffn`` chooses the feed-forward
+(``'gelu'``: two matrices; ``'swiglu'``: three, gated), ``sandwich_norm``
+norms each branch's output as well as its input, ``rope_theta`` is the
+rotary base. ``recurrent_steps=R`` makes the model LOOPED: the same
+``num_layers`` blocks and the same final norm are applied R times over the
+same parameter leaves (one ``lax.scan`` over the passes), each pass's
+normed state feeding the next; with ``exit_gate`` every pass but the last
+also gives an exit probability and :func:`make_loss_fn` trains all R
+passes (``exit_loss``).
+
+**Recomputation is a rule, not a switch**: a stack run more than once
+(``recurrent_steps > 1``) keeps only each block application's INPUT for
+the backward (``nn.remat``: the block's forward runs again there); a
+stack run once keeps what it always kept. R passes hold R x the
+activations of one for the same parameters, and those activations, not
+the parameters, decide the depth that fits a chip: at the benchmark's
+looped configuration, 6 layers x 4 passes at T=8192, the step without the
+rule needs 19.0 GiB of a v5e's 15.75 and does not compile (PERF.md,
+PR 27).
 """
 
 from __future__ import annotations
@@ -30,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 import optax
+
+from horovod_tpu.core import timeline as _timeline
 
 
 class TransformerConfig(NamedTuple):
@@ -48,10 +70,15 @@ class TransformerConfig(NamedTuple):
     window: int | None = None     # sliding-window attention (causal SWA)
     kv_dtype: str = "model"       # paged-KV pool format ('model' = dtype;
                                   # fp32|bf16|int8_block|int4 — serving)
+    ffn: str = "gelu"             # 'gelu' (2 matrices) | 'swiglu' (3, gated)
+    sandwich_norm: bool = False   # RMSNorm on each branch's output too
+    rope_theta: float = 10000.0   # rotary base
+    recurrent_steps: int = 1      # passes of the one weight-shared stack
+    exit_gate: bool = False       # per-pass exit probability (looped only)
 
 
-def _rotary(x, positions):
-    """Rotary position embedding on (B, T, H, D).
+def _rotary(x, positions, theta=10000.0):
+    """Rotary position embedding on (B, T, H, D), base ``theta``.
 
     ``positions`` is (T,) global positions shared across the batch, or
     (B, T) per-row positions — the paged decode path serves ragged
@@ -60,7 +87,7 @@ def _rotary(x, positions):
     """
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., T, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     if angles.ndim == 2:            # (T, half): shared positions
@@ -99,8 +126,8 @@ class Attention(nn.Module):
             raise ValueError(
                 "kv_view= (paged KV cache) is only meaningful with "
                 "decode=True — the serving engine's one-token step.")
-        q = _rotary(dense("query", h)(x), positions)
-        k = _rotary(dense("key", hkv)(x), positions)
+        q = _rotary(dense("query", h)(x), positions, cfg.rope_theta)
+        k = _rotary(dense("key", hkv)(x), positions, cfg.rope_theta)
         v = dense("value", hkv)(x)
 
         import horovod_tpu as hvd
@@ -272,21 +299,44 @@ class Attention(nn.Module):
                                use_bias=False, name="out")(out)
 
 
+def _gelu_ffn(cfg, y):
+    y = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, use_bias=False)(y)
+    y = nn.gelu(y)
+    return nn.Dense(cfg.embed_dim, dtype=cfg.dtype, use_bias=False)(y)
+
+
+def _swiglu_ffn(cfg, y):
+    dense = lambda width, name: nn.Dense(width, dtype=cfg.dtype,
+                                         use_bias=False, name=name)
+    y = nn.silu(dense(cfg.mlp_dim, "gate")(y)) * dense(cfg.mlp_dim, "up")(y)
+    return dense(cfg.embed_dim, "down")(y)
+
+
+# The block's feed-forward slot: ``cfg.ffn`` -> (cfg, y) -> y, building
+# its matrices in the calling Block's scope.
+FFN = {"gelu": _gelu_ffn, "swiglu": _swiglu_ffn}
+
+
 class Block(nn.Module):
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, kv_view=None):
         cfg = self.config
-        y = nn.RMSNorm(dtype=cfg.dtype)(x)
-        x = x + Attention(cfg, name="attn")(y, positions, segment_ids,
-                                            kv_view=kv_view)
-        y = nn.RMSNorm(dtype=cfg.dtype)(x)
+        if cfg.ffn not in FFN:
+            raise ValueError(f"Unknown ffn {cfg.ffn!r}; one of "
+                             f"{sorted(FFN)}.")
+        norm = lambda: nn.RMSNorm(dtype=cfg.dtype)
+        # Sandwich norms: a branch's output is normed before it joins the
+        # residual stream, so four RMSNorms a block for two.
+        post = (lambda y: norm()(y)) if cfg.sandwich_norm else (lambda y: y)
+        y = norm()(x)
+        x = x + post(Attention(cfg, name="attn")(y, positions, segment_ids,
+                                                 kv_view=kv_view))
+        y = norm()(x)
         with jax.named_scope("mlp"):
-            y = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, use_bias=False)(y)
-            y = nn.gelu(y)
-            y = nn.Dense(cfg.embed_dim, dtype=cfg.dtype, use_bias=False)(y)
-        return x + y
+            y = FFN[cfg.ffn](cfg, y)
+        return x + post(y)
 
 
 class Transformer(nn.Module):
@@ -298,13 +348,18 @@ class Transformer(nn.Module):
     ``positions``: explicit (T_local,) global positions, overriding
     ``shard_offset`` — required for ``sp_layout='zigzag'`` shards (use
     :func:`horovod_tpu.zigzag_positions`).
+    ``return_passes``: every pass's output and the exit gates' logits, as
+    two tuples (R and R - 1 long; one and none for a plain model) — logits,
+    or with ``return_hidden`` the normed states the head reads. Without
+    it a looped model returns its last pass's.
     """
 
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, tokens, shard_offset=0, segment_ids=None,
-                 positions=None, return_hidden=False, kv_views=None):
+                 positions=None, return_hidden=False, kv_views=None,
+                 return_passes=False):
         cfg = self.config
         t_local = tokens.shape[1]
         if kv_views is not None:
@@ -329,22 +384,80 @@ class Transformer(nn.Module):
                     "positions=hvd.zigzag_positions(hvd.rank(sp_group), "
                     "t_local, group_size) from inside the step function.")
             positions = shard_offset + jnp.arange(t_local)
+        if cfg.recurrent_steps < 1:
+            raise ValueError(
+                f"recurrent_steps must be >= 1, got {cfg.recurrent_steps}.")
+        looped = cfg.recurrent_steps > 1
+        if cfg.exit_gate and not looped:
+            raise ValueError(
+                "exit_gate needs recurrent_steps > 1: with one pass there "
+                "is nothing to exit from.")
+        if looped and cfg.decode:
+            raise ValueError(
+                "decode=True does not run a looped model (recurrent_steps="
+                f"{cfg.recurrent_steps}): the KV cache holds one entry a "
+                "layer, a looped model needs one a pass and layer.")
         x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
                      dtype=cfg.dtype,
                      embedding_init=nn.initializers.normal(0.02))(tokens)
-        for i in range(cfg.num_layers):
-            x = Block(cfg, name=f"block_{i}")(
-                x, positions, segment_ids,
-                kv_view=None if kv_views is None else kv_views[i])
-        x = nn.RMSNorm(dtype=cfg.dtype)(x)
-        if return_hidden:
+        # Into the record of the hvd.spmd program being traced, a step, a
+        # rank (core/timeline.py count_plan; dropped where none is).
+        applied = cfg.num_layers * cfg.recurrent_steps
+        tl = _timeline.session()
+        tl.count_plan("model.block_applications", applied)
+        tl.count_plan("model.recomputed_blocks", applied if looped else 0)
+
+        def stack(block, x):
+            """One pass: the blocks and the final norm."""
+            for i in range(cfg.num_layers):
+                x = block(cfg, name=f"block_{i}")(
+                    x, positions, segment_ids,
+                    None if kv_views is None else kv_views[i])
+            return nn.RMSNorm(dtype=cfg.dtype, name="RMSNorm_0")(x)
+
+        if looped:
+            # The R passes are ONE lax.scan whose body is the stack, its
+            # parameters broadcast to every iteration: the same leaves,
+            # their gradient the sum over the passes. THE RECOMPUTATION
+            # RULE (module docstring): each block application keeps only
+            # its input. The normed state is what the next pass takes.
+            def one_pass(_, x, __):
+                x = stack(nn.remat(Block), x)
+                return x, x
+
+            with jax.named_scope("loop"):
+                x, states = nn.scan(
+                    one_pass, variable_broadcast="params",
+                    split_rngs={"params": False},
+                    length=cfg.recurrent_steps)(self, x, None)
+            hidden = tuple(states)
+        else:
+            x = stack(Block, x)
+            hidden = (x,)
+        exits = ()
+        if cfg.exit_gate:
+            # Every pass but the last has an exit probability; the last
+            # takes what is left.
+            with jax.named_scope("exit_gate"):
+                exits = tuple(nn.Dense(1, dtype=jnp.float32,
+                                       name="exit_gate")(states[:-1])[..., 0])
+        if return_hidden and not return_passes:
             # Pre-head activations for the fused (chunked-vocab) loss —
             # the lm_head matmul then runs inside fused_cross_entropy
             # without materializing (N, V) logits (ops/losses.py).
             return x
-        logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        if return_hidden:
+            # What a loss over every pass takes (make_loss_fn): each
+            # pass's normed state and the gates' logits, tuples of R and
+            # R - 1 (one and none for a plain model).
+            return hidden, exits
+        lm_head = nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
+                           name="lm_head")
+        if return_passes:
+            return (tuple(lm_head(h).astype(jnp.float32) for h in hidden),
+                    exits)
+        # A looped model's logits are its last pass's (no early exit).
+        return lm_head(x).astype(jnp.float32)
 
 
 def init_params(config: TransformerConfig, seed: int = 0):
@@ -358,17 +471,50 @@ def init_params(config: TransformerConfig, seed: int = 0):
     return model.init(jax.random.PRNGKey(seed), dummy)["params"]
 
 
+def exit_log_probs(gate_logits):
+    """Log of the exit distribution of a looped model, from its gates.
+
+    ``gate_logits``: (R - 1, ...) — pass t's gate at every position,
+    ``lambda_t = sigmoid(z_t)``. Returns (R, ...):
+    ``p_t = lambda_t * prod_{j<t}(1 - lambda_j)`` for t < R and
+    ``p_R = prod_{j<R}(1 - lambda_j)`` — the last pass takes what is
+    left, so the R probabilities sum to 1 whatever the gates say."""
+    z = jnp.asarray(gate_logits, jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)   # sum_{j<=t} log(1-l_j)
+    stayed = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([jax.nn.log_sigmoid(z) + stayed, stay[-1:]])
+
+
+def exit_loss(pass_losses, gate_logits, beta: float):
+    """The looped LM's entropy-regularised multi-exit objective
+    (arXiv:2510.25741, first training stage): over all positions, the mean
+    of ``sum_t p_t * loss_t - beta * H(p)``, with ``p`` the positions'
+    exit distributions (:func:`exit_log_probs`) and ``H`` their entropy.
+    ``pass_losses``: (R, ...) per-position losses of every pass;
+    ``gate_logits``: (R - 1, ...)."""
+    logp = exit_log_probs(gate_logits)
+    p = jnp.exp(logp)
+    return jnp.mean(jnp.sum(p * (pass_losses + beta * logp), axis=0))
+
+
 def make_loss_fn(config: TransformerConfig, sp_rank=None,
-                 fused_head: bool = False):
+                 fused_head: bool = False, exit_beta: float = 0.1):
     """Next-token cross-entropy over the local shard.
 
     ``fused_head=True`` routes the lm_head matmul through
-    :func:`horovod_tpu.ops.losses.fused_cross_entropy` (chunked-vocab
-    log-sum-exp): the (N, V) logits never materialize in HBM in either
-    direction — peak memory drops by that footprint (1 GB fp32 at T=8k,
-    V=32k) at the cost of one extra head-matmul recompute in backward
-    (~3% step time on the bench LM) — the right trade when the logits
-    tensor threatens HBM. Contiguous layouts only.
+    :func:`horovod_tpu.ops.losses.fused_cross_entropy_per_position`
+    (chunked-vocab log-sum-exp): the (N, V) logits never materialize in
+    HBM in either direction — peak memory drops by that footprint (1 GB
+    fp32 at T=8k, V=32k) at the cost of one extra head-matmul recompute
+    in backward — the right trade when the logits tensor threatens HBM.
+    Contiguous layouts only.
+
+    A looped model (``recurrent_steps > 1``) with an ``exit_gate`` trains
+    every pass: the one head is applied to each pass's state, and the
+    loss is :func:`exit_loss` over the passes' per-position losses with
+    ``exit_beta`` on the exit distribution's entropy. Without a gate only
+    the last pass's loss counts (its gradient still reaches every shared
+    leaf through all the passes).
 
     ``sp_rank``: traced group rank when sequence-parallel (compute it inside
     the hvd.spmd step: ``hvd.rank(cfg.sp_group)``); None for plain DP.
@@ -396,6 +542,11 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
                     "fused_head=True is not supported with "
                     "sp_layout='zigzag' (the cross-chunk loss masking is "
                     "not plumbed through the fused path).")
+            if config.recurrent_steps > 1:
+                raise ValueError(
+                    "a looped model (recurrent_steps > 1) is not supported "
+                    "with sp_layout='zigzag' (the cross-chunk loss masking "
+                    "is not plumbed through the multi-exit loss).")
             if sp_rank is None:
                 raise ValueError(
                     "sp_layout='zigzag' needs sp_rank (the SP group rank "
@@ -413,27 +564,38 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
             valid = jnp.arange(t_local - 1) != (c - 1)
             return (per_tok * valid[None]).sum() / valid.sum()
         offset = 0 if sp_rank is None else sp_rank() * t_local
-        if fused_head:
-            from horovod_tpu.ops.losses import (default_chunk,
-                                                fused_cross_entropy)
-
-            hidden = model.apply({"params": params}, tokens,
-                                 shard_offset=offset, return_hidden=True)
-            with jax.named_scope("head"):
-                w = params["lm_head"]["kernel"].astype(config.dtype)
-                x2 = hidden[:, :-1].reshape(-1, hidden.shape[-1])
-                tgt = tokens[:, 1:].reshape(-1)
-                return fused_cross_entropy(x2, w, tgt,
-                                           chunk=default_chunk(w.shape[1]))
-        logits = model.apply({"params": params}, tokens,
-                             shard_offset=offset)
+        # Every pass's state (or logits) and the exit gates' logits: one
+        # and none for a plain model, R and R - 1 for a gated looped one.
+        passes, exits = model.apply(
+            {"params": params}, tokens, shard_offset=offset,
+            return_hidden=fused_head, return_passes=True)
+        if not config.exit_gate:
+            passes = passes[-1:]  # only the last pass is trained
+        _timeline.session().count_plan("model.head_applications",
+                                       len(passes))
         with jax.named_scope("head"):
             # Shift within the shard: predict token[t+1] from position t.
-            targets = tokens[:, 1:]
-            pred = logits[:, :-1]
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                pred, targets)
-            return loss.mean()
+            if fused_head:
+                from horovod_tpu.ops.losses import (
+                    default_chunk, fused_cross_entropy_per_position)
+
+                w = params["lm_head"]["kernel"].astype(config.dtype)
+                rows = [hidden[:, :-1].reshape(-1, hidden.shape[-1])
+                        for hidden in passes]
+                tgt = tokens[:, 1:].reshape(-1)
+                per_pass = [fused_cross_entropy_per_position(
+                    x2, w, tgt, chunk=default_chunk(w.shape[1]))
+                    for x2 in rows]
+            else:
+                targets = tokens[:, 1:]
+                per_pass = [optax.softmax_cross_entropy_with_integer_labels(
+                    logits[:, :-1], targets) for logits in passes]
+            if not config.exit_gate:
+                return per_pass[0].mean()
+        with jax.named_scope("exit_gate"):
+            gates = jnp.stack([z[:, :-1].reshape(per_pass[0].shape)
+                               for z in exits])
+            return exit_loss(jnp.stack(per_pass), gates, exit_beta)
 
     def loss_fn(params, batch):
         # The root of every op_name of the loss, whatever flax calls its

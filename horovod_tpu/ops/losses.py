@@ -145,28 +145,41 @@ def _fwd_scan(x, w, targets, chunk):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_cross_entropy(x, w, targets, chunk: int = DEFAULT_CHUNK):
-    """Mean cross-entropy of ``x @ w`` against integer ``targets``.
+def fused_cross_entropy_per_position(x, w, targets,
+                                     chunk: int = DEFAULT_CHUNK):
+    """Cross-entropy of ``x @ w`` against integer ``targets``, one loss a
+    row: ``lse - target logit``, shape (N,), float32.
 
     ``x``: (N, E) activations (any float dtype; matmuls run in its dtype
     with fp32 accumulation); ``w``: (E, V) vocabulary projection;
     ``targets``: (N,) int32 class ids. Equivalent to
-    ``optax.softmax_cross_entropy_with_integer_labels(x @ w, targets).mean()``
+    ``optax.softmax_cross_entropy_with_integer_labels(x @ w, targets)``
     without materializing the (N, V) logits in either direction; any
     vocabulary size works (a trailing remainder chunk handles V % chunk).
+    The backward takes an (N,) cotangent — a loss that weights every
+    position by itself (the looped LM's exit probabilities,
+    models/transformer.py) — and scales each row's ``softmax - onehot``
+    by it.
     """
     lse, tl = _fwd_scan(x, w, targets, chunk)
-    return jnp.mean(lse - tl)
+    return lse - tl
+
+
+def fused_cross_entropy(x, w, targets, chunk: int = DEFAULT_CHUNK):
+    """Mean cross-entropy of ``x @ w`` against integer ``targets``:
+    :func:`fused_cross_entropy_per_position` with a mean on top (its
+    backward hands every row the cotangent ``g / N``)."""
+    return jnp.mean(fused_cross_entropy_per_position(x, w, targets, chunk))
 
 
 def _fce_fwd(x, w, targets, chunk):
     lse, tl = _fwd_scan(x, w, targets, chunk)
-    return jnp.mean(lse - tl), (x, w, targets, lse)
+    return lse - tl, (x, w, targets, lse)
 
 
 def _dchunk(x, wc, base, targets, lse, scale):
-    """Recompute one chunk's softmax-minus-onehot cotangent; return
-    (dx contribution, dW chunk)."""
+    """Recompute one chunk's softmax-minus-onehot cotangent, each row
+    scaled by its ``scale`` (N, 1); return (dx contribution, dW chunk)."""
     width = wc.shape[1]
     logits = jnp.dot(x, wc, preferred_element_type=jnp.float32)
     p = jnp.exp(logits - lse[:, None])
@@ -184,7 +197,7 @@ def _fce_bwd(chunk, res, g):
     n, e = x.shape
     v = w.shape[1]
     nfull = v // chunk
-    scale = g / n                                  # d(mean)/d(per-token)
+    scale = g.astype(jnp.float32)[:, None]         # a row's own cotangent
 
     if nfull <= UNROLL_MAX_CHUNKS:
         # Unrolled: each chunk's dW is its own tensor and one concatenate
@@ -225,4 +238,4 @@ def _fce_bwd(chunk, res, g):
     return dx.astype(x.dtype), dw.astype(w.dtype), None
 
 
-fused_cross_entropy.defvjp(_fce_fwd, _fce_bwd)
+fused_cross_entropy_per_position.defvjp(_fce_fwd, _fce_bwd)

@@ -172,7 +172,12 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
     hvd.shutdown()
     assert counters == {
         "exchange.wire_bytes": int(nbytes * share),
-        "exchange.unpacked_bytes": nbytes if compression is None else 0}
+        "exchange.unpacked_bytes": nbytes if compression is None else 0,
+        # the model's own account (models/transformer.py): a plain stack
+        # applies each of its CFG.num_layers blocks once, recomputes none
+        # and has one head
+        "model.block_applications": 2, "model.recomputed_blocks": 0,
+        "model.head_applications": 1}
 
 
 def _stablehlo_ops(step, *args):
