@@ -32,14 +32,26 @@ also gives an exit probability and :func:`make_loss_fn` trains all R
 passes (``exit_loss``).
 
 **Recomputation is a rule, not a switch**: a stack run more than once
-(``recurrent_steps > 1``) keeps only each block application's INPUT for
-the backward (``nn.remat``: the block's forward runs again there); a
-stack run once keeps what it always kept. R passes hold R x the
-activations of one for the same parameters, and those activations, not
-the parameters, decide the depth that fits a chip: at the benchmark's
-looped configuration, 6 layers x 4 passes at T=8192, the step without the
-rule needs 19.0 GiB of a v5e's 15.75 and does not compile (PERF.md,
-PR 27).
+(``recurrent_steps > 1``) keeps, of each block application, its INPUT and
+WHAT ITS ATTENTION KERNEL WROTE for the backward (``nn.remat`` under a
+policy that saves the two residuals the flash kernel's VJP names,
+``ops/flash_attention.OUT_RESIDUAL`` and ``LSE_RESIDUAL``): the block's
+forward runs again there, the Pallas forward kernel does not. A stack run
+once keeps what it always kept and reads no name; attention that does not
+go through the kernel (the CPU's blockwise path) names nothing and is
+recomputed whole. R passes hold R x the activations of one for the same
+parameters, and those activations, not the parameters, decide the depth
+that fits a chip: at the benchmark's looped configuration, 6 layers x 4
+passes at T=8192, the step without the rule needs 19.0 GiB of a v5e's
+15.75 and does not compile (PERF.md, PR 27). What the kernel wrote is the
+dearest thing in the block by the byte: at 8 layers x 4 passes, 16 heads
+of 128 and T=8192 the 32 kept outputs (bfloat16, 33.5 MB each) and
+log-sum-exps (float32, 0.5 MB) are 1.07 GB more for 32 kernel calls of
+2.6 ms fewer, and the step still compiles for a v5e: a peak of 15.71 GB
+of its 16.91, for 14.99 without them, and XLA's own rematerialization
+pass already runs six fusions twice to hold it there (PERF.md, PR 28).
+Keeping more (q, k, v at 100 MB a block application, the MLP's products
+at 184 MB) does not fit.
 """
 
 from __future__ import annotations
@@ -51,7 +63,9 @@ import jax.numpy as jnp
 import flax.linen as nn
 import optax
 
+from horovod_tpu.core import state as _state
 from horovod_tpu.core import timeline as _timeline
+from horovod_tpu.ops.flash_attention import LSE_RESIDUAL, OUT_RESIDUAL
 
 
 class TransformerConfig(NamedTuple):
@@ -339,6 +353,22 @@ class Block(nn.Module):
         return x + post(y)
 
 
+def _attends_through_the_kernel(cfg: TransformerConfig, t_local: int) -> bool:
+    """Whether a block's attention is a call of the Pallas flash kernel
+    that the block's ``nn.remat`` sees, so that its policy finds the
+    kernel's two named residuals. Ring attention's steps sit under a bare
+    ``jax.checkpoint`` of their own, which saves nothing, named or not."""
+    from horovod_tpu.parallel.sequence import local_attention_impl
+
+    if cfg.attention == "local":
+        t = t_local
+    elif cfg.attention == "ulysses":  # the full sequence, H/g heads
+        t = t_local * _state.get_group(cfg.sp_group).size
+    else:
+        return False
+    return local_attention_impl(t) == "flash"
+
+
 class Transformer(nn.Module):
     """Decoder-only LM over the LOCAL sequence shard.
 
@@ -406,6 +436,12 @@ class Transformer(nn.Module):
         tl = _timeline.session()
         tl.count_plan("model.block_applications", applied)
         tl.count_plan("model.recomputed_blocks", applied if looped else 0)
+        # Of those, the ones whose backward reads the attention kernel's
+        # output and log-sum-exp back and does not run the kernel again.
+        tl.count_plan(
+            "model.kept_attention_outputs",
+            applied if looped and _attends_through_the_kernel(cfg, t_local)
+            else 0)
 
         def stack(block, x):
             """One pass: the blocks and the final norm."""
@@ -419,10 +455,14 @@ class Transformer(nn.Module):
             # The R passes are ONE lax.scan whose body is the stack, its
             # parameters broadcast to every iteration: the same leaves,
             # their gradient the sum over the passes. THE RECOMPUTATION
-            # RULE (module docstring): each block application keeps only
-            # its input. The normed state is what the next pass takes.
+            # RULE (module docstring): each block application keeps its
+            # input and what its attention kernel wrote. The normed state
+            # is what the next pass takes.
+            kept = jax.checkpoint_policies.save_only_these_names(
+                OUT_RESIDUAL, LSE_RESIDUAL)
+
             def one_pass(_, x, __):
-                x = stack(nn.remat(Block), x)
+                x = stack(nn.remat(Block, policy=kept), x)
                 return x, x
 
             with jax.named_scope("loop"):

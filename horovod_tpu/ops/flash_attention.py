@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -807,14 +808,39 @@ def _flash(q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
     return out
 
 
-def _flash_fwd_rule(q, k, v, qseg, kvseg, causal, sm_scale, q_offset,
-                    kv_offset, block_q, block_k, bwd_blocks, interpret,
-                    window):
+# The two residuals the backward kernel reads that only the forward KERNEL
+# can make, by the names ``jax.ad_checkpoint.checkpoint_name`` gives them.
+# A ``jax.checkpoint`` whose policy saves these two
+# (``save_only_these_names(OUT_RESIDUAL, LSE_RESIDUAL)``) reads them back
+# in its backward and does not run the forward kernel a second time; with
+# no policy, or another's names, they lower to nothing.
+OUT_RESIDUAL = "hvd_flash_out"
+LSE_RESIDUAL = "hvd_flash_lse"
+
+
+def _fwd_and_residuals(q, k, v, qseg, kvseg, causal, sm_scale, q_offset,
+                       kv_offset, block_q, block_k, interpret, window):
+    """The forward kernel and the residuals of both VJP rules: ``(out,
+    lse_c, residuals)``, ``out`` and ``lse_c`` NAMED. The names have to be
+    given here, inside the rule: the residual is this variable, and a name
+    on the attention's result outside the ``custom_vjp`` is another."""
     sm_scale, interpret = _resolve(sm_scale, interpret, q.shape[-1])
     out, lse_c = _flash_fwd(q, k, v, _unwrap_seg(qseg), _unwrap_seg(kvseg),
                             causal, sm_scale, q_offset, kv_offset,
                             block_q, block_k, interpret, window)
-    return out, (q, k, v, qseg, kvseg, out, lse_c, q_offset, kv_offset)
+    out = checkpoint_name(out, OUT_RESIDUAL)
+    lse_c = checkpoint_name(lse_c, LSE_RESIDUAL)
+    return out, lse_c, (q, k, v, qseg, kvseg, out, lse_c, q_offset,
+                        kv_offset)
+
+
+def _flash_fwd_rule(q, k, v, qseg, kvseg, causal, sm_scale, q_offset,
+                    kv_offset, block_q, block_k, bwd_blocks, interpret,
+                    window):
+    out, _, residuals = _fwd_and_residuals(
+        q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
+        block_q, block_k, interpret, window)
+    return out, residuals
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, bwd_blocks,
@@ -874,6 +900,20 @@ def flash_attention(q, k, v, causal: bool = True,
     ``block_kv_mem=4096`` K/V rows VMEM-resident per grid step. For head
     dims above 128 the unset defaults scale themselves down (see
     ``_default_blocks``); explicit arguments always win.
+
+    **Residual names.** The backward kernel reads q, k, v, the output and
+    the log-sum-exp. The last two only the forward kernel can make, and
+    the VJP rule names them (``jax.ad_checkpoint.checkpoint_name``):
+    :data:`OUT_RESIDUAL` — the (B, Tq, H, D) output, in q's dtype as the
+    kernel wrote it — and :data:`LSE_RESIDUAL` — the float32 log-sum-exp.
+    A name alone lowers to nothing, and a bare ``jax.checkpoint`` around
+    this call ignores it: its backward runs the forward kernel again. To
+    keep what the kernel wrote and recompute the rest, give the
+    checkpoint the policy
+    ``jax.checkpoint_policies.save_only_these_names(OUT_RESIDUAL,
+    LSE_RESIDUAL)`` (both: with the output alone the kernel still runs
+    again for the log-sum-exp), or list the two among your own policy's
+    names. A looped ``models.transformer`` stack does.
     """
     _check_seg_pair(q_segment_ids, kv_segment_ids)
     _check_window(window, causal)
@@ -908,13 +948,11 @@ def _flash_lse(q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
 def _flash_lse_fwd_rule(q, k, v, qseg, kvseg, causal, sm_scale, q_offset,
                         kv_offset, block_q, block_k, bwd_blocks, interpret,
                         window):
-    sm_scale, interpret = _resolve(sm_scale, interpret, q.shape[-1])
-    out, lse_c = _flash_fwd(q, k, v, _unwrap_seg(qseg), _unwrap_seg(kvseg),
-                            causal, sm_scale, q_offset, kv_offset,
-                            block_q, block_k, interpret, window)
+    out, lse_c, residuals = _fwd_and_residuals(
+        q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
+        block_q, block_k, interpret, window)
     lse_rows = jnp.transpose(lse_c[:, :, :q.shape[1]], (0, 2, 1))
-    return ((out, lse_rows),
-            (q, k, v, qseg, kvseg, out, lse_c, q_offset, kv_offset))
+    return (out, lse_rows), residuals
 
 
 def _flash_lse_bwd_rule(causal, sm_scale, block_q, block_k, bwd_blocks,
@@ -958,6 +996,12 @@ def flash_attention_lse(q, k, v, causal: bool = True,
     partial-attention merges (ring attention) backprop exactly. Supports
     GQA and segment ids like :func:`flash_attention`, including its
     head-dim-aware default block sizes.
+
+    **Residual names**: as :func:`flash_attention`'s — the same two, the
+    same way (one helper makes both rules' residuals). The ``lse``
+    returned is a transposed slice of the named one, so it is not the
+    residual: name nothing outside. Ring attention wraps each step in a
+    bare ``jax.checkpoint``, which saves nothing, named or not.
     """
     _check_seg_pair(q_segment_ids, kv_segment_ids)
     _check_window(window, causal)

@@ -693,6 +693,14 @@ def ulysses_attention(q, k, v, group: int = 0, causal: bool = True,
     return out
 
 
+def local_attention_impl(t: int) -> str:
+    """What :func:`local_attention`'s ``impl='auto'`` means for ``t``
+    tokens on the devices ``hvd.init`` was given."""
+    if t <= 2048:
+        return "xla"
+    return "flash" if _state.target_platform() == "tpu" else "blockwise"
+
+
 def local_attention(q, k, v, causal: bool = True,
                     sm_scale: float | None = None, impl: str = "auto",
                     q_segment_ids=None, kv_segment_ids=None,
@@ -724,10 +732,7 @@ def local_attention(q, k, v, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if impl == "auto":
-        if t <= 2048:
-            impl = "xla"
-        else:
-            impl = "flash" if _state.target_platform() == "tpu" else "blockwise"
+        impl = local_attention_impl(t)
 
     if impl == "flash":
         return _fa.flash_attention(q, k, v, causal, sm_scale,

@@ -22,6 +22,7 @@ import horovod_tpu as hvd
 from horovod_tpu.core import timeline
 from horovod_tpu.models import transformer
 from horovod_tpu.ops import optim
+from horovod_tpu.parallel import sequence
 from horovod_tpu.ops.losses import (fused_cross_entropy,
                                     fused_cross_entropy_per_position)
 
@@ -38,6 +39,8 @@ def _load(*parts):
     spec.loader.exec_module(module)
     return module
 
+
+from test_flash_attention import equations, kernel_calls  # noqa: E402
 
 REFERENCE = _load("reference", "train_looped_lm.py")
 PLAIN_REFERENCE = _load("reference", "train_lm.py")
@@ -165,6 +168,9 @@ def test_three_adamw_steps_through_hvd_match_the_reference(exact_attention):
     assert counters["model.block_applications"] == layers * passes
     assert counters["model.recomputed_blocks"] == layers * passes
     assert counters["model.head_applications"] == passes
+    # no Pallas kernel in this step (T=32 on the CPU): nothing is named,
+    # so nothing of the attention is kept
+    assert counters["model.kept_attention_outputs"] == 0
 
 
 def test_last_pass_only_is_another_model():
@@ -242,7 +248,7 @@ def test_recomputation_on_and_off_give_equal_gradients(monkeypatch,
         CFG, fused_head=True))).lower(params, toks).as_text(debug_info=True)
     on = grad()
     assert "rematted_computation" in text()  # the rule: a looped stack
-    monkeypatch.setattr(transformer.nn, "remat", lambda module: module)
+    monkeypatch.setattr(transformer.nn, "remat", lambda module, **_: module)
     off = grad()
     assert "rematted_computation" not in text()
     for a, b in zip(jax.tree.leaves(on), jax.tree.leaves(off)):
@@ -254,6 +260,75 @@ def test_recomputation_on_and_off_give_equal_gradients(monkeypatch,
     assert "rematted_computation" not in jax.jit(jax.grad(
         transformer.make_loss_fn(plain))).lower(
             transformer.init_params(plain), toks).as_text(debug_info=True)
+
+
+@pytest.fixture
+def kernel_attention(monkeypatch):
+    """``hvd.local_attention``'s ``impl='auto'`` resolved to the Pallas
+    flash kernel, as on a TPU above T=2048; off the TPU the kernel runs
+    interpreted (``ops/flash_attention._resolve``)."""
+    monkeypatch.setattr(sequence, "local_attention_impl", lambda t: "flash")
+
+
+def test_the_backward_reads_the_attention_kernels_output_back(
+        monkeypatch, kernel_attention):
+    """The recomputation rule with the kernel in the block: the gradient
+    holds the forward kernel once a layer (the scan's forward body) where
+    a bare ``nn.remat`` holds it twice (and again in the backward body),
+    and the backward kernel once either way; the saved output and
+    log-sum-exp are the bits the second run gave, so the gradients are
+    equal to the bare rule's and to no recomputation's bit for bit."""
+    params = _tree(_weights(gate_std=20.0))
+    toks = jnp.asarray(_tokens(4, rows=2))
+    layers = SMALL["num_hidden_layers"]
+    remat = nn.remat
+    forms = {"kept": remat,
+             "bare": lambda module, **_: remat(module),
+             "off": lambda module, **_: module}
+    calls, grads = {}, {}
+    for name, form in forms.items():
+        monkeypatch.setattr(transformer.nn, "remat", form)
+        grad = jax.value_and_grad(transformer.make_loss_fn(  # traced anew
+            CFG, fused_head=True, exit_beta=BETA))
+        calls[name] = kernel_calls(jax.make_jaxpr(grad)(params, toks).jaxpr)
+        grads[name] = jax.jit(grad)(params, toks)
+    assert calls["kept"] == {"hvd_flash_fwd": layers,
+                             "hvd_flash_bwd": layers}
+    assert calls["bare"] == {"hvd_flash_fwd": 2 * layers,
+                             "hvd_flash_bwd": layers}
+    assert calls["off"] == calls["kept"]
+    for other in ("bare", "off"):
+        for (path, a), b in zip(
+                jax.tree_util.tree_leaves_with_path(grads["kept"]),
+                jax.tree.leaves(grads[other])):
+            np.testing.assert_array_equal(
+                a, b, err_msg=other + jax.tree_util.keystr(path))
+
+
+def test_a_stack_run_once_has_no_checkpoint_and_keeps_nothing_by_name(
+        kernel_attention):
+    """... whatever its attention: one forward kernel a layer, one
+    backward, no ``checkpoint`` in the gradient (the looped stack's has
+    one), and the counter 0 (a looped step's: ``tests/test_tracing.py``)."""
+    plain = CFG._replace(recurrent_steps=1, exit_gate=False)
+    toks = jnp.asarray(_tokens(5, rows=2))
+    params = transformer.init_params(plain)
+    loss_fn = transformer.make_loss_fn(plain, fused_head=True)
+    jaxpr = jax.make_jaxpr(jax.grad(loss_fn))(params, toks).jaxpr
+    assert kernel_calls(jaxpr) == {
+        "hvd_flash_fwd": plain.num_layers, "hvd_flash_bwd": plain.num_layers}
+    primitives = lambda j: {eqn.primitive.name for eqn in equations(j)}
+    assert "remat2" not in primitives(jaxpr)  # jax.checkpoint's
+    assert "remat2" in primitives(jax.make_jaxpr(jax.grad(
+        transformer.make_loss_fn(CFG, fused_head=True)))(
+            transformer.init_params(CFG), toks).jaxpr)
+    hvd.shutdown()
+    hvd.init(devices=jax.devices()[:1])
+    step = hvd.spmd(lambda p, toks: hvd.allreduce(loss_fn(p, toks)))
+    step(hvd.replicate(params), hvd.rank_stack([np.asarray(toks)]))
+    [program] = timeline.record()["programs"].values()
+    hvd.shutdown()
+    assert program["counters"]["model.kept_attention_outputs"] == 0
 
 
 def _old_loss(cfg, fused):

@@ -270,3 +270,50 @@ class TestHorovodXlaOptionsEnv:
         out = double(np.ones((n, 4), np.float32))
         np.testing.assert_allclose(np.asarray(out), float(n))
         hvd.shutdown()
+
+
+class TestLoopedStepKeepsWhatTheKernelWrote:
+    def test_as_many_forward_kernels_as_backward_ones(self):
+        """The recomputation rule of a looped stack, in a REAL executable
+        (models/transformer.py): compiled for v5e:2x2 above T=2048, where
+        attention is the Pallas kernel, the looped step holds as many
+        ``hvd_flash_fwd`` custom calls as ``hvd_flash_bwd`` ones — one a
+        layer in the forward passes' loop, none in the backward's, which
+        reads the kept outputs and log-sum-exps back. (A bare
+        ``nn.remat`` holds twice as many.)"""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from horovod_tpu.core.state import AXIS_NAME
+        from horovod_tpu.models import transformer
+
+        devices = _topo(4, "v5e:2x2")
+        hvd.shutdown()
+        hvd.init(devices=devices)
+        cfg = transformer.TransformerConfig(
+            vocab_size=512, num_layers=2, num_heads=2, embed_dim=256,
+            mlp_dim=512, max_seq_len=4096, ffn="swiglu", sandwich_norm=True,
+            recurrent_steps=3, exit_gate=True)
+        loss_fn = transformer.make_loss_fn(cfg, fused_head=True)
+
+        def grad_step(params, tokens):
+            loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+            return hvd.allreduce_gradients(grads), hvd.allreduce(loss)
+
+        shard = NamedSharding(hvd.get_group(0).mesh, P(AXIS_NAME))
+        stacked = lambda a, dtype: jax.ShapeDtypeStruct(
+            (4,) + a.shape, dtype, sharding=shard)
+        params = jax.tree.map(
+            lambda a: stacked(a, a.dtype),
+            jax.eval_shape(lambda: transformer.init_params(cfg)))
+        tokens = stacked(jnp.zeros((1, 4096)), jnp.int32)
+        # The suite's float64 (conftest.py) is not the chip's: the kernels
+        # lower for the TPU with 32-bit indices, as they run there.
+        with jax.enable_x64(False):
+            txt = hvd.spmd(grad_step).lower(params, tokens).compile(
+                ).as_text()
+        hvd.shutdown()
+        calls = {name: len(re.findall(
+            rf"= [^\n]* custom-call\([^\n]*{name}", txt))
+            for name in ("hvd_flash_fwd", "hvd_flash_bwd")}
+        assert calls == {"hvd_flash_fwd": cfg.num_layers,
+                         "hvd_flash_bwd": cfg.num_layers}

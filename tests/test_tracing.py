@@ -20,7 +20,7 @@ from horovod_tpu.analysis import hlo
 from horovod_tpu.core import timeline, xprof
 from horovod_tpu.models import transformer
 from horovod_tpu.ops import exchange, optim
-from horovod_tpu.parallel import spmd as spmd_mod
+from horovod_tpu.parallel import sequence, spmd as spmd_mod
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RECORDED = os.path.join(ROOT, "benchmark", "tests", "recorded")
@@ -37,13 +37,13 @@ def _world4():
     hvd.init(devices=jax.devices()[:4])
 
 
-def _lm_step(compression=None):
+def _lm_step(compression=None, cfg=CFG):
     """A tiny LM step as the benchmark's runner builds it: (step, params,
     optimizer state, tokens), rank-stacked over four CPU devices."""
-    params = transformer.init_params(CFG)
+    params = transformer.init_params(cfg)
     opt = hvd.DistributedOptimizer(optim.adamw(1e-3),
                                    compression=compression)
-    loss_fn = transformer.make_loss_fn(CFG, fused_head=True)
+    loss_fn = transformer.make_loss_fn(cfg, fused_head=True)
 
     def train_step(p, s, toks):
         loss, grads = jax.value_and_grad(loss_fn)(p, toks)
@@ -177,6 +177,25 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
         # applies each of its CFG.num_layers blocks once, recomputes none
         # and has one head
         "model.block_applications": 2, "model.recomputed_blocks": 0,
+        "model.kept_attention_outputs": 0, "model.head_applications": 1}
+
+
+@pytest.mark.parametrize("impl", ["flash", "xla"])
+def test_a_looped_steps_record_counts_the_kept_attention_outputs(
+        monkeypatch, impl):
+    """A looped stack recomputes every block application, and of each one
+    whose attention is the Pallas kernel (here interpreted) its backward
+    reads the kernel's output back; where attention does not go through
+    the kernel nothing is named, so nothing is kept."""
+    monkeypatch.setattr(sequence, "local_attention_impl", lambda t: impl)
+    _world4()
+    step, ps, ss, toks, _ = _lm_step(cfg=CFG._replace(recurrent_steps=3))
+    step(ps, ss, toks)
+    counters = timeline.record()["programs"][TAG]["counters"]
+    hvd.shutdown()
+    assert {k: v for k, v in counters.items() if k.startswith("model.")} == {
+        "model.block_applications": 6, "model.recomputed_blocks": 6,
+        "model.kept_attention_outputs": 6 if impl == "flash" else 0,
         "model.head_applications": 1}
 
 
