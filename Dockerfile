@@ -10,12 +10,6 @@
 
 FROM python:3.12-slim
 
-# g++ compiles the native control-plane core (horovod_tpu/core/native)
-# lazily on first import.
-RUN apt-get update && apt-get install -y --no-install-recommends \
-        build-essential \
-        && rm -rf /var/lib/apt/lists/*
-
 # On a TPU VM, swap the extra for the libtpu-bundled wheel:
 #   pip install 'jax[tpu]' -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
 RUN pip install --no-cache-dir \

@@ -40,9 +40,7 @@ mesh; ``main()`` owns the gate and the full-width config.
 Last stdout line on success:
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
 Without an accelerator, or in a directory that holds nothing else of the
-repo, it exits non-zero and prints no result. It starts no Python child
-process (``hvd.init`` may run one g++ build of the control plane, which
-never touches JAX and has ended before ``init`` returns).
+repo, it exits non-zero and prints no result. It starts no child process.
 """
 
 from __future__ import annotations
@@ -145,15 +143,14 @@ def gate() -> dict:
 
 
 def describe_installation() -> None:
-    """Versions, compile-cache directory, control plane, cost-model
-    source — what a reader needs to tell two runs apart."""
+    """Versions, compile-cache directory, cost-model source — what a
+    reader needs to tell two runs apart."""
     import importlib.metadata as md
 
     import jax
     import jaxlib
 
     import horovod_tpu as hvd
-    from horovod_tpu.core import state as _state
     from horovod_tpu.ops import topology
     from horovod_tpu.utils import costs, env
 
@@ -174,10 +171,9 @@ def describe_installation() -> None:
     # innermost frame only.
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     hvd.init()
-    plane = "native (hvd_core.cc)" if _state.native_core() else "python"
     model = costs.model_for(topology.discover(hvd.get_group(0)))
-    _say("gate", f"control plane: {plane}; cost model source: "
-                 f"{model.source} (tuning cache {env.tuning_cache_path()})")
+    _say("gate", f"cost model source: {model.source} "
+                 f"(tuning cache {env.tuning_cache_path()})")
     hvd.shutdown()
 
 

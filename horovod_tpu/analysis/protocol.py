@@ -116,7 +116,7 @@ def validate_requests(requests: Sequence[Req], group_size: int) -> Verdict:
     allgather/gather, root-rank agreement for broadcast/gather. Error
     messages are byte-identical to the reference's (the error-path tests
     in the live layer assert them). Returns a :class:`Verdict`; the live
-    wrapper (``negotiate.validate_py``) raises ``HorovodError`` on
+    wrapper (``negotiate.validate``) raises ``HorovodError`` on
     ``error``."""
     if not requests:
         return Verdict(error="No requests to validate.")

@@ -18,7 +18,7 @@ MPI_Send/Probe/Recv as the control-plane transport:
   process submits a descriptor (name, op, dtype, shape, root, group) for the
   ranks it hosts; process 0 collects one entry per process, merges them into
   per-rank requests, runs the same validation as the single-controller path
-  (``negotiate.validate_py``, byte-matching the reference's
+  (``negotiate.validate``, byte-matching the reference's
   ``ConstructMPIResponse`` messages, mpi_ops.cc:374-592), and publishes the
   verdict. Every process raises the same :class:`HorovodError` on mismatch —
   the multi-process analog of the reference's error-path tests

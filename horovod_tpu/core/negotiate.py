@@ -10,8 +10,6 @@ cross-rank correlation key, and any mismatch in dtype / op / shape / root
 raises :class:`HorovodError` with a message in the reference's format, which
 is what the reference's error-path tests assert (mpi_ops_test.py:284-356).
 
-This module is the pure-Python implementation; when the native core extension
-is available (``horovod_tpu.core.native``), validation is delegated to it.
 The semantic checks themselves live in the side-effect-free protocol module
 (:mod:`horovod_tpu.analysis.protocol` — ``validate_requests``), which the
 ``hvd-model`` checker exhaustively explores; this module is the live wrapper
@@ -70,23 +68,34 @@ class Response:
     root_rank: int = -1
 
 
-def validate(requests: Sequence[Request], group_size: int) -> Response:
-    """Cross-validate all ranks' requests for one tensor name.
+def _to_proto(r: Request) -> _proto.Req:
+    return _proto.Req(rank=r.rank, name=r.name, op=r.op.value, dtype=r.dtype,
+                      shape=tuple(r.shape), root_rank=r.root_rank,
+                      group=r.group)
 
-    Delegates to the native core's request table when loaded (hvd_core.cc
-    ValidateEntry — identical checks, byte-identical messages), else runs the
-    pure-Python port below.
+
+def validate(requests: Sequence[Request], group_size: int) -> Response:
+    """Cross-validate all ranks' requests for one tensor name: the semantic
+    checks of ``ConstructMPIResponse`` (mpi_ops.cc:374-592) — dtype match
+    (:387-398), op match (:400-416), exact shape match for
+    allreduce/broadcast (:423-451), rank-count + trailing-dim match with
+    per-rank first-dim collection for allgather/gather (:453-517), root-rank
+    agreement for broadcast/gather (:519-539). Raises :class:`HorovodError`
+    on any mismatch.
+
+    The checks themselves are the pure transition function
+    ``analysis.protocol.validate_requests`` — the exact code the
+    ``hvd-model`` checker explores; this function converts types, raises,
+    and writes the negotiation phases to the timeline (timeline.cc
+    NEGOTIATE events via IncrementTensorCount). The error messages stay
+    byte-identical to the reference's (mpi_ops_test.py:284-356 asserts
+    them).
     """
-    from horovod_tpu.core import state as _state
     from horovod_tpu.core import timeline as _tl
 
-    native = _state.native_core()
-    if native is not None and requests:
-        return _validate_native(native, requests, group_size)
-    # Pure-Python path: emit the negotiation phases the native table would
-    # (timeline.cc NEGOTIATE events via IncrementTensorCount).
     tl = _tl.session()
-    if tl.active and requests:
+    traced = tl.active and bool(requests)
+    if traced:
         tag = f"NEGOTIATE_{requests[0].op.name.lower()}"
         tl.event(requests[0].name, tag, "B")
         # Per-rank ready ticks (NegotiateRankReady, timeline.cc:117-125) —
@@ -95,67 +104,14 @@ def validate(requests: Sequence[Request], group_size: int) -> Response:
         # as each process's submission arrives (multihost.Negotiator).
         for r in requests:
             tl.rank_ready(r.name, r.rank)
-        try:
-            return validate_py(requests, group_size)
-        finally:
+    try:
+        verdict = _proto.validate_requests(
+            tuple(_to_proto(r) for r in requests), group_size)
+        if verdict.error is not None:
+            raise HorovodError(verdict.error)
+    finally:
+        if traced:
             tl.event(requests[0].name, tag, "E")
-    return validate_py(requests, group_size)
-
-
-def _validate_native(native, requests: Sequence[Request],
-                     group_size: int) -> Response:
-    """Drive the native request table: one submit per rank
-    (IncrementTensorCount), response ready when the last rank lands."""
-    first = requests[0]
-    if len(requests) != group_size:
-        raise HorovodError(
-            f"Tensor {first.name} has {len(requests)} request(s) but the "
-            f"group has {group_size} rank(s); every rank must submit the "
-            f"collective.")
-    group_index = first.group
-    status = 0
-    err = ""
-    for r in requests:
-        status, err = native.submit(
-            group_index, r.name, r.op.value, r.dtype, r.shape, r.root_rank,
-            r.rank)
-        if status < 0:
-            raise HorovodError(err)
-    if status != 1:
-        raise HorovodError(
-            f"Tensor {first.name} did not complete negotiation "
-            f"(internal error).")
-    sizes = native.response_sizes(group_index, first.name) or []
-    root = native.response_root(group_index, first.name)
-    native.response_done(group_index, first.name)
-    return Response(name=first.name, op=first.op, dtype=first.dtype,
-                    tensor_sizes=tuple(sizes), root_rank=root)
-
-
-def _to_proto(r: Request) -> _proto.Req:
-    return _proto.Req(rank=r.rank, name=r.name, op=r.op.value, dtype=r.dtype,
-                      shape=tuple(r.shape), root_rank=r.root_rank,
-                      group=r.group)
-
-
-def validate_py(requests: Sequence[Request], group_size: int) -> Response:
-    """The semantic checks of ``ConstructMPIResponse`` (mpi_ops.cc:374-592):
-    dtype match (:387-398), op match (:400-416), exact shape match for
-    allreduce/broadcast (:423-451), rank-count + trailing-dim match with
-    per-rank first-dim collection for allgather/gather (:453-517), root-rank
-    agreement for broadcast/gather (:519-539). Raises :class:`HorovodError`
-    on any mismatch.
-
-    The checks themselves are the pure transition function
-    ``analysis.protocol.validate_requests`` — the exact code the
-    ``hvd-model`` checker explores; this wrapper only converts types and
-    raises. The error messages stay byte-identical to the reference's
-    (mpi_ops_test.py:284-356 asserts them).
-    """
-    verdict = _proto.validate_requests(
-        tuple(_to_proto(r) for r in requests), group_size)
-    if verdict.error is not None:
-        raise HorovodError(verdict.error)
     return Response(name=verdict.name, op=CollectiveOp(verdict.op),
                     dtype=verdict.dtype, tensor_sizes=verdict.tensor_sizes,
                     root_rank=verdict.root_rank)

@@ -111,7 +111,6 @@ class _State:
         self.devices: tuple[jax.Device, ...] = ()
         self.groups: list[Group] = []
         self.fusion_threshold = _env.DEFAULT_FUSION_THRESHOLD
-        self.native = None  # NativeCore when the C++ control plane is loaded
         # Bumped on every successful init; compiled-program caches include it
         # in their keys so a shutdown/re-init with a different group layout
         # (but an equal mesh) can never replay a stale closure.
@@ -121,9 +120,6 @@ class _State:
         self.initialized = False
         self.devices = ()
         self.groups = []
-        if self.native is not None:
-            self.native.close()
-            self.native = None
 
 
 _state = _State()
@@ -239,19 +235,8 @@ def _init(group_ranks, devices) -> None:
         _state.devices = devs
         _state.groups = groups
         _state.fusion_threshold = _env.fusion_threshold_bytes()
-        # Native control plane (validation / fusion planning / stall
-        # detection / timeline), the analog of InitializeHorovodOnce building
-        # the C++ runtime (mpi_ops.cc:1815-1892). Optional: the pure-Python
-        # implementations carry identical semantics.
-        from horovod_tpu.core import native as _native
         from horovod_tpu.core import timeline as _timeline
 
-        if _native.available():
-            try:
-                _state.native = _native.NativeCore(
-                    [g.size for g in groups], _env.stall_warning_seconds())
-            except RuntimeError:
-                _state.native = None
         # Coordinator-only, like the reference ("Open the timeline file on
         # coordinator", mpi_ops.cc:1486-1489): in multi-host mode only
         # process 0 — which drives the negotiation and sees every rank's
@@ -259,7 +244,7 @@ def _init(group_ranks, devices) -> None:
         from horovod_tpu.core import multihost as _mh
 
         if not _mh.active() or _mh.process_index() == 0:
-            _timeline.maybe_start(_state.native)
+            _timeline.maybe_start()
         _state.generation += 1
         _state.initialized = True
         if _mh.active():
@@ -341,8 +326,7 @@ def reconfigure(ranks: Sequence[int]) -> Group:
     global device indices, so a dropped rank's row simply leaves every
     group); the generation bumps exactly like ``Trainer.restore`` so
     compiled-program caches, the multi-host KV namespace, and the
-    heartbeat keys all roll to a fresh namespace; the native control
-    plane (when loaded) is rebuilt at the new group size. User subset
+    heartbeat keys all roll to a fresh namespace. User subset
     groups are deliberately NOT carried across — a subset referencing a
     dropped rank has no meaning in the new world, and the elastic
     training loop only drives group 0."""
@@ -365,15 +349,6 @@ def reconfigure(ranks: Sequence[int]) -> Group:
                     f"Rank {r} out of range for world size {world}.")
         _state.groups = [_build_group(0, rs, _state.devices)]
         _state.generation += 1
-        if _state.native is not None:
-            from horovod_tpu.core import native as _native
-
-            _state.native.close()
-            try:
-                _state.native = _native.NativeCore(
-                    [len(rs)], _env.stall_warning_seconds())
-            except RuntimeError:
-                _state.native = None
         new_group = _state.groups[0]
     # Cached collective programs close over the OLD Group objects under
     # the same group index — exactly the shutdown/re-init hazard the
@@ -382,11 +357,6 @@ def reconfigure(ranks: Sequence[int]) -> Group:
 
     _coll.clear_caches()
     return new_group
-
-
-def native_core():
-    """The loaded NativeCore instance, or None (pure-Python control plane)."""
-    return _state.native if _state.initialized else None
 
 
 def is_initialized() -> bool:

@@ -6,10 +6,9 @@ Reference: ``tensorflow/timeline.{h,cc}`` — a coordinator-side Chrome tracing
 (pid) with metadata events; negotiation and execution phases appear as B/E
 events with µs timestamps; the file flushes every second (timeline.h:35).
 
-Here the writer lives in the native core (hvd_core.cc Timeline class) with a
-pure-Python fallback below producing the same JSON. Activity vocabulary keeps
-the reference's names (docs/timeline.md:25-43) with the MPI-specific ones
-mapped to their XLA equivalents:
+Here the writer is :class:`_ChromeTraceWriter` below. Activity vocabulary
+keeps the reference's names (docs/timeline.md:25-43) with the MPI-specific
+ones mapped to their XLA equivalents:
 
     NEGOTIATE_<OP>           request submitted → all ranks matched
     QUEUE                    host-side dispatch queueing
@@ -55,8 +54,10 @@ RING_SPANS = 4096  # newest spans kept once the first step was dispatched
 SPAN_ROW = "_hvd"  # the Chrome file's row of span() pairs
 
 
-class _PyTimeline:
-    """Pure-Python fallback writer, format-compatible with hvd_core.cc."""
+class _ChromeTraceWriter:
+    """The Chrome-tracing JSON file: one fake process a tensor, events on
+    the monotonic clock in µs since the file was opened, flushed every
+    second."""
 
     def __init__(self, path: str):
         self._f = open(path, "w")
@@ -138,7 +139,7 @@ class _Span:
         stack = tl._stack()
         self._parent = stack[-1] if stack else None
         stack.append(self.name)
-        self._in_file = tl._active  # hvd/init opens the file inside itself
+        self._in_file = tl.active  # hvd/init opens the file inside itself
         tl.event(SPAN_ROW, self.name, "B")
         self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
@@ -159,12 +160,11 @@ class _Span:
 
 
 class Timeline:
-    """Session timeline: prefers the native writer, falls back to Python."""
+    """Session timeline: the file's writer while one is open, and the
+    program's in-memory account of itself."""
 
     def __init__(self) -> None:
-        self._py: _PyTimeline | None = None
-        self._native = None  # NativeCore owning the writer
-        self._active = False
+        self._writer: _ChromeTraceWriter | None = None
         # True when ``HOROVOD_TIMELINE_DEVICE=1`` was set when the timeline
         # started (latched in :meth:`start`): per-step spans come from a
         # sampled ``jax.profiler`` capture with device timestamps.
@@ -249,34 +249,23 @@ class Timeline:
                 "programs": {t: dict(p, counters=dict(p["counters"]))
                              for t, p in self.programs.items()}}
 
-    def start(self, path: str, native_core=None) -> None:
-        if self._active:
+    def start(self, path: str) -> None:
+        if self.active:
             return
-        # Device-fidelity mode injects xplane-derived spans with explicit
-        # timestamps, which only the Python writer supports — the native
-        # writer stamps its own clock on every event. The env var is
-        # latched HERE: flipping HOROVOD_TIMELINE_DEVICE after start()
-        # cannot change the writer choice, so honoring a late flip would
-        # silently drop every device span into a native-only timeline.
+        # Latched HERE: a run's per-step rows are either all host-stamped
+        # or all device-true, whatever HOROVOD_TIMELINE_DEVICE is flipped
+        # to after start().
         self.device_mode = _env.timeline_device_mode()
-        if (native_core is not None and not self.device_mode
-                and native_core.timeline_start(path)):
-            self._native = native_core
-        else:
-            self._py = _PyTimeline(path)
-        self._active = True
+        self._writer = _ChromeTraceWriter(path)
 
     @property
     def active(self) -> bool:
-        return self._active
+        return self._writer is not None
 
     def event(self, tensor: str, activity: str, phase: str) -> None:
-        if not self._active:
-            return
-        if self._native is not None:
-            self._native.timeline_event(tensor, activity, phase)
-        elif self._py is not None:
-            self._py.event(tensor, activity, phase)
+        writer = self._writer  # stop() on another thread may clear it
+        if writer is not None:
+            writer.event(tensor, activity, phase)
 
     def rank_ready(self, tensor: str, rank: int) -> None:
         """Per-rank negotiation-ready tick — the NegotiateRankReady analog
@@ -292,21 +281,15 @@ class Timeline:
 
     def event_at(self, tensor: str, activity: str, ts_us: float,
                  dur_us: float) -> None:
-        """Explicit-timestamp complete event (device-true spans), by the
-        Python writer: device mode, its only caller, always has one."""
-        if self._py is not None:
-            self._py.event_at(tensor, activity, ts_us, dur_us)
+        """Explicit-timestamp complete event (device-true spans)."""
+        writer = self._writer
+        if writer is not None:
+            writer.event_at(tensor, activity, ts_us, dur_us)
 
     def stop(self) -> None:
-        if not self._active:
-            return
-        if self._native is not None:
-            self._native.timeline_stop()
-            self._native = None
-        if self._py is not None:
-            self._py.close()
-            self._py = None
-        self._active = False
+        writer, self._writer = self._writer, None
+        if writer is not None:
+            writer.close()
 
 
 _session = Timeline()
@@ -316,11 +299,11 @@ def session() -> Timeline:
     return _session
 
 
-def maybe_start(native_core=None) -> None:
+def maybe_start() -> None:
     """Start the timeline if ``HOROVOD_TIMELINE`` is set (mpi_ops.cc:1486)."""
     path = _env.timeline_path()
     if path:
-        _session.start(path, native_core)
+        _session.start(path)
 
 
 def stop() -> None:

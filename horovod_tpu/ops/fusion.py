@@ -225,9 +225,7 @@ def plan_buckets(leaves: Sequence[jax.Array], threshold_bytes: int,
     """Partition leaves (in order) into fusion buckets.
 
     threshold 0 disables fusion — every leaf is its own bucket
-    (mpi_ops.cc:1492-1495 semantics). Uses the native planner
-    (hvd_core_plan_fusion) when loaded; the Python fallback below implements
-    identical semantics. ``compression`` (a resolved
+    (mpi_ops.cc:1492-1495 semantics). ``compression`` (a resolved
     :class:`~horovod_tpu.ops.compression.Compressor` or None) annotates
     each bucket with its wire dtype; bucket boundaries stay planned on
     logical bytes (see :class:`Bucket`). ``algo`` (a concrete
@@ -239,28 +237,7 @@ def plan_buckets(leaves: Sequence[jax.Array], threshold_bytes: int,
     ``cross_compression`` the per-phase annotation of hierarchical
     buckets (:func:`_annotate_phase_wire`).
     """
-    from horovod_tpu.core import state as _state
-
-    native = _state.native_core()
-    if native is not None and leaves:
-        dtype_codes: dict = {}
-        codes = []
-        nbytes = []
-        for leaf in leaves:
-            codes.append(dtype_codes.setdefault(str(leaf.dtype),
-                                                len(dtype_codes)))
-            nbytes.append(leaf.size * leaf.dtype.itemsize)
-        ids = native.plan_fusion(threshold_bytes, nbytes, codes)
-        buckets = []
-        for i, bid in enumerate(ids):
-            if bid == len(buckets):
-                buckets.append(Bucket((i,), leaves[i].dtype, nbytes[i]))
-            else:
-                b = buckets[bid]
-                buckets[bid] = Bucket(b.indices + (i,), b.dtype,
-                                      b.total_bytes + nbytes[i])
-    else:
-        buckets = plan_buckets_py(leaves, threshold_bytes)
+    buckets = _partition(leaves, threshold_bytes)
     buckets = _annotate_algo(_annotate_wire(buckets, compression,
                                             group_size), algo)
     buckets = _annotate_phase_wire(buckets, compression, cross_compression)
@@ -353,9 +330,10 @@ def _annotate_algo(buckets: list[Bucket], algo) -> list[Bucket]:
     return [dataclasses.replace(b, algo=pick(b)) for b in buckets]
 
 
-def plan_buckets_py(leaves: Sequence[jax.Array],
-                    threshold_bytes: int) -> list[Bucket]:
-    """Pure-Python fusion planner (reference semantics, mpi_ops.cc:1604-1637)."""
+def _partition(leaves: Sequence[jax.Array],
+               threshold_bytes: int) -> list[Bucket]:
+    """Contiguous same-dtype runs of at most ``threshold_bytes`` (reference
+    semantics, mpi_ops.cc:1604-1637)."""
     buckets: list[Bucket] = []
     cur: list[int] = []
     cur_dtype = None

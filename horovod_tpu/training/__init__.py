@@ -13,7 +13,6 @@ from horovod_tpu.training.callbacks import (
     LearningRateWarmupCallback,
     MetricAverageCallback,
     ModelCheckpointCallback,
-    StallWarningCallback,
 )
 from horovod_tpu.training.estimator import Estimator, EstimatorSpec, ModeKeys
 from horovod_tpu.training.loop import Trainer, adadelta, adam, sgd
@@ -35,7 +34,6 @@ __all__ = [
     "MetricAverageCallback",
     "ModeKeys",
     "ModelCheckpointCallback",
-    "StallWarningCallback",
     "Trainer",
     "adadelta",
     "adam",

@@ -192,26 +192,6 @@ class LearningRateWarmupCallback(LearningRateScheduleCallback):
                   f"to {self.trainer.get_lr():.6g}.")
 
 
-class StallWarningCallback(Callback):
-    """Surface native-core stall reports during training — the analog of the
-    coordinator's 60 s CheckForStalledTensors sweep (mpi_ops.cc:1369-1412,
-    invoked from the tick loop at :1664-1669)."""
-
-    def __init__(self, group: int = 0) -> None:
-        self.group = group
-
-    def on_batch_end(self, batch: int, logs: dict | None = None) -> None:
-        from horovod_tpu.core import state as _state
-
-        core = _state.native_core()
-        if core is None:
-            return
-        for report in core.stalled(self.group):
-            print(f"WARNING: One or more tensors were submitted to be "
-                  f"reduced, gathered or broadcasted by subset of ranks and "
-                  f"are waiting for remainder of ranks: {report}")
-
-
 class ModelCheckpointCallback(Callback):
     """Rank-0-writes checkpointing, the reference's convention
     (examples/keras_mnist_advanced.py:103-104, SURVEY §5.4): only the

@@ -1,10 +1,14 @@
-"""Native C++ control-plane tests: parity with the Python implementations.
+"""The control plane: request validation, the fusion planner's partition
+and the timeline writer, held to the reference's words.
 
-The native core (hvd_core.cc) must be a drop-in for core/negotiate.py and
-ops/fusion.py — same semantics, byte-identical error messages — mirroring how
-the reference's single C++ runtime backs every binding (mpi_ops.cc).
+Validation messages are compared whole with literal strings (the
+reference's format, mpi_ops.cc ConstructMPIResponse); the planner with the
+partition written out below; the timeline by reading the Chrome-tracing
+file back.
 """
 
+import importlib.util
+import json
 import os
 
 import jax.numpy as jnp
@@ -13,12 +17,10 @@ import pytest
 
 import horovod_tpu as hvd
 from horovod_tpu.core import negotiate as neg
-from horovod_tpu.core import native
+from horovod_tpu.core import state as _state
+from horovod_tpu.core import timeline
 from horovod_tpu.core.state import HorovodError
 from horovod_tpu.ops import fusion
-
-pytestmark = pytest.mark.skipif(not native.available(),
-                                reason="native core not built")
 
 
 def _req(rank, name="t", op=neg.CollectiveOp.ALLREDUCE, dtype="float32",
@@ -28,136 +30,135 @@ def _req(rank, name="t", op=neg.CollectiveOp.ALLREDUCE, dtype="float32",
 
 
 MISMATCH_CASES = [
-    # (requests, expected-match) — each exercises one ConstructMPIResponse check
+    # (requests, the whole message) — each exercises one
+    # ConstructMPIResponse check
     ([_req(0), _req(1, dtype="int32")] + [_req(r) for r in range(2, 8)],
-     "Mismatched data types"),
+     "Mismatched data types: One or more ranks sent tensors of type "
+     "float32, but one or more other ranks sent tensors of type int32 for "
+     "tensor t."),
     ([_req(0), _req(1, op=neg.CollectiveOp.ALLGATHER)]
      + [_req(r) for r in range(2, 8)],
-     "Mismatched collective operations"),
+     "Mismatched collective operations: One or more ranks did an "
+     "allreduce, but one or more other ranks did an allgather on tensor t."),
     ([_req(0), _req(1, shape=(3, 3))] + [_req(r) for r in range(2, 8)],
-     "Mismatched allreduce tensor shapes"),
+     "Mismatched allreduce tensor shapes: One or more ranks sent tensors of "
+     "shape [2, 3], but one or more other ranks sent tensors of shape "
+     "[3, 3] on tensor t."),
     ([_req(r, op=neg.CollectiveOp.ALLGATHER) for r in range(7)]
      + [_req(7, op=neg.CollectiveOp.ALLGATHER, shape=(2,))],
-     "Mismatched allgather tensor shapes"),
+     "Mismatched allgather tensor shapes: One or more ranks sent tensors of "
+     "rank 2, but one or more other ranks sent tensors of rank 1 on tensor "
+     "t."),
     ([_req(r, op=neg.CollectiveOp.ALLGATHER) for r in range(7)]
      + [_req(7, op=neg.CollectiveOp.ALLGATHER, shape=(4, 9))],
-     "trailing dimensions"),
+     "Mismatched allgather tensor shapes: trailing dimensions of tensor t "
+     "differ between ranks ([2, 3] vs [4, 9]); only the first dimension may "
+     "vary."),
     ([_req(r, op=neg.CollectiveOp.GATHER, root=0) for r in range(7)]
      + [_req(7, op=neg.CollectiveOp.GATHER, root=3)],
-     "Mismatched gather root ranks"),
+     "Mismatched gather root ranks: One rank specified root rank 0, but "
+     "another rank specified root rank 3 for tensor t."),
     ([_req(r, op=neg.CollectiveOp.BROADCAST, root=55) for r in range(8)],
-     "Invalid root rank"),
+     "Invalid root rank 55 for tensor t in a group of size 8."),
     ([_req(r, op=neg.CollectiveOp.ALLGATHER, shape=()) for r in range(8)],
-     "rank-zero tensor"),
+     "Rank zero tried to allgather a rank-zero tensor t, which is not "
+     "allowed."),
     ([_req(0), _req(0)] + [_req(r) for r in range(2, 8)],
-     "submitted twice"),
+     "Tensor t was submitted twice by rank 0."),
 ]
 
 
-class TestValidationParity:
+class TestValidation:
     @pytest.mark.parametrize("case", range(len(MISMATCH_CASES)))
-    def test_native_and_python_raise_identically(self, world, case):
-        requests, expected = MISMATCH_CASES[case]
-        native_core = hvd.get_group(0) and None  # state holds the core
-        from horovod_tpu.core import state as st
-
-        assert st.native_core() is not None
-        with pytest.raises(HorovodError, match=expected) as native_err:
-            neg._validate_native(st.native_core(), requests, 8)
-        with pytest.raises(HorovodError, match=expected) as py_err:
-            neg.validate_py(requests, 8)
-        assert str(native_err.value) == str(py_err.value)
+    def test_mismatch_raises_the_references_message(self, world, case):
+        requests, message = MISMATCH_CASES[case]
+        with pytest.raises(HorovodError) as err:
+            neg.validate(requests, 8)
+        assert str(err.value) == message
 
     def test_success_responses_match(self, world):
-        from horovod_tpu.core import state as st
-
         reqs = [_req(r, op=neg.CollectiveOp.ALLGATHER, shape=(r + 1, 4))
                 for r in range(8)]
-        rn = neg._validate_native(st.native_core(), reqs, 8)
-        rp = neg.validate_py(reqs, 8)
-        assert rn.tensor_sizes == rp.tensor_sizes == tuple(range(1, 9))
+        resp = neg.validate(reqs, 8)
+        assert resp.tensor_sizes == tuple(range(1, 9))
 
     def test_gather_root_recorded(self, world):
-        from horovod_tpu.core import state as st
-
         reqs = [_req(r, op=neg.CollectiveOp.GATHER, shape=(2, 2), root=5)
                 for r in range(8)]
-        rn = neg._validate_native(st.native_core(), reqs, 8)
-        assert rn.root_rank == 5
+        assert neg.validate(reqs, 8).root_rank == 5
 
-    def test_table_reusable_after_error(self, world):
-        """An errored negotiation must not poison the next one for the same
-        tensor name (the reference erases the entry, mpi_ops.cc:589)."""
-        from horovod_tpu.core import state as st
+    @pytest.mark.parametrize("submitted", [7, 9])
+    def test_request_count_must_be_the_groups(self, world, submitted):
+        with pytest.raises(HorovodError) as err:
+            neg.validate([_req(r) for r in range(submitted)], 8)
+        assert str(err.value) == (
+            f"Tensor t has {submitted} request(s) but the group has 8 "
+            f"rank(s); every rank must submit the collective.")
 
-        bad = [_req(0), _req(1, dtype="int32")] + [_req(r) for r in range(2, 8)]
-        with pytest.raises(HorovodError):
-            neg._validate_native(st.native_core(), bad, 8)
-        good = [_req(r) for r in range(8)]
-        resp = neg._validate_native(st.native_core(), good, 8)
+    def test_reconfigured_world_validates_at_its_new_size(self, world):
+        """After an elastic shrink the group's size is the new world's:
+        what the old world submitted is refused, and an errored
+        negotiation leaves nothing behind for the next one."""
+        _state.reconfigure(range(4))
+        assert hvd.size() == 4
+        with pytest.raises(HorovodError, match=r"has 8 request\(s\) but "
+                                               r"the group has 4 rank"):
+            neg.validate([_req(r) for r in range(8)], hvd.size())
+        resp = neg.validate([_req(r) for r in range(4)], hvd.size())
         assert resp.name == "t"
+        out = hvd.allreduce([np.ones((2,), np.float32)] * 4, average=False)
+        np.testing.assert_allclose(np.asarray(out[0]), 4.0)
 
 
-class TestFusionPlannerParity:
+def _written_out_partition(leaves, threshold):
+    """Contiguous same-dtype runs of at most ``threshold`` bytes, an
+    oversized leaf alone; 0 = every leaf alone. As (indices, bytes)."""
+    runs = []
+    for i, leaf in enumerate(leaves):
+        nbytes = leaf.size * leaf.dtype.itemsize
+        last = runs[-1] if runs else None
+        if (threshold > 0 and last is not None
+                and leaves[last[0][-1]].dtype == leaf.dtype
+                and last[1] + nbytes <= threshold):
+            runs[-1] = (last[0] + (i,), last[1] + nbytes)
+        else:
+            runs.append(((i,), nbytes))
+    return runs
+
+
+class TestFusionPlanner:
     @pytest.mark.parametrize("threshold", [0, 24, 40, 1 << 20])
-    def test_native_matches_python(self, world, threshold):
+    def test_plan_is_the_written_out_partition(self, world, threshold):
         rng = np.random.RandomState(0)
         leaves = []
         for _ in range(20):
             n = int(rng.randint(1, 30))
             dt = [np.float32, np.float64, np.int32][int(rng.randint(3))]
             leaves.append(jnp.zeros((n,), dt))
-        a = fusion.plan_buckets(leaves, threshold)
-        b = fusion.plan_buckets_py(leaves, threshold)
-        assert [x.indices for x in a] == [y.indices for y in b]
-        assert [x.total_bytes for x in a] == [y.total_bytes for y in b]
+        plan = fusion.plan_buckets(leaves, threshold)
+        assert [(b.indices, b.total_bytes) for b in plan] \
+            == _written_out_partition(leaves, threshold)
+        assert [b.priority for b in plan] == list(range(len(plan)))
 
 
-class TestStallDetection:
-    def test_partial_submission_reports_missing_ranks(self, world):
-        core = native.NativeCore([4], stall_seconds=0.0)
-        try:
-            core.submit(0, "grad/w", 0, "float32", (2,), -1, 0)
-            core.submit(0, "grad/w", 0, "float32", (2,), -1, 2)
-            import time
-
-            time.sleep(0.01)
-            reports = core.stalled(0)
-            assert len(reports) == 1
-            assert "grad/w" in reports[0]
-            assert "[ready ranks: [0, 2]]" in reports[0]
-            assert "[missing ranks: [1, 3]]" in reports[0]
-        finally:
-            core.close()
-
-    def test_no_stall_within_window(self, world):
-        core = native.NativeCore([4], stall_seconds=60.0)
-        try:
-            core.submit(0, "grad/w", 0, "float32", (2,), -1, 0)
-            assert core.stalled(0) == []
-        finally:
-            core.close()
+def _events(path):
+    # Chrome tracing tolerates the trailing comma / missing ']' (the
+    # reference also leaves the array open while streaming).
+    return json.loads(open(path).read().rstrip().rstrip(",") + "]")
 
 
 class TestTimeline:
     def test_chrome_trace_written(self, tmp_path, world):
-        import json
-
         path = str(tmp_path / "timeline.json")
-        core = native.NativeCore([2], stall_seconds=60.0)
+        tl = timeline.session()
+        tl.start(path)
         try:
-            assert core.timeline_start(path)
-            core.submit(0, "gradA", 0, "float32", (2,), -1, 0)
-            core.submit(0, "gradA", 0, "float32", (2,), -1, 1)
-            core.timeline_event("gradA", "XLA_ALLREDUCE", "B")
-            core.timeline_event("gradA", "XLA_ALLREDUCE", "E")
-            core.timeline_stop()
+            neg.validate([_req(r, name="gradA") for r in range(2)], 2)
+            tl.start_activity("gradA", "XLA_ALLREDUCE")
+            tl.end_activity("gradA", "XLA_ALLREDUCE")
         finally:
-            core.close()
-        raw = open(path).read()
-        # Chrome tracing tolerates the trailing comma / missing ']' (the
-        # reference also leaves the array open while streaming).
-        events = json.loads(raw.rstrip().rstrip(",") + "]")
+            tl.stop()
+        events = _events(path)
         names = [e["name"] for e in events]
         assert "process_name" in names            # tensor metadata row
         assert "NEGOTIATE_allreduce" in names     # negotiation phases
@@ -169,6 +170,36 @@ class TestTimeline:
         ticks = [e for e in events if e["ph"] == "X"]
         assert sorted(t["name"] for t in ticks) == ["0", "1"]
         assert all(t["dur"] == 0 for t in ticks)
+
+    @pytest.mark.parametrize("device_mode", [False, True])
+    def test_one_writer_whatever_the_device_mode(self, tmp_path,
+                                                 monkeypatch, device_mode):
+        """``HOROVOD_TIMELINE_DEVICE`` is latched at start and decides
+        where per-step rows come from — never who writes the file: host
+        events and explicit-timestamp events both land in either mode."""
+        if device_mode:
+            monkeypatch.setenv("HOROVOD_TIMELINE_DEVICE", "1")
+        else:
+            monkeypatch.delenv("HOROVOD_TIMELINE_DEVICE", raising=False)
+        path = str(tmp_path / "tl.json")
+        tl = timeline.Timeline()
+        tl.start(path)
+        try:
+            assert tl.active and tl.device_mode is device_mode
+            assert type(tl._writer) is timeline._ChromeTraceWriter
+            monkeypatch.setenv("HOROVOD_TIMELINE_DEVICE",
+                               "0" if device_mode else "1")
+            assert tl.device_mode is device_mode  # a late flip is not read
+            tl.event("w", "QUEUE", "B")
+            tl.event("w", "QUEUE", "E")
+            tl.event_at("w", "XLA_ALLREDUCE", 5.0e6, 12.5)
+        finally:
+            tl.stop()
+        assert not tl.active
+        row = [e for e in _events(path) if e["ph"] != "M"]
+        assert [(e["name"], e["ph"]) for e in row] == [
+            ("QUEUE", "B"), ("QUEUE", "E"), ("XLA_ALLREDUCE", "X")]
+        assert row[2]["dur"] == 12.5
 
 
 class TestTimelineEndToEnd:
@@ -518,63 +549,28 @@ class TestXprofSpanMapping:
 
 
 # ---------------------------------------------------------------------------
-# Only a library built from the hvd_core.cc beside it is ever loaded
+# Nothing is compiled or loaded: the package is Python down to JAX
 # ---------------------------------------------------------------------------
 
 
-def _native_copy(tmp_path):
-    """The native package loaded from a scratch copy of its directory, so
-    planted files never touch the real one."""
-    import importlib.util
-    import shutil
+def test_runs_where_nothing_can_be_compiled_or_loaded(monkeypatch):
+    import ctypes
+    import subprocess
 
-    src_dir = os.path.dirname(native.__file__)
-    for name in ("__init__.py", "hvd_core.cc"):
-        shutil.copy(os.path.join(src_dir, name), tmp_path / name)
-    spec = importlib.util.spec_from_file_location(
-        "_native_copy", tmp_path / "__init__.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    def refuse(*args, **kwargs):
+        raise AssertionError("the control plane started a process or "
+                             "loaded a library")
 
-
-def test_planted_library_is_never_loaded(tmp_path, monkeypatch):
-    from unittest import mock
-
-    mod = _native_copy(tmp_path)
-    # A stale binary under the old fixed name, and one under a hash that
-    # is not this source's: neither may ever reach dlopen.
-    planted = [tmp_path / "_hvd_core.so",
-               tmp_path / "_hvd_core.0123456789abcdef.so"]
-    for path in planted:
-        path.write_bytes(b"not built from this hvd_core.cc")
-    compiled, opened = [], []
-
-    def fake_compile(cmd, **kw):
-        compiled.append(cmd)
-        out = cmd[cmd.index("-o") + 1]
-        with open(out, "wb") as f:
-            f.write(b"fresh build")
-        return mock.Mock(returncode=0, stderr="")
-
-    monkeypatch.setattr(mod.subprocess, "run", fake_compile)
-    monkeypatch.setattr(mod.ctypes, "CDLL",
-                        lambda path: opened.append(path) or mock.MagicMock())
-    assert mod._load() is not None
-    want = mod._so_path()
-    assert os.path.dirname(want) == str(tmp_path)
-    assert len(compiled) == 1 and compiled[0][-1].endswith("hvd_core.cc")
-    assert opened == [want]
-    assert want not in [str(p) for p in planted]
-    for path in planted:
-        assert path.read_bytes() == b"not built from this hvd_core.cc"
-
-
-def test_library_name_follows_the_source(tmp_path):
-    mod = _native_copy(tmp_path)
-    before = mod._so_path()
-    with open(tmp_path / "hvd_core.cc", "a") as f:
-        f.write("\n// edited\n")
-    assert mod._so_path() != before
-    # The real package loaded exactly the library its own source names.
-    assert os.path.exists(native._so_path())
+    monkeypatch.setattr(subprocess, "run", refuse)
+    monkeypatch.setattr(subprocess, "Popen", refuse)
+    monkeypatch.setattr(ctypes, "CDLL", refuse)
+    assert importlib.util.find_spec(".native",
+                                    package="horovod_tpu.core") is None
+    hvd.shutdown()
+    hvd.init()
+    try:
+        out = hvd.allreduce([np.full((2,), r, np.float32) for r in range(8)],
+                            average=False, name="no_compiler")
+        np.testing.assert_allclose(np.asarray(out[0]), 28.0)
+    finally:
+        hvd.shutdown()

@@ -69,7 +69,7 @@ class TestSharedTransitionFunctions:
         assert _neg.CollectiveOp.REDUCESCATTER.value == proto.OP_REDUCESCATTER
         assert {op.value for op in _neg.CollectiveOp} == set(proto.OP_NAMES)
 
-    def test_validate_py_raises_protocols_exact_message(self):
+    def test_validate_raises_protocols_exact_message(self):
         reqs = [
             _neg.Request(rank=0, name="t", op=_neg.CollectiveOp.ALLREDUCE,
                          dtype="f32", shape=(4,)),
@@ -80,17 +80,17 @@ class TestSharedTransitionFunctions:
             tuple(_neg._to_proto(r) for r in reqs), 2)
         assert verdict.error is not None
         with pytest.raises(HorovodError) as e:
-            _neg.validate_py(reqs, 2)
+            _neg.validate(reqs, 2)
         assert str(e.value) == verdict.error
         assert "Mismatched data types" in verdict.error
 
-    def test_validate_py_success_matches_protocol_verdict(self):
+    def test_validate_success_matches_protocol_verdict(self):
         reqs = [
             _neg.Request(rank=r, name="g", op=_neg.CollectiveOp.ALLGATHER,
                          dtype="f32", shape=(2 + r, 3))
             for r in range(3)
         ]
-        resp = _neg.validate_py(reqs, 3)
+        resp = _neg.validate(reqs, 3)
         verdict = proto.validate_requests(
             tuple(_neg._to_proto(r) for r in reqs), 3)
         assert verdict.error is None

@@ -403,7 +403,7 @@ def test_timeline_atexit_registered_and_idempotent(tmp_path, monkeypatch):
     monkeypatch.setattr(timeline.atexit, "unregister",
                         lambda fn: registered.remove(fn))
     path = str(tmp_path / "tl.json")
-    tl = timeline._PyTimeline(path)
+    tl = timeline._ChromeTraceWriter(path)
     assert registered == [tl.close]
     tl.event("t0", "QUEUE", "B")
     tl.close()
@@ -420,7 +420,7 @@ def test_timeline_atexit_flushes_buffered_events(tmp_path):
     path = tmp_path / "crash_tl.json"
     script = (
         "from horovod_tpu.core import timeline\n"
-        f"tl = timeline._PyTimeline({str(path)!r})\n"
+        f"tl = timeline._ChromeTraceWriter({str(path)!r})\n"
         "tl.event('grad_0', 'NEGOTIATE_ALLREDUCE', 'B')\n"
         "raise RuntimeError('uncaught crash')\n"
     )

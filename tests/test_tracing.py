@@ -375,17 +375,16 @@ def test_profiled_program_is_resolved_at_shutdown(tmp_path, compile_events):
 
 def test_ring_keeps_setup_and_the_newest_spans():
     tl = timeline.Timeline()
-    with tl.span("hvd/init"):
-        with tl.span("hvd/init/native_build"):
+    with tl.span("hvd/broadcast"):
+        with tl.span("hvd/spmd/build"):
             pass
     tl.dispatched = True
     for _ in range(timeline.RING_SPANS + 100):
         with tl.span("hvd/spmd/dispatch"):
             pass
     spans = tl.record()["spans"]
-    assert [s[0] for s in spans[:2]] == ["hvd/init/native_build",
-                                         "hvd/init"]
-    assert spans[0][3] == "hvd/init" and spans[1][3] is None
+    assert [s[0] for s in spans[:2]] == ["hvd/spmd/build", "hvd/broadcast"]
+    assert spans[0][3] == "hvd/broadcast" and spans[1][3] is None
     assert len(spans) == 2 + timeline.RING_SPANS
     tl.count_plan("exchange.wire_bytes", 2)  # no program being traced
     assert tl.record()["programs"] == {}

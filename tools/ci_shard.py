@@ -39,7 +39,7 @@ SHARDS = {
         "tests/test_models.py",
     ],
     "unit-3": [
-        "tests/test_native_core.py",  # moved from unit-2 (r5 rebalance)
+        "tests/test_control_plane.py",  # moved from unit-2 (r5 rebalance)
         "tests/test_tensor_parallel.py",
         "tests/test_pipeline_parallel.py",
         "tests/test_expert_parallel.py",
