@@ -435,7 +435,7 @@ def _compressed_psum(x, comp, key, gsize, member, name, members=None,
             _compression.record_local(
                 comp.decompress(wire, meta, x.dtype, wctx))
     summed = _strategy.lower_allreduce(wire, algo, name, topo, gsize,
-                                       channels=channels)
+                                       channels=channels, compressed=True)
     if tl.active:
         tl.start_activity(name, "DEQUANTIZE")
     with jax.named_scope("DEQUANTIZE"):
@@ -457,10 +457,12 @@ def _traced_allreduce(tctx, x, group, average, name, comp=None, key=None,
     """``x``: one array, or the tuple of a plain-sum fusion bucket's
     leaves in their own shapes (ops/fusion.py ``fused_apply``): a sum
     adds elementwise whatever the shape, so the tuple goes through the
-    same masks and divides leaf by leaf and ONE ``lax.psum`` (this JAX
-    binds a ``psum`` a leaf, adjacent; XLA's combiner merges them). A
-    wire that cuts or scales one flat buffer has no such form and
-    refuses a tuple."""
+    same masks and divides leaf by leaf and ONE plain sum
+    (ops/strategy.py ``_plain_sum``: a ``lax.psum`` of the tuple — this
+    JAX binds one a leaf, adjacent; XLA's combiner merges them — but for
+    the large leaves of a whole-axis group, each a ring of
+    collective-permutes). A wire that cuts or scales one flat buffer has
+    no such form and refuses a tuple."""
     leaves = jax.tree.leaves(x)
     dtype, size = leaves[0].dtype, sum(v.size for v in leaves)
     applies = comp is not None and comp.applies_to(dtype)
@@ -735,7 +737,7 @@ def allreduce(x, group: int = 0, average: bool = True, name: str | None = None,
     span back onto its member tensor rows. A packed bucket arrives as one
     flat buffer; a plain-sum bucket as the TUPLE of its leaves in their
     own shapes (traced-only, same dtype, no compression / phased algo /
-    channels): one ``lax.psum`` over the tuple, a tuple returned.
+    channels): one plain sum over the tuple, a tuple returned.
 
     ``compression``: a wire format name (``"bf16"``/``"int8"``) or
     :class:`~horovod_tpu.ops.compression.Compressor` — the collective then

@@ -13,7 +13,7 @@ ICI bandwidth.
 The plan is computed host-side at trace time (shapes are static under jit).
 The flat buffer itself is built only for a bucket whose wire needs one
 (:attr:`Bucket.packed`), inside the compiled program; a plain-sum bucket is
-one collective over its leaves where they lie.
+reduced over its leaves where they lie.
 """
 
 from __future__ import annotations
@@ -93,7 +93,10 @@ class Bucket:
         ``rs_ag`` / ``hierarchical`` / ``channels > 1`` cut one buffer
         into shards. A plain sum (``flat``, the leaves' dtype on the
         wire, one channel) adds elementwise whatever the shape, so it is
-        reduced in the leaves' own shapes (:func:`fused_apply`)."""
+        reduced in the leaves' own shapes (:func:`fused_apply`): one
+        ``lax.psum`` of the small leaves, a ring of collective-permutes a
+        large one, one ring after another (ops/strategy.py
+        ``_plain_sum``)."""
         return (self.algo != "flat" or self.wire_dtype is not None
                 or self.channels != 1)
 
@@ -360,6 +363,28 @@ def _partition(leaves: Sequence[jax.Array],
     return buckets
 
 
+def trace_order(buckets: Sequence[Bucket], leaves: Sequence[jax.Array],
+                group_size: int | None) -> list[Bucket]:
+    """The order :func:`fused_apply` traces ``buckets`` in. The plan's —
+    but the plain-sum buckets with a leaf that goes round the ring
+    (ops/strategy.py ``ring_eligible``) come after the others, the one
+    with the last leaf first: a backward pass brings the gradients into
+    being from the last leaf to the first (ops/exchange.py, priority
+    ordering), and the rings are chained in the order of tracing
+    (``one_ring_at_a_time``). What a program computes does not depend on
+    the order it is traced in; with no such bucket the order, and so the
+    text, is the plan's."""
+    from horovod_tpu.ops import strategy as _strategy
+
+    def after(b):  # a stable sort: the others keep the plan's order
+        ring = not b.packed and group_size and any(
+            _strategy.ring_eligible(leaves[i], group_size)
+            for i in b.indices)
+        return (1, -b.indices[-1]) if ring else (0, 0)
+
+    return sorted(buckets, key=after)
+
+
 def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
                 labels: Sequence[str] | None = None, compression=None,
                 algo=None, schedule=None, group_size: int | None = None,
@@ -376,9 +401,18 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
     ``collective(leaves) -> leaves``: still one call and one row of the
     schedule, but no buffer is built — on a TPU a tiled gradient is not
     a flat vector, and each ``reshape(-1)`` is a pass over the leaf.
-    (``lax.psum`` of a tuple is one all-reduce a leaf in the lowered
-    text, adjacent and in the plan's order; XLA's combiner merges them
-    into variadic all-reduces without a copy.) The plan
+    What the tuple becomes is the lowering's to say (ops/strategy.py
+    ``_plain_sum``): its small leaves one ``lax.psum`` (one all-reduce a
+    leaf in the lowered text, adjacent and in the plan's order; XLA's
+    combiner merges them into variadic all-reduces without a copy, and
+    runs each alone on the core's timeline: nothing hides it), each large
+    leaf of a whole-axis group a ring reduce-scatter and all-gather of
+    collective-permutes, which the compiler issues asynchronously, the
+    backward's fusions between a start and its done. XLA's scheduler
+    does not spread those rings over the backward by itself: it packs
+    them all behind its end. So the buckets are traced in
+    :func:`trace_order` and their rings chained in that order, one on the
+    links at a time (ops/strategy.py ``one_ring_at_a_time``). The plan
     (``threshold_bytes`` or ``schedule``) decides the buckets either way.
 
     ``labels``: one display name per leaf (gradient pytree paths). When
@@ -406,6 +440,7 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
     single-threshold enumeration-order plan.
     """
     from horovod_tpu.core import timeline as _timeline
+    from horovod_tpu.ops import strategy as _strategy
 
     leaves = list(leaves)
     if labels is not None and len(labels) != len(leaves):
@@ -460,24 +495,28 @@ def fused_apply(leaves: Sequence[jax.Array], collective, threshold_bytes: int,
                   sum(b.bytes_on_wire for b in buckets))
     tl.count_plan("exchange.unpacked_bytes",
                   sum(b.bytes_on_wire for b in buckets if not b.packed))
-    for bucket in buckets:
-        parts = [leaves[i] for i in bucket.indices]
-        if not bucket.packed:
-            for i, r in zip(bucket.indices, run(tuple(parts), bucket)):
-                out[i] = r
-            continue
-        if len(parts) == 1:
-            [i], [leaf] = bucket.indices, parts
-            out[i] = run(leaf.reshape(-1), bucket).reshape(leaf.shape)
-            continue
-        with jax.named_scope("MEMCPY_IN_FUSION_BUFFER"):
-            flat = jnp.concatenate([p.reshape(-1) for p in parts], axis=0)
-        reduced = run(flat, bucket)
-        offset = 0
-        with jax.named_scope("MEMCPY_OUT_FUSION_BUFFER"):
-            for i, p in zip(bucket.indices, parts):
-                out[i] = reduced[offset: offset + p.size].reshape(p.shape)
-                offset += p.size
+    # One ring on the links at a time, in this order (ops/strategy.py).
+    with _strategy.one_ring_at_a_time():
+        for bucket in trace_order(
+                buckets, leaves,
+                group_size if schedule is None else schedule.world_size):
+            parts = [leaves[i] for i in bucket.indices]
+            if not bucket.packed:
+                for i, r in zip(bucket.indices, run(tuple(parts), bucket)):
+                    out[i] = r
+                continue
+            if len(parts) == 1:
+                [i], [leaf] = bucket.indices, parts
+                out[i] = run(leaf.reshape(-1), bucket).reshape(leaf.shape)
+                continue
+            with jax.named_scope("MEMCPY_IN_FUSION_BUFFER"):
+                flat = jnp.concatenate([p.reshape(-1) for p in parts], axis=0)
+            reduced = run(flat, bucket)
+            offset = 0
+            with jax.named_scope("MEMCPY_OUT_FUSION_BUFFER"):
+                for i, p in zip(bucket.indices, parts):
+                    out[i] = reduced[offset: offset + p.size].reshape(p.shape)
+                    offset += p.size
     return out
 
 

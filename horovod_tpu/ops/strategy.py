@@ -11,10 +11,20 @@ where addition order matters (bit-exact on integer-valued data — the
 tests/test_strategy.py contract):
 
 ``flat``
-    Today's ``lax.psum``: one XLA AllReduce. Best for small buckets (one α)
-    and the only lowering for subset groups (whose masked-psum scheme,
+    The plain sum. ``lax.psum``, one XLA AllReduce, for small leaves (one
+    α) and the only lowering for subset groups (whose masked-psum scheme,
     ops/collectives.py ``_traced_groups_arg``, has no uniform partition for
-    the phased variants to ride).
+    the phased variants to ride). A LARGE leaf summed over the whole axis
+    of 2 to 8 ranks in its own dtype goes round a ring of
+    ``lax.ppermute`` instead (:func:`_ring_allreduce`): this TPU compiler
+    runs every all-reduce, reduce-scatter and all-gather synchronously,
+    alone on the core's timeline, and issues a collective-permute
+    asynchronously, with the backward's fusions between its start and its
+    done — where it finds any: left to itself it packs every ring behind
+    the backward's end, so the rings of one exchange are chained, one on
+    the links at a time, the last leaf's first
+    (:func:`one_ring_at_a_time`). Which leaf is decided by what the
+    lowering sees of it (:func:`ring_eligible`), never by an option.
 
 ``rs_ag``
     ``lax.psum_scatter`` + ``lax.all_gather`` (tiled) — the two halves of a
@@ -70,6 +80,8 @@ wrapped in a ``CH<c>`` named scope (inside it, the usual phase scopes).
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import jax.numpy as jnp
 from jax import lax
@@ -219,17 +231,23 @@ def _channel_sizes(total: int, channels: int) -> list[int]:
 
 def lower_allreduce(x, algo: str, name: str,
                     topo: "_topology.Topology | None", gsize: int,
-                    channels: int = 1):
+                    channels: int = 1, compressed: bool = False):
     """Emit ``algo``'s wire ops for a full-axis-group sum of ``x``.
     ``gsize`` is the group size (rs_ag needs nothing else — it may run
     with ``topo=None``); hierarchical needs the discovered topology.
     ``channels``: concurrent channel instances (module docstring);
-    1 = the exact classic lowering."""
+    1 = the exact classic lowering. ``flat`` with one channel is the
+    plain sum (:func:`_plain_sum`): ``lax.psum`` of ``x`` — one array or a
+    bucket's tuple of leaves — but for the large leaves of a whole-axis
+    group, which go round a ring of collective-permutes, one ring after
+    another where the caller chains them (:func:`one_ring_at_a_time`);
+    ``compressed`` says ``x`` is a quantized wire and not the leaves
+    themselves, and keeps the ``psum``."""
     if gsize <= 1:
         return lax.psum(x, AXIS_NAME) if algo == "flat" else x
     if algo == "flat":
         if channels <= 1:
-            return lax.psum(x, AXIS_NAME)
+            return _plain_sum(x, gsize, compressed)
         return _flat_channels(x, name, channels)
     if algo == "rs_ag":
         return _rs_ag(x, gsize, name, channels)
@@ -237,6 +255,185 @@ def lower_allreduce(x, algo: str, name: str,
         assert topo is not None, "hierarchical needs a discovered topology"
         return _hierarchical(x, topo, name, channels)
     raise HorovodError(f"unknown allreduce algorithm {algo!r}")
+
+
+# ---------------------------------------------------------------------------
+# The plain sum: ``flat``, one channel, the leaves' own dtype on the wire.
+# ---------------------------------------------------------------------------
+
+# The least slab (a leaf's bytes over the group's size) that goes round the
+# ring. A round costs the two chips' handshake whatever it carries, and
+# short rounds find no cover in the chain (:func:`one_ring_at_a_time`): on
+# four v5e chips, with starcoder2_3b's 37.7 MB leaves (slabs of 9.4 MB) in
+# the chain, the permutes waited 11.3 ms a step (3.6 on those leaves' own
+# rounds, 7.0 on the 151 MB leaves' behind them) and the all-reduces took
+# 0.6; with them left as all-reduces, 1.9 and 8.3, and half the
+# instructions (PERF.md section 6, PR 30).
+RING_MIN_SLAB_BYTES = 16 << 20
+RING_MAX_RANKS = 8  # 2(n-1) rounds a leaf: past one host the rounds are too many
+
+
+def ring_eligible(v, n: int) -> bool:
+    """Whether the plain sum of leaf ``v`` over a group of ``n`` ranks is
+    lowered as :func:`_ring_allreduce`: the group is the whole axis, the
+    leading dimension cuts into two halves of ``n`` slabs each, a slab of
+    a matrix keeps the tiled layout's sublanes whole (8 rows of 32 bits),
+    and a slab is worth its rounds (:data:`RING_MIN_SLAB_BYTES`). Asked
+    by the lowering (:func:`_plain_sum`) and by the order the exchange
+    traces its buckets in (ops/fusion.py ``trace_order``)."""
+    if not 2 <= n <= RING_MAX_RANKS or v.ndim < 2:
+        return False
+    try:
+        if n != lax.axis_size(AXIS_NAME):
+            return False
+    except NameError:  # no axis bound: not inside a compiled step
+        return False
+    slabs, ragged = divmod(v.shape[0], 2 * n)
+    if ragged or (v.ndim == 2 and slabs % (8 * max(1, 4 // v.dtype.itemsize))):
+        return False
+    return v.size // n * v.dtype.itemsize >= RING_MIN_SLAB_BYTES
+
+
+# The open chain (:func:`one_ring_at_a_time`): ``None`` outside one, else
+# the last slabs the newest ring received, which the next ring waits for.
+_chain: list | None = None
+
+
+@contextlib.contextmanager
+def one_ring_at_a_time():
+    """Inside, every :func:`_ring_allreduce` starts only when the one
+    traced before it has received its last slabs: ONE ring on the links at
+    a time, in the order of tracing — which the caller makes the order the
+    gradients come to exist in (ops/fusion.py ``trace_order``).
+
+    Why: XLA's latency-hiding scheduler lays a program out from its end
+    and gives every collective-permute enough compute to cover what ONE
+    permute takes alone. Independent rings are each other's cover in its
+    eyes, so it packs all of them behind the backward's last
+    fusion, where they share two links and wait for one another (PERF.md
+    section 6, PR 30). A chain it cannot pack: each ring's cover has to be
+    found further up the backward, and one ring at a time is the load its
+    estimate is right for. The dependency is an ``optimization_barrier``
+    on the next leaf and the last ring's slabs: nothing moves for it."""
+    global _chain
+    outer, _chain = _chain, []
+    try:
+        yield
+    finally:
+        _chain = outer
+
+
+def _ring_order(devices) -> list[int]:
+    """The ranks in the order of a cycle whose neighbours are one ICI hop
+    apart where the devices say where they lie — a 2 x k slice: up one
+    column, down the other; rank order would cross a 2 x 2 slice's
+    diagonal twice — else rank order."""
+    coords = [getattr(d, "coords", None) for d in devices]
+    if any(c is None for c in coords):
+        return list(range(len(devices)))
+    a, b = (0, 1) if len({c[0] for c in coords}) <= 2 else (1, 0)
+    return sorted(range(len(devices)), key=lambda r: (
+        coords[r][a], -coords[r][b] if coords[r][a] % 2 else coords[r][b]))
+
+
+def _plain_sum(x, n: int, compressed: bool):
+    """``lax.psum`` of one array or of a bucket's tuple of leaves, but for
+    the leaves :func:`ring_eligible` picks when ``x`` is the leaves
+    themselves (not a quantized wire): those go through
+    :func:`_ring_allreduce`, the last first, each after the ring before
+    it where a chain is open (:func:`one_ring_at_a_time`). The rest stay
+    ONE ``psum`` of a tuple, as before; with no leaf picked the text is
+    today's."""
+    import jax
+
+    from horovod_tpu.core import context as _ctx
+    from horovod_tpu.core import state as _state
+    from horovod_tpu.core import timeline as _tl
+
+    leaves, treedef = jax.tree.flatten(x)
+    ring = [not compressed and ring_eligible(v, n) for v in leaves]
+    _tl.session().count_plan(
+        "exchange.async_bytes",
+        sum(v.size * v.dtype.itemsize for v, r in zip(leaves, ring) if r))
+    if not any(ring):
+        return lax.psum(x, AXIS_NAME)
+    order = _ring_order(
+        _state.get_group(_ctx.current().group_index).devices)
+    rest = iter(lax.psum(tuple(v for v, r in zip(leaves, ring) if not r),
+                         AXIS_NAME))
+    out = [None if r else next(rest) for r in ring]
+    for i in reversed([i for i, r in enumerate(ring) if r]):
+        v = leaves[i]
+        if _chain:  # not before the ring before has its last slabs
+            v, _ = lax.optimization_barrier((v, tuple(_chain)))
+        out[i], last = _ring_allreduce(v, order)
+        if _chain is not None:
+            _chain[:] = last
+    return jax.tree.unflatten(treedef, out)
+
+
+def _ring_allreduce(x, order: list[int]):
+    """The sum of ``x`` over the whole axis as a ring reduce-scatter then a
+    ring all-gather made of ``lax.ppermute``: the one collective this TPU
+    compiler issues asynchronously (``collective-permute-start`` /
+    ``-done``, the backward's fusions between them), where an
+    ``all-reduce``, a ``reduce-scatter`` and an ``all-gather`` hold the
+    core for their whole length.
+
+    ``x`` is cut along axis 0 into two halves of ``n`` slabs; one half
+    goes round the ring ``order`` one way, the other the other way (a
+    chip has two neighbours on it; one stream alone moves 41 GB/s, the two
+    68). Each slab is summed on one chip after another in one fixed order
+    of ranks and then handed round as it is, so every rank receives the
+    same bits — never "rotate and accumulate", which lets replicas drift.
+    The order of a leaf's float sum differs from XLA's all-reduce.
+
+    The slab is an index on an axis of its own (``x`` viewed as
+    ``[2, n, rows, ...]``): a dynamic offset inside the tiled dimension
+    reads as unaligned to the compiler and the sums run at a quarter of
+    the memory's rate. The gathered slabs land in ``x``'s own buffer
+    (in-place ``dynamic_update_slice``); the barrier orders every read of
+    ``x`` before the first of them, or XLA copies the leaf.
+
+    Returns the sum and the last slab each half received, for the next
+    ring of a chain to wait on (:func:`one_ring_at_a_time`)."""
+    import jax.numpy as jnp
+
+    n = len(order)
+    rank = lax.axis_index(AXIS_NAME)
+    ring_pos = [0] * n
+    for p, r in enumerate(order):
+        ring_pos[r] = p
+    pos = rank if order == sorted(order) else jnp.asarray(
+        ring_pos, jnp.int32)[rank]
+    rows = x.shape[0] // (2 * n)
+    xr = x.reshape((2, n, rows) + x.shape[1:])
+    zero = jnp.int32(0)
+
+    def at(lane, slab):  # indices of one slab of one half
+        return (zero + lane, slab % n) + (zero,) * x.ndim
+
+    steps = (1, -1)  # lane 0 goes up the ring, lane 1 down
+    perms = [[(order[p], order[(p + step) % n]) for p in range(n)]
+             for step in steps]
+    sums = []
+    for lane, step in enumerate(steps):
+        carry = None
+        for k in range(n):
+            mine = lax.dynamic_slice(
+                xr, at(lane, pos - step * k), (1, 1) + xr.shape[2:])
+            carry = mine if carry is None else lax.ppermute(
+                carry, AXIS_NAME, perms[lane]) + mine
+        sums.append(carry)
+    out, last = xr, []
+    for lane, carry in enumerate(lax.optimization_barrier(sums)):
+        for k in range(n):  # the slab summed here, then its n - 1 peers'
+            if k:
+                carry = lax.ppermute(carry, AXIS_NAME, perms[lane])
+            out = lax.dynamic_update_slice(
+                out, carry, at(lane, pos - steps[lane] * (n - 1 + k)))
+        last.append(carry)
+    return lax.optimization_barrier(out.reshape(x.shape)), last
 
 
 def _flat_channels(x, name: str, channels: int):

@@ -375,14 +375,16 @@ def allreduce_gradients(grads, group: int = 0, average: bool = True,
                     dense, reduce_bucket, fusion_threshold,
                     labels=dense_labels, compression=comp,
                     algo=bucket_algo, schedule=plan)
-            # One recorded entry per bucket in issue order (the
-            # fused_apply loop): slice each bucket's local dequantized
+            # One recorded entry per bucket in the order fused_apply
+            # traced them: slice each bucket's local dequantized
             # contribution back onto its leaves (a compressed bucket is
             # always packed, so the offsets are the flat buffer's). None
             # = the leaf's contribution was exact — residual telescopes
             # to zero.
             dense_resid = [None] * len(dense)
-            for bucket, local in zip(plan.buckets, locals_):
+            traced = _fusion.trace_order(plan.buckets, dense,
+                                         plan.world_size)
+            for bucket, local in zip(traced, locals_):
                 offset = 0
                 for di in bucket.indices:
                     n = dense[di].size

@@ -52,7 +52,9 @@ def _compile_dp_step(devices, n, compiler_options=None, fusion_threshold=0):
     own fusion bucket (threshold 0 = bucket per tensor, mpi_ops.cc:1492;
     ``None`` = the default plan: one 32 MB bucket of the four), reduced
     via hvd.allreduce_gradients under the optimizer's exchange scope,
-    then SGD-updated."""
+    then SGD-updated. A 2048 x 2048 bfloat16 gradient (8 MiB) is far
+    under the slab that goes round the ring of collective-permutes
+    (ops/strategy.py ``RING_MIN_SLAB_BYTES``): these stay all-reduces."""
     import os
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -148,6 +150,41 @@ class TestGradientOverlapSchedule:
                 moved.append(m.group(1))
         assert not moved, moved
         assert any(op == "all-reduce" for _, op in _schedule(txt))
+
+    @pytest.mark.parametrize("slab_mib,ring", [(1, True), (16, False)])
+    def test_large_leaves_go_round_a_ring_the_backward_runs_beside(
+            self, monkeypatch, slab_mib, ring):
+        """The plain sum of a leaf whose slab is over
+        ``RING_MIN_SLAB_BYTES`` (here 2048 x 2048 bfloat16 on four ranks,
+        2 MiB, with the constant set to 1 MiB so that the step stays
+        small) compiles for v5e:2x2 to ``collective-permute-start`` /
+        ``-done`` pairs, the one collective this compiler issues
+        asynchronously, and no ``all-reduce``: two halves x (3 + 3)
+        rounds a leaf, with compute scheduled between a start and its
+        done, and never more than one ring's two permutes in flight (the
+        chain, ``one_ring_at_a_time``). Under the constant as it stands
+        the same leaves keep today's schedule: all-reduces (synchronous:
+        no ``-start``) and no permute."""
+        from horovod_tpu.ops import strategy
+
+        monkeypatch.setattr(strategy, "RING_MIN_SLAB_BYTES", slab_mib << 20)
+        sched = _schedule(_compile_dp_step(_topo(4, "v5e:2x2"), 4))
+        ops = [op for _, op in sched]
+        starts = ops.count("collective-permute-start")
+        assert starts == ops.count("collective-permute-done")
+        if not ring:
+            assert starts == 0 and "all-reduce-start" not in ops
+            assert 1 <= ops.count("all-reduce") <= 4
+            return
+        assert starts == 4 * 2 * 6 and "all-reduce" not in ops
+        in_flight, most, hidden = 0, 0, 0
+        for op in ops:
+            in_flight += ((op == "collective-permute-start")
+                          - (op == "collective-permute-done"))
+            most = max(most, in_flight)
+            hidden += op in _COMPUTE and in_flight > 0
+        assert hidden >= 1, "no compute between any start and its done"
+        assert most == 2, most
 
     # Known pre-existing failure (tracked since r10, triaged r12): under
     # this container's XLA the combiner-pinned compile

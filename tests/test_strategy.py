@@ -392,6 +392,260 @@ class TestHLOStructure:
         assert len(ag) == 1 and intra in ag[0]
 
 
+def _world_of(n):
+    hvd.shutdown()
+    hvd.init(devices=jax.devices()[:n])
+
+
+def _ring_text(fn, shape, dtype=jnp.float32):
+    """Lowered (pre-optimization) text of ``hvd.spmd(fn)`` on one
+    rank-stacked leaf: shapes only, nothing runs."""
+    return hvd.spmd(fn).lower(
+        jax.ShapeDtypeStruct((hvd.size(),) + shape, dtype)).as_text()
+
+
+class TestRingSum:
+    """The plain sum of a large leaf over the whole axis: a ring
+    reduce-scatter then all-gather of ``lax.ppermute`` (ops/strategy.py
+    ``_ring_allreduce``), chosen by what the lowering sees of the leaf.
+    The cases cut their slabs around 1 MiB, not the module's 16, so
+    that lowering them stays cheap; the last case reads the constant."""
+
+    @pytest.fixture(autouse=True)
+    def _slabs_from_1_mib(self, monkeypatch):
+        monkeypatch.setattr(strategy, "RING_MIN_SLAB_BYTES", 1 << 20)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("average", [False, True])
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    def test_equals_psum_and_every_rank_holds_the_same_bits(
+            self, monkeypatch, n, average, dtype):
+        _world_of(n)
+        shape = (64 * n, 3, 32)  # a slab of 32 rows x 3 x 32
+        x = np.asarray(jax.random.normal(
+            jax.random.key(n), (n,) + shape, jnp.float32).astype(dtype))
+        fn = lambda v: hvd.allreduce(v, average=average)
+        monkeypatch.setattr(strategy, "RING_MIN_SLAB_BYTES", 1 << 10)
+        assert "collective_permute" in _ring_text(fn, shape, x.dtype)
+        got = np.asarray(hvd.spmd(fn)(x))
+        monkeypatch.setattr(strategy, "RING_MIN_SLAB_BYTES", 1 << 40)
+        assert "collective_permute" not in _ring_text(fn, shape, x.dtype)
+        ref = np.asarray(hvd.spmd(fn)(x))
+        hvd.shutdown()
+        assert got.dtype == ref.dtype == x.dtype
+        for r in range(1, n):  # replicas never drift: the same bits
+            np.testing.assert_array_equal(got[r], got[0])
+        # the two differ by the order of one n-term sum in the dtype
+        room = (n * float(jnp.finfo(x.dtype).eps)
+                * np.abs(x.astype(np.float64)).sum(0)
+                / (n if average else 1))
+        assert (np.abs(got[0].astype(np.float64)
+                       - ref[0].astype(np.float64)) <= room).all()
+
+    # 8 ranks: slabs of 1 MiB, but for "small"
+    BIG, SMALL = (4096, 512), (256, 512)
+
+    @pytest.mark.parametrize("case", [
+        "small", "indivisible", "ragged_sublanes", "vector", "subgroup",
+        "family", "compressed", "rs_ag", "channels", "one_rank"])
+    def test_ineligible_inputs_lower_to_todays_text(self, monkeypatch, case):
+        """Everything the ring is not for keeps ``lax.psum``'s text to the
+        letter: compared with the lowering under a rule that picks no
+        leaf."""
+        hvd.shutdown()
+        if case == "one_rank":
+            hvd.init(devices=jax.devices()[:1])
+        elif case == "family":
+            hvd.init([[0, 1, 2, 3], [4, 5, 6, 7]])
+        elif case == "subgroup":
+            hvd.init([[0, 1, 2, 3]])
+        else:
+            hvd.init()
+        shape = {"small": self.SMALL, "indivisible": (4100, 512),
+                 "ragged_sublanes": (4096 + 16, 512),
+                 "vector": (4096 * 512,)}.get(case, self.BIG)
+        kwargs = {"subgroup": dict(group=1), "family": dict(group=(1, 2)),
+                  "compressed": dict(compression="bf16"),
+                  "rs_ag": dict(algo="rs_ag"),
+                  "channels": dict(channels=2)}.get(case, {})
+        fn = lambda v: hvd.allreduce(v, average=False, name="leaf", **kwargs)
+        text = _ring_text(fn, shape)
+        monkeypatch.setattr(strategy, "ring_eligible", lambda v, n: False)
+        today = _ring_text(fn, shape)
+        hvd.shutdown()
+        assert text == today and "collective_permute" not in text
+
+    def test_an_eligible_leaf_has_no_all_reduce(self, world):
+        fn = lambda v: hvd.allreduce(v, average=False, name="leaf")
+        text = _ring_text(fn, self.BIG)
+        assert "all_reduce" not in text
+        # two halves x (7 rounds of reduce-scatter + 7 of all-gather)
+        assert text.count("collective_permute") == 2 * 2 * 7
+
+    def test_a_bucket_keeps_one_psum_for_its_small_leaves(self, world):
+        """A plain-sum bucket arrives as the tuple of its leaves: the
+        large one goes round the ring, the others stay one ``psum``."""
+        def fn(g):
+            return hvd.allreduce_gradients(g, fusion_threshold=1 << 30)
+
+        n = hvd.size()
+        g = {"a": jax.ShapeDtypeStruct((n, 8), jnp.float32),
+             "big": jax.ShapeDtypeStruct((n,) + self.BIG, jnp.float32),
+             "c": jax.ShapeDtypeStruct((n, 3, 5), jnp.float32)}
+        text = hvd.spmd(fn).lower(g).as_text()
+        assert text.count("collective_permute") == 28
+        assert text.count("all_reduce") == 2  # a psum binds one a leaf
+
+    def test_the_rings_of_one_exchange_are_chained_last_leaf_first(
+            self, world):
+        """``allreduce_gradients`` traces the buckets whose leaves go
+        round the ring after the others and from the last leaf to the
+        first (the order a backward pass makes them in), and each ring
+        waits, through an ``optimization_barrier`` on its leaf and the
+        last slabs of the ring before it, until that one is done: one
+        ring on the links at a time. The small leaf keeps its ``psum``,
+        traced first."""
+        def fn(g):
+            return hvd.allreduce_gradients(g, fusion_threshold=0)
+
+        n = hvd.size()
+        g = {"a": jax.ShapeDtypeStruct((n, 4096, 512), jnp.float32),
+             "b": jax.ShapeDtypeStruct((n, 8), jnp.float32),
+             "c": jax.ShapeDtypeStruct((n, 8192, 256), jnp.float32),
+             "d": jax.ShapeDtypeStruct((n, 2048, 1024), jnp.float32)}
+        text = hvd.spmd(fn).lower(g).as_text()
+        ops = [ln for ln in text.splitlines()
+               if "all_reduce" in ln or "collective_permute" in ln
+               or "optimization_barrier" in ln]
+        kinds = ["psum" if "all_reduce" in ln
+                 else "barrier" if "optimization_barrier" in ln
+                 else "permute" for ln in ops]
+        assert kinds[0] == "psum" and kinds.count("psum") == 1
+        # a ring's own two barriers (its sums, its result) and, but for
+        # the first ring, the one that ties it to the ring before
+        assert kinds.count("barrier") == 3 * 3 - 1
+        assert kinds.count("permute") == 3 * 28
+        slabs = [ln for ln, kind in zip(ops, kinds) if kind == "permute"]
+        # the first permute of each ring sends a slab of d, then c, then a
+        assert ["1x1x128x1024" in slabs[0], "1x1x512x256" in slabs[28],
+                "1x1x256x512" in slabs[56]] == [True] * 3
+        ties = [ln for ln, kind in zip(ops, kinds) if kind == "barrier"]
+        # c waits for d's last slabs, a for c's
+        assert any("tensor<8192x256xf32>" in ln and "1x1x128x1024" in ln
+                   for ln in ties)
+        assert any("tensor<4096x512xf32>" in ln and "1x1x512x256" in ln
+                   for ln in ties)
+
+    def test_a_chained_exchange_sums_like_psum_on_every_rank(self):
+        _world_of(4)
+        rng = np.random.default_rng(0)
+        g = {k: rng.standard_normal((4,) + shape).astype(np.float32)
+             for k, shape in (("a", (2048, 512)), ("b", (8,)),
+                              ("c", (8192, 128)))}
+        fn = lambda t: hvd.allreduce_gradients(t, fusion_threshold=0)
+        shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                  for k, v in g.items()}
+        assert hvd.spmd(fn).lower(shapes).as_text().count(
+            "collective_permute") == 2 * 12
+        got = hvd.spmd(fn)(g)
+        hvd.shutdown()
+        for k, v in g.items():
+            out = np.asarray(got[k])
+            for r in range(1, 4):
+                np.testing.assert_array_equal(out[r], out[0])
+            room = 4 * 1.2e-7 * np.abs(v).sum(0)  # the order of one sum
+            assert (np.abs(out[0] - v.mean(0, dtype=np.float64))
+                    <= room).all()
+
+    def test_trace_order_is_the_plans_but_for_the_ring_buckets(self, world):
+        """``fusion.trace_order``: ring buckets after the others, the one
+        with the last leaf first; no ring bucket (small leaves, a packed
+        bucket, a group that is not the whole axis, no axis bound at all),
+        the plan's order."""
+        big, small = jnp.zeros((4096, 512)), jnp.zeros((8,))
+        leaves = [big, small, big, small, big]
+        plan = fusion.plan_buckets(leaves, 0)
+        packed = fusion.plan_buckets(leaves, 0, algo="rs_ag")
+        seen = {}
+
+        def fn(x):
+            seen["ring"] = fusion.trace_order(plan, leaves, 8)
+            seen["subgroup"] = fusion.trace_order(plan, leaves, 4)
+            seen["packed"] = fusion.trace_order(packed, leaves, 8)
+            seen["unknown"] = fusion.trace_order(plan, leaves, None)
+            return x
+
+        hvd.spmd(fn).lower(jax.ShapeDtypeStruct((8, 1), jnp.float32))
+        assert [b.indices for b in seen["ring"]] == [
+            (1,), (3,), (4,), (2,), (0,)]
+        assert seen["subgroup"] == seen["unknown"] == list(plan)
+        assert seen["packed"] == list(packed)
+        assert fusion.trace_order(plan, leaves, 8) == list(plan)  # no axis
+
+    def test_error_feedback_follows_the_order_of_tracing(self, world,
+                                                         monkeypatch):
+        """The optimizer pairs each bucket with the local contribution
+        its collective recorded, in the order ``fused_apply`` traced them:
+        an integer leaf that goes round the ring (uncompressed, traced
+        last) ahead of a compressed float leaf leaves the float leaf's
+        residual the same as with no ring at all."""
+        g = {"a": jnp.arange(4096 * 512, dtype=jnp.int32).reshape(4096, 512),
+             "w": jnp.linspace(-1, 1, 64, dtype=jnp.float32)}
+        e = {"a": jnp.zeros((4096, 512), jnp.int32),
+             "w": jnp.zeros((64,), jnp.float32)}
+
+        def step(g, e):
+            return hvd.allreduce_gradients(g, compression="int8",
+                                           average=False, error_residual=e)
+
+        args = hvd.replicate(g), hvd.replicate(e)
+        assert "collective_permute" in hvd.spmd(step).lower(*args).as_text()
+        out, resid = hvd.spmd(step)(*args)
+        monkeypatch.setattr(strategy, "RING_MIN_SLAB_BYTES", 1 << 40)
+        assert "collective_permute" not in hvd.spmd(step).lower(
+            *args).as_text()
+        ref, ref_resid = hvd.spmd(step)(*args)
+        assert np.abs(np.asarray(resid["w"])).max() > 0
+        for got, want in ((out, ref), (resid, ref_resid)):
+            for k in g:
+                np.testing.assert_array_equal(np.asarray(got[k]),
+                                              np.asarray(want[k]))
+
+    def test_the_slab_size_is_16_mib(self, world, monkeypatch):
+        """The constant as it stands (PERF.md section 6, PR 30): on 8
+        ranks a 128 MiB leaf goes round the ring, one a row of slabs
+        shorter does not."""
+        monkeypatch.undo()
+        assert strategy.RING_MIN_SLAB_BYTES == 16 << 20
+        fn = lambda v: hvd.allreduce(v, average=False, name="leaf")
+        assert "all_reduce" not in _ring_text(fn, (8192, 4096))
+        assert "collective_permute" not in _ring_text(fn, (8192 - 128, 4096))
+
+    @pytest.mark.parametrize("coords,order", [
+        ([(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)], [0, 2, 3, 1]),
+        ([(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0),
+          (0, 2, 0), (1, 2, 0), (0, 3, 0), (1, 3, 0)],
+         [0, 2, 4, 6, 7, 5, 3, 1]),
+        ([(0, 0, 0), (1, 0, 0), (2, 0, 0), (3, 0, 0),
+          (0, 1, 0), (1, 1, 0), (2, 1, 0), (3, 1, 0)],
+         [0, 1, 2, 3, 7, 6, 5, 4]),
+        ([None] * 4, [0, 1, 2, 3])])
+    def test_the_ring_follows_the_slices_cycle(self, coords, order):
+        """Neighbours on the ring are one ICI hop apart on a 2 x k slice
+        (rank order crosses a 2 x 2 slice's diagonal twice); devices that
+        do not say where they lie keep rank order."""
+        import types
+
+        devices = [types.SimpleNamespace(**({} if c is None
+                                            else {"coords": c}))
+                   for c in coords]
+        assert strategy._ring_order(devices) == order
+        if coords[0] is not None:
+            hops = [sum(abs(a - b) for a, b in zip(coords[p], coords[q]))
+                    for p, q in zip(order, order[1:] + order[:1])]
+            assert hops == [1] * len(order)
+
+
 class TestRefusals:
     def test_subset_group_explicit_phased_raises(self, grouped_world):
         x = _int_grid(8, 8)

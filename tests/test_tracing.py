@@ -173,11 +173,43 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
     assert counters == {
         "exchange.wire_bytes": int(nbytes * share),
         "exchange.unpacked_bytes": nbytes if compression is None else 0,
+        # no leaf of this tiny model is worth a ring of collective-permutes
+        # (ops/strategy.py RING_MIN_SLAB_BYTES): all stay all-reduces
+        "exchange.async_bytes": 0,
         # the model's own account (models/transformer.py): a plain stack
         # applies each of its CFG.num_layers blocks once, recomputes none
         # and has one head
         "model.block_applications": 2, "model.recomputed_blocks": 0,
         "model.kept_attention_outputs": 0, "model.head_applications": 1}
+
+
+def test_exchange_async_bytes_are_the_leaves_that_go_round_the_ring(
+        monkeypatch):
+    """``exchange.async_bytes``, counted where the lowering decides
+    (ops/strategy.py ``_plain_sum``): of a bucket's leaves, those whose
+    plain sum is a ring of collective-permutes — here, with slabs from
+    1 MiB, the 2 MiB-slab matrix, not the bias beside it, nor anything
+    of a program on one rank."""
+    from horovod_tpu.ops import strategy
+
+    monkeypatch.setattr(strategy, "RING_MIN_SLAB_BYTES", 1 << 20)
+    for n, ring in ((4, 8 << 20), (1, None)):
+        hvd.shutdown()
+        hvd.init(devices=jax.devices()[:n])
+        grads = {"bias": np.ones((n, 512), np.float32),
+                 "kernel": np.ones((n, 4096, 512), np.float32)}
+
+        @hvd.spmd
+        def exchange(g):
+            return hvd.allreduce_gradients(g, average=False)
+
+        out = exchange(grads)
+        [counters] = [p["counters"]
+                      for p in timeline.record()["programs"].values()]
+        hvd.shutdown()
+        np.testing.assert_array_equal(np.asarray(out["kernel"]), float(n))
+        assert counters.get("exchange.async_bytes") == ring
+        assert counters["exchange.wire_bytes"] == (8 << 20) + 2048
 
 
 @pytest.mark.parametrize("impl", ["flash", "xla"])
