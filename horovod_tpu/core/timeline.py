@@ -199,6 +199,17 @@ class Timeline:
         if counters is not None:
             counters[name] = counters.get(name, 0) + n
 
+    def count_measured(self, name: str, value) -> None:
+        """Set counter ``name`` of the program with most dispatches (the
+        step) to a number its caller MEASURED from the step's own outputs
+        (a step, a rank) — what a plan cannot know before the data is
+        seen, such as how many (token, choice) pairs the held experts
+        took. Dropped where no program was dispatched."""
+        if self.programs:
+            step = max(self.programs.values(),
+                       key=lambda p: p["dispatches"])
+            step["counters"][name] = value
+
     def add_program(self, tag: str, owner) -> str:
         """Open the record of one compiled program; ``owner.hlo_text()``
         gives its optimized text or None (held weakly until a dispatch of

@@ -31,6 +31,33 @@ normed state feeding the next; with ``exit_gate`` every pass but the last
 also gives an exit probability and :func:`make_loss_fn` trains all R
 passes (``exit_loss``).
 
+Three more kinds live in the same slots (PR 31; a DeepSeek-V3-style model
+such as GLM-4.7-Flash uses all three, ``benchmark/configs``):
+
+* ``mla=MLAConfig(...)`` makes :class:`Attention` LATENT (MLA): low-rank
+  query and key/value paths with RMSNorms of their own, a rotary part
+  beside a no-position part in every query and key head, ONE rotary key
+  shared by all heads, value heads of their own width, ``heads x v_dim``
+  free of ``embed_dim``. Training and the plain forward only.
+* ``moe=MoEConfig(...)`` makes the feed-forward of every layer after the
+  first ``dense_layers`` (which keep ``ffn`` at ``mlp_dim``) the
+  ``'moe'`` entry of :data:`FFN`: a sigmoid-routed top-k over ``total``
+  experts of which this chip holds ``held`` from ``first``
+  (``ops/moe.py``: no token dropped, no exchange), beside a shared expert.
+* ``mtp=MTPConfig(...)`` adds the multi-token-prediction module: one more
+  block of its own leaves over ``[norm(h_i) | norm(Embed(t_{i+1}))]``,
+  through the SAME embedding and the SAME head, trained by
+  :func:`make_loss_fn` on ``t_{i+2}`` with weight ``mtp.weight``.
+
+``norm_eps`` is every RMSNorm's epsilon. Combinations that RAISE, each
+where it is first seen: ``mla`` with ``decode=True`` (caching the latent
+is serving's work and waits for a serving metric: ROADMAP M5), with
+``attention='ring'`` / ``'ulysses'`` (one shared rotary key and unequal
+widths are not plumbed through the exchanges) and with ``window``;
+``moe`` and ``mtp`` with ``recurrent_steps > 1`` (a pass's expert counts
+and a looped MTP have no defined meaning here); ``mtp`` under sequence
+parallelism (its shifted tokens cross the shards).
+
 **Recomputation is a rule, not a switch**: a stack run more than once
 (``recurrent_steps > 1``) keeps, of each block application, its INPUT and
 WHAT ITS ATTENTION KERNEL WROTE for the backward (``nn.remat`` under a
@@ -65,7 +92,36 @@ import optax
 
 from horovod_tpu.core import state as _state
 from horovod_tpu.core import timeline as _timeline
+from horovod_tpu.ops import moe as _moe
 from horovod_tpu.ops.flash_attention import LSE_RESIDUAL, OUT_RESIDUAL
+
+
+class MLAConfig(NamedTuple):
+    """Latent attention's widths (a head's query and key are
+    ``nope_dim + rope_dim`` wide, its value ``v_dim``)."""
+    q_rank: int               # the query's latent width
+    kv_rank: int              # the shared key/value latent's width
+    nope_dim: int             # a head's part that carries no position
+    rope_dim: int             # a head's rotary part; ONE key for all heads
+    v_dim: int                # a head's value width
+
+
+class MoEConfig(NamedTuple):
+    """The expert layer and this chip's share of it (``ops/moe.py``)."""
+    total: int                # experts the router scores: its width
+    held: int                 # of them, held here ...
+    first: int = 0            # ... from this one on
+    top_k: int = 1            # experts a token
+    expert_dim: int = 2048    # a gated expert's width
+    shared_experts: int = 0   # shared expert's width, in experts
+    scale: float = 1.0        # routed_scaling_factor on the gates
+    dense_layers: int = 0     # leading layers that keep ``ffn``
+
+
+class MTPConfig(NamedTuple):
+    """The multi-token-prediction module (one: t_{i+2} from position i)."""
+    weight: float = 0.3       # its loss's weight beside the main one
+    pad_id: int = 0           # stands for t_{i+1} where there is none
 
 
 class TransformerConfig(NamedTuple):
@@ -89,6 +145,13 @@ class TransformerConfig(NamedTuple):
     rope_theta: float = 10000.0   # rotary base
     recurrent_steps: int = 1      # passes of the one weight-shared stack
     exit_gate: bool = False       # per-pass exit probability (looped only)
+    norm_eps: float = 1e-6        # every RMSNorm's epsilon
+    mla: MLAConfig | None = None  # latent attention; raises with decode,
+                                  # 'ring' / 'ulysses' and window
+    moe: MoEConfig | None = None  # expert layers after moe.dense_layers;
+                                  # raises with recurrent_steps > 1
+    mtp: MTPConfig | None = None  # multi-token prediction; raises with
+                                  # recurrent_steps > 1 and under SP
 
 
 def _rotary(x, positions, theta=10000.0):
@@ -114,12 +177,80 @@ def _rotary(x, positions, theta=10000.0):
         [xf1 * cos - xf2 * sin, xf1 * sin + xf2 * cos], axis=-1).astype(x.dtype)
 
 
+def _mla_attention(cfg, x, positions, segs):
+    """Latent attention (MLA), built in the calling :class:`Attention`'s
+    scope: ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` (heads of nope | rope);
+    ``[c_kv | k_r] = x W_kva``, ``[k_nope | v] = norm(c_kv) W_kvb``; the
+    rotary embedding on the rope parts only, ``k_r`` one key for all
+    heads; causal softmax over ``q k^T / sqrt(nope + rope)``; the heads'
+    values through ``out``."""
+    import horovod_tpu as hvd
+
+    m, h = cfg.mla, cfg.num_heads
+    if cfg.decode:
+        raise ValueError(
+            "decode=True does not run latent attention (mla=): the cache "
+            "would hold the kv_rank + rope_dim latent a token, not K and V "
+            "— the latent cache, this decode branch and the page layout "
+            "are serving's work and wait for a serving metric (ROADMAP "
+            "M5).")
+    if cfg.attention != "local":
+        raise ValueError(
+            f"latent attention (mla=) runs attention='local' only, not "
+            f"{cfg.attention!r}: one rotary key shared by all heads and "
+            f"unequal key / value widths are not plumbed through the "
+            f"sequence-parallel exchanges.")
+    if cfg.window is not None:
+        raise ValueError("latent attention (mla=) is full causal: window "
+                         "is not supported with it.")
+    if m.rope_dim % 2 != 0:
+        raise ValueError(f"mla.rope_dim ({m.rope_dim}) must be even for "
+                         f"rotary embeddings.")
+    dense = lambda width, name: nn.Dense(width, dtype=cfg.dtype,
+                                         use_bias=False, name=name)
+    heads = lambda width, name: nn.DenseGeneral(
+        (h, width), axis=-1, dtype=cfg.dtype, use_bias=False, name=name)
+    norm = lambda name: nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                                   name=name)
+    with jax.named_scope("mla"):
+        q = heads(m.nope_dim + m.rope_dim, "q_b")(
+            norm("q_norm")(dense(m.q_rank, "q_a")(x)))
+        latent = dense(m.kv_rank + m.rope_dim, "kv_a")(x)
+        kv = heads(m.nope_dim + m.v_dim, "kv_b")(
+            norm("kv_norm")(latent[..., :m.kv_rank]))
+        k_rope = _rotary(latent[..., None, m.kv_rank:], positions,
+                         cfg.rope_theta)                   # (B, T, 1, rope)
+        q = jnp.concatenate(
+            [q[..., :m.nope_dim],
+             _rotary(q[..., m.nope_dim:], positions, cfg.rope_theta)], -1)
+        k = jnp.concatenate(
+            [kv[..., :m.nope_dim],
+             jnp.broadcast_to(k_rope, k_rope.shape[:2] + (h, m.rope_dim))],
+            -1)
+        v = kv[..., m.nope_dim:]
+        # The kernels take one head width: the narrower of (q, k) and v is
+        # padded with zeros, which add nothing to a score or an output.
+        qk_dim = m.nope_dim + m.rope_dim
+        width = max(qk_dim, m.v_dim)
+        pad = lambda a: a if a.shape[-1] == width else jnp.pad(
+            a, ((0, 0),) * 3 + ((0, width - a.shape[-1]),))
+        out = hvd.local_attention(pad(q), pad(k), pad(v), causal=True,
+                                  sm_scale=qk_dim ** -0.5, **segs)
+        return nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1),
+                               dtype=cfg.dtype, use_bias=False,
+                               name="out")(out[..., :m.v_dim])
+
+
 class Attention(nn.Module):
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, kv_view=None):
         cfg = self.config
+        if cfg.mla is not None:
+            segs = {} if segment_ids is None else dict(
+                q_segment_ids=segment_ids, kv_segment_ids=segment_ids)
+            return _mla_attention(cfg, x, positions, segs)
         if cfg.embed_dim % cfg.num_heads != 0:
             raise ValueError(
                 f"embed_dim ({cfg.embed_dim}) must be divisible by num_heads "
@@ -319,28 +450,87 @@ def _gelu_ffn(cfg, y):
     return nn.Dense(cfg.embed_dim, dtype=cfg.dtype, use_bias=False)(y)
 
 
-def _swiglu_ffn(cfg, y):
+def _swiglu_ffn(cfg, y, width=None, prefix=""):
     dense = lambda width, name: nn.Dense(width, dtype=cfg.dtype,
-                                         use_bias=False, name=name)
-    y = nn.silu(dense(cfg.mlp_dim, "gate")(y)) * dense(cfg.mlp_dim, "up")(y)
+                                         use_bias=False, name=prefix + name)
+    width = width or cfg.mlp_dim
+    y = nn.silu(dense(width, "gate")(y)) * dense(width, "up")(y)
     return dense(cfg.embed_dim, "down")(y)
 
 
-# The block's feed-forward slot: ``cfg.ffn`` -> (cfg, y) -> y, building
-# its matrices in the calling Block's scope.
-FFN = {"gelu": _gelu_ffn, "swiglu": _swiglu_ffn}
+EXPERT_PAIRS = "expert_pairs"  # the collection an expert layer sows into
+EXPERT_CHOICES = "expert_choices"  # ... and its tokens' top-k, if asked
+
+
+class MoE(nn.Module):
+    """The expert layer as this chip holds it (``ops/moe.py``): the
+    router over all ``moe.total`` experts, this chip's ``moe.held`` gated
+    experts for the tokens routed to them, and the shared expert. The
+    score-correction bias is a constant of zeros, not a parameter (its
+    update rule is no gradient's: ROADMAP M3). Sows, into
+    :data:`EXPERT_PAIRS`, how many (token, choice) pairs each held expert
+    took and, into :data:`EXPERT_CHOICES`, every token's top-k (nothing
+    where the collection is not asked for)."""
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, y):
+        cfg, m = self.config, self.config.moe
+        _moe.check_share(m.total, m.held, m.first, m.top_k)
+        e, f = cfg.embed_dim, m.expert_dim
+        experts = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                               batch_axis=(0,))
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (e, m.total))
+        wg = self.param("wg", experts, (m.held, e, f))
+        wu = self.param("wu", experts, (m.held, e, f))
+        wd = self.param("wd", experts, (m.held, f, e))
+        tokens = y.reshape(-1, e)
+        with jax.named_scope("router"):
+            idx, gates = _moe.route(tokens, router, jnp.zeros((m.total,)),
+                                    m.top_k, m.scale)
+        out, pairs = _moe.routed_experts(tokens, idx, gates, wg, wu, wd,
+                                         first=m.first)
+        self.sow(EXPERT_PAIRS, "pairs", pairs)
+        self.sow(EXPERT_CHOICES, "idx", idx)
+        out = out.reshape(y.shape)
+        if m.shared_experts:
+            with jax.named_scope("shared_expert"):
+                out = out + _swiglu_ffn(cfg, y, m.shared_experts * f,
+                                        prefix="shared_")
+        return out
+
+
+def _moe_ffn(cfg, y):
+    return MoE(cfg, name="moe")(y)
+
+
+# The block's feed-forward slot: a kind -> (cfg, y) -> y, building its
+# matrices in the calling Block's scope. ``cfg.ffn`` names the dense
+# kind; ``'moe'`` is what the layers after ``cfg.moe.dense_layers`` take.
+FFN = {"gelu": _gelu_ffn, "swiglu": _swiglu_ffn, "moe": _moe_ffn}
+
+
+def ffn_of_layer(cfg: TransformerConfig, i: int) -> str:
+    """The feed-forward kind of layer ``i``: ``cfg.ffn`` but ``'moe'``
+    after an expert configuration's leading dense layers."""
+    if cfg.moe is not None and i >= cfg.moe.dense_layers:
+        return "moe"
+    return cfg.ffn
 
 
 class Block(nn.Module):
     config: TransformerConfig
+    ffn: str | None = None        # this layer's kind; None: ``config.ffn``
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, kv_view=None):
         cfg = self.config
-        if cfg.ffn not in FFN:
-            raise ValueError(f"Unknown ffn {cfg.ffn!r}; one of "
+        ffn = self.ffn or cfg.ffn
+        if ffn not in FFN:
+            raise ValueError(f"Unknown ffn {ffn!r}; one of "
                              f"{sorted(FFN)}.")
-        norm = lambda: nn.RMSNorm(dtype=cfg.dtype)
+        norm = lambda: nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
         # Sandwich norms: a branch's output is normed before it joins the
         # residual stream, so four RMSNorms a block for two.
         post = (lambda y: norm()(y)) if cfg.sandwich_norm else (lambda y: y)
@@ -348,8 +538,8 @@ class Block(nn.Module):
         x = x + post(Attention(cfg, name="attn")(y, positions, segment_ids,
                                                  kv_view=kv_view))
         y = norm()(x)
-        with jax.named_scope("mlp"):
-            y = FFN[cfg.ffn](cfg, y)
+        with jax.named_scope("moe" if ffn == "moe" else "mlp"):
+            y = FFN[ffn](cfg, y)
         return x + post(y)
 
 
@@ -427,12 +617,19 @@ class Transformer(nn.Module):
                 "decode=True does not run a looped model (recurrent_steps="
                 f"{cfg.recurrent_steps}): the KV cache holds one entry a "
                 "layer, a looped model needs one a pass and layer.")
-        x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
-                     dtype=cfg.dtype,
-                     embedding_init=nn.initializers.normal(0.02))(tokens)
+        if looped and (cfg.moe is not None or cfg.mtp is not None):
+            raise ValueError(
+                "expert layers (moe=) and the multi-token-prediction "
+                "module (mtp=) do not run in a looped model "
+                f"(recurrent_steps={cfg.recurrent_steps}): a pass's expert "
+                "counts and a looped MTP have no defined meaning here.")
+        embed = nn.Embed(cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype,
+                         embedding_init=nn.initializers.normal(0.02))
+        x = embed(tokens)
         # Into the record of the hvd.spmd program being traced, a step, a
         # rank (core/timeline.py count_plan; dropped where none is).
-        applied = cfg.num_layers * cfg.recurrent_steps
+        applied = cfg.num_layers * cfg.recurrent_steps \
+            + (cfg.mtp is not None)  # the MTP module is one more block
         tl = _timeline.session()
         tl.count_plan("model.block_applications", applied)
         tl.count_plan("model.recomputed_blocks", applied if looped else 0)
@@ -442,14 +639,25 @@ class Transformer(nn.Module):
             "model.kept_attention_outputs",
             applied if looped and _attends_through_the_kernel(cfg, t_local)
             else 0)
+        if cfg.moe is not None:
+            # The plan of the expert layers (the MTP module's is one more);
+            # what the experts TOOK is the step's output, not the plan's.
+            tl.count_plan("model.moe_layers",
+                          max(cfg.num_layers - cfg.moe.dense_layers, 0)
+                          + (cfg.mtp is not None))
+            tl.count_plan("model.experts_held", cfg.moe.held)
+            tl.count_plan("model.experts_total", cfg.moe.total)
+            tl.count_plan("model.moe_pair_capacity",
+                          tokens.size * cfg.moe.top_k)
 
         def stack(block, x):
             """One pass: the blocks and the final norm."""
             for i in range(cfg.num_layers):
-                x = block(cfg, name=f"block_{i}")(
+                x = block(cfg, ffn=ffn_of_layer(cfg, i), name=f"block_{i}")(
                     x, positions, segment_ids,
                     None if kv_views is None else kv_views[i])
-            return nn.RMSNorm(dtype=cfg.dtype, name="RMSNorm_0")(x)
+            return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                              name="RMSNorm_0")(x)
 
         if looped:
             # The R passes are ONE lax.scan whose body is the stack, its
@@ -474,6 +682,24 @@ class Transformer(nn.Module):
         else:
             x = stack(Block, x)
             hidden = (x,)
+        if cfg.mtp is not None and (return_passes or self.is_initializing()):
+            # Multi-token prediction: position i's state beside the
+            # embedding of t_{i+1} (a pad id where there is none), through
+            # one more block of its own; the loss reads it for t_{i+2}
+            # through the same head. Its state rides behind the main one.
+            with jax.named_scope("mtp"):
+                norm = lambda name: nn.RMSNorm(
+                    epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+                after = jnp.concatenate(
+                    [tokens[:, 1:],
+                     jnp.full_like(tokens[:, :1], cfg.mtp.pad_id)], axis=1)
+                z = jnp.concatenate([norm("mtp_norm_h")(x),
+                                     norm("mtp_norm_e")(embed(after))], -1)
+                z = nn.Dense(cfg.embed_dim, dtype=cfg.dtype, use_bias=False,
+                             name="mtp_proj")(z)
+                z = Block(cfg, ffn=ffn_of_layer(cfg, cfg.num_layers),
+                          name="mtp_block")(z, positions, segment_ids)
+                hidden += (norm("mtp_norm")(z),)
         exits = ()
         if cfg.exit_gate:
             # Every pass but the last has an exit probability; the last
@@ -537,8 +763,19 @@ def exit_loss(pass_losses, gate_logits, beta: float):
     return jnp.mean(jnp.sum(p * (pass_losses + beta * logp), axis=0))
 
 
+def expert_pairs(config: TransformerConfig, sown) -> jax.Array:
+    """(expert layers, held) int32 from what the expert layers sowed into
+    :data:`EXPERT_PAIRS`, in layer order, the MTP module's layer last."""
+    blocks = [f"block_{i}" for i in range(config.num_layers)
+              if ffn_of_layer(config, i) == "moe"]
+    if config.mtp is not None:
+        blocks.append("mtp_block")
+    return jnp.stack([sown[b]["moe"]["pairs"][0] for b in blocks])
+
+
 def make_loss_fn(config: TransformerConfig, sp_rank=None,
-                 fused_head: bool = False, exit_beta: float = 0.1):
+                 fused_head: bool = False, exit_beta: float = 0.1,
+                 with_expert_pairs: bool = False):
     """Next-token cross-entropy over the local shard.
 
     ``fused_head=True`` routes the lm_head matmul through
@@ -556,6 +793,15 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
     the last pass's loss counts (its gradient still reaches every shared
     leaf through all the passes).
 
+    With ``mtp=`` the loss is the main one over the T - 1 positions with a
+    target plus ``mtp.weight`` times the MTP module's over the T - 2
+    positions whose ``t_{i+2}`` exists, both through the one head.
+
+    ``with_expert_pairs`` (``moe=`` only) makes the loss function return
+    ``(loss, pairs)`` for ``jax.value_and_grad(..., has_aux=True)``:
+    ``pairs`` (expert layers, held) int32, how many (token, choice) pairs
+    each held expert took in each expert layer (:func:`expert_pairs`).
+
     ``sp_rank``: traced group rank when sequence-parallel (compute it inside
     the hvd.spmd step: ``hvd.rank(cfg.sp_group)``); None for plain DP.
     Under SP the boundary token between shards is predicted from the previous
@@ -572,6 +818,13 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
     model = Transformer(config)
     zigzag = (config.sp_layout == "zigzag"
               and config.attention == "ring")
+    if with_expert_pairs and config.moe is None:
+        raise ValueError("with_expert_pairs needs expert layers (moe=).")
+    if config.mtp is not None and (sp_rank is not None or zigzag):
+        raise ValueError(
+            "the multi-token-prediction module (mtp=) is not supported "
+            "under sequence parallelism: its shifted tokens cross the "
+            "shards.")
 
     def _loss(params, batch):
         tokens = batch  # (B, T_local) int32
@@ -606,9 +859,43 @@ def make_loss_fn(config: TransformerConfig, sp_rank=None,
         offset = 0 if sp_rank is None else sp_rank() * t_local
         # Every pass's state (or logits) and the exit gates' logits: one
         # and none for a plain model, R and R - 1 for a gated looped one.
+        if with_expert_pairs:
+            (passes, exits), sown = model.apply(
+                {"params": params}, tokens, shard_offset=offset,
+                return_hidden=fused_head, return_passes=True,
+                mutable=[EXPERT_PAIRS])
+            pairs = expert_pairs(config, sown[EXPERT_PAIRS])
+            return _passes_loss(params, tokens, passes, exits), pairs
         passes, exits = model.apply(
             {"params": params}, tokens, shard_offset=offset,
             return_hidden=fused_head, return_passes=True)
+        return _passes_loss(params, tokens, passes, exits)
+
+    def _mtp_loss(params, tokens, passes):
+        """Main loss + ``mtp.weight`` x the MTP module's, from the two
+        states ``passes`` holds (logits where the head is not fused)."""
+        main, ahead = passes
+        with jax.named_scope("head"):
+            if fused_head:
+                from horovod_tpu.ops.losses import (
+                    default_chunk, fused_cross_entropy_per_position)
+
+                w = params["lm_head"]["kernel"].astype(config.dtype)
+                ce = lambda h, shift: fused_cross_entropy_per_position(
+                    h[:, :-shift].reshape(-1, h.shape[-1]), w,
+                    tokens[:, shift:].reshape(-1),
+                    chunk=default_chunk(w.shape[1]))
+            else:
+                ce = lambda logits, shift: \
+                    optax.softmax_cross_entropy_with_integer_labels(
+                        logits[:, :-shift], tokens[:, shift:])
+            return ce(main, 1).mean() + config.mtp.weight * ce(ahead,
+                                                               2).mean()
+
+    def _passes_loss(params, tokens, passes, exits):
+        if config.mtp is not None:
+            _timeline.session().count_plan("model.head_applications", 2)
+            return _mtp_loss(params, tokens, passes)
         if not config.exit_gate:
             passes = passes[-1:]  # only the last pass is trained
         _timeline.session().count_plan("model.head_applications",

@@ -765,14 +765,18 @@ _BWD_BLOCK_KV_MEM = 4096   # kv rows resident in VMEM per grid step
 
 
 def _default_blocks(d, t, block_q, block_k, bwd_q, bwd_k, bwd_mem):
-    """Resolve unset block sizes, scaled down for large head dims.
+    """Resolve unset block sizes. Explicit arguments always win.
 
-    The defaults are tuned on v5e at D=128; the kernels' VMEM footprint
-    has a d-independent part (the (bq, bk) fp32 score intermediates) and a
-    d-proportional part (operand blocks, the backward's K/V residency and
-    dk/dv accumulators). For D > 128 the d-proportional terms double and
-    the tuned residency no longer fits comfortably — halve the forward
-    blocks and the backward K/V residency. Explicit arguments always win.
+    The D <= 128 defaults are tuned on v5e at D=128. For D > 128 the
+    d-proportional part of the kernels' VMEM footprint (operand blocks,
+    the backward's K/V residency and dk/dv accumulators) doubles; those
+    defaults are a sweep's on a v5e chip at D=256, 20 heads, T=8192, full
+    causal (tools/moe_sweep.py; PERF.md, PR 31): forward 1024x1024 (6.32
+    ms a call for 7.88 at the 512x512 this function gave before it was
+    timed, 6.60 at 1024x512, 6.91 at 1024x2048); backward 256 q lanes x
+    1024 k sublanes with 4096 K/V rows resident (13.46 ms for 14.37 at
+    512 x 512 x 2048; 512 q lanes with 4096 resident rows is the worst
+    measured, 19.5).
     """
     big = d > 128
     # fwd 2048x2048: device-timeline-measured best at D=128, T=16k on v5e
@@ -781,20 +785,19 @@ def _default_blocks(d, t, block_q, block_k, bwd_q, bwd_k, bwd_mem):
     # The 2048 tiles need the raised _FWD_SEMANTICS vmem budget (two
     # 16 MB fp32 score tiles), which v2/v3's 16 MB physical VMEM cannot
     # hold — those keep 1024 everywhere.
-    if big:
-        fwd_default = 512
-    elif t >= 16384 and not _small_vmem_chip():
+    if t >= 16384 and not big and not _small_vmem_chip():
         fwd_default = 2048
     else:
         fwd_default = 1024
-    bwd_k_default = 512 if big else (
+    bwd_k_default = (
         2 * _BWD_BLOCK_KC
-        if t >= 32768 and not _small_vmem_chip() else _BWD_BLOCK_KC)
+        if t >= 32768 and not big and not _small_vmem_chip()
+        else _BWD_BLOCK_KC)
     return ((block_q or fwd_default),
             (block_k or fwd_default),
-            (bwd_q or _BWD_BLOCK_Q),
+            (bwd_q or (256 if big else _BWD_BLOCK_Q)),
             (bwd_k or bwd_k_default),
-            (bwd_mem or (2048 if big else _BWD_BLOCK_KV_MEM)))
+            (bwd_mem or _BWD_BLOCK_KV_MEM))
 
 
 @functools.partial(jax.custom_vjp,
@@ -897,8 +900,8 @@ def flash_attention(q, k, v, causal: bool = True,
     budget admits the 2048 tiles; v2/v3 chips stay at 1024). Backward
     blocks default to ``block_q_bwd=512``
     q lanes × ``block_k_bwd=1024`` k sublanes per score tile, with
-    ``block_kv_mem=4096`` K/V rows VMEM-resident per grid step. For head
-    dims above 128 the unset defaults scale themselves down (see
+    ``block_kv_mem=4096`` K/V rows VMEM-resident per grid step. Head dims
+    above 128 have unset defaults of their own, swept at D=256 (see
     ``_default_blocks``); explicit arguments always win.
 
     **Residual names.** The backward kernel reads q, k, v, the output and

@@ -1,0 +1,445 @@
+"""The latent-attention / expert-layer LM (MLA, a sigmoid-routed expert
+layer holding a share of the experts beside a shared expert, leading dense
+layers, the multi-token-prediction module) against its plain reference
+``benchmark/reference/train_moe_lm.py``, and what ties the chip's share
+to the model: the shares of a layer add up to the uncut layer, no token is
+dropped whatever the imbalance, and a configuration without the new
+fields still computes the old model."""
+
+import importlib.util
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import horovod_tpu as hvd
+from horovod_tpu.core import timeline
+from horovod_tpu.models import transformer
+from horovod_tpu.ops import moe as moe_ops
+from horovod_tpu.ops import optim
+from horovod_tpu.parallel import sequence
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)  # benchmark/ is a namespace package of ROOT
+
+
+def _load(*parts):
+    path = os.path.join(ROOT, "benchmark", *parts)
+    spec = importlib.util.spec_from_file_location(
+        "moe_" + parts[-1][:-3], path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+REFERENCE = _load("reference", "train_moe_lm.py")
+RUNNER = _load("runners", "train_moe_lm.py")
+SEEDED = _load("seeded.py")
+
+# A small GLM-4.7-Flash: every mechanism of the configuration (a dense
+# layer, two expert layers, the MTP module), unequal nope / rope / value
+# widths, share 1 of 4 of the experts; float32 compute.
+SMALL = {"hidden_size": 32, "num_attention_heads": 4,
+         "num_key_value_heads": 4, "q_lora_rank": 16, "kv_lora_rank": 12,
+         "qk_nope_head_dim": 6, "qk_rope_head_dim": 4, "v_head_dim": 12,
+         "intermediate_size": 48, "moe_intermediate_size": 24,
+         "n_routed_experts": 4, "n_shared_experts": 1,
+         "routed_scaling_factor": 1.8, "num_experts_per_tok": 3,
+         "first_k_dense_replace": 1, "num_hidden_layers": 3,
+         "num_nextn_predict_layers": 1, "vocab_size": 96,
+         "rope_theta": 1000000, "rms_norm_eps": 1e-5, "hidden_act": "silu",
+         "attention_bias": False, "norm_topk_prob": True,
+         "rope_scaling": None, "n_group": 1, "topk_group": 1,
+         "max_position_embeddings": 64, "initializer_range": 0.1,
+         "embedding_std": 1.0,
+         "mtp_loss_weight": 0.3,
+         "published": {"n_routed_experts": 16},
+         "expert_share": {"chips": 4, "index": 1}}
+OPT = {"name": "adamw", "learning_rate": 3e-3, "b1": 0.9, "b2": 0.95,
+       "eps": 1e-8, "weight_decay": 0.1, "moment_dtype": "bfloat16"}
+SEED, T = 2147483659, 32
+CFG = RUNNER.model_config(SMALL)._replace(dtype=jnp.float32)
+
+
+def _tokens(batch, rows=1):
+    return SEEDED.lm_tokens(SEED, 0, batch, rows, T, SMALL["vocab_size"])
+
+
+def _reference(variant="reference", cfg=SMALL):
+    with jax.default_matmul_precision("highest"):
+        return REFERENCE.Reference(cfg, OPT, SEED, SEEDED, variant)
+
+
+def _gradients(ref, toks):
+    """(loss, {leaf: gradient}) of one row by the reference."""
+    acc = {}
+
+    def add(name, g, whole):
+        acc[name] = g if name not in acc else acc[name] + g
+
+    with jax.default_matmul_precision("highest"):
+        return ref._gradients(jnp.asarray(toks, jnp.int32), add), acc
+
+
+def _exact(q, k, v, causal=True, sm_scale=None, **_):
+    t = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (
+        sm_scale or q.shape[-1] ** -0.5)
+    pos = jnp.arange(t)
+    p = jax.nn.softmax(
+        jnp.where(pos[None, :] <= pos[:, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.fixture
+def exact_attention(monkeypatch):
+    """``hvd.local_attention`` rounds q and k to bfloat16 whatever the
+    model's dtype; the tests of what stands AROUND the attention give the
+    model a float32 one (as ``tests/test_looped_lm.py`` does). The
+    program's own attention is held to the reference below, and by the
+    cell's rehearsal."""
+    monkeypatch.setattr(hvd, "local_attention", _exact)
+
+
+@pytest.fixture
+def one_device():
+    hvd.shutdown()
+    hvd.init(devices=jax.devices()[:1])
+    yield
+    hvd.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# (a) the program against the plain reference, through hvd.spmd
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "unfused"])
+def test_loss_and_every_gradient_match_the_reference(
+        fused, exact_attention, one_device):
+    ref = _reference()
+    toks = _tokens(0)
+    want, acc = _gradients(ref, toks[0])
+    loss_fn = transformer.make_loss_fn(CFG, fused_head=fused,
+                                       with_expert_pairs=True)
+    step = hvd.spmd(lambda p, toks: jax.value_and_grad(
+        loss_fn, has_aux=True)(p, toks))
+    with jax.default_matmul_precision("highest"):
+        (got, pairs), grads = step(hvd.replicate(RUNNER._to_tree(ref.p)),
+                                   hvd.rank_stack([toks]))
+    np.testing.assert_allclose(np.asarray(got)[0], want, rtol=2e-6)
+    assert set(acc) == set(ref.p)  # a gradient reaches every leaf
+    grads = RUNNER._by_name(jax.tree.map(lambda a: a[0], grads), list(acc))
+    for name, g in acc.items():
+        scale = float(jnp.max(jnp.abs(g)))
+        np.testing.assert_allclose(grads[name], g, rtol=1e-4,
+                                   atol=2e-5 * scale, err_msg=name)
+    # two expert layers and the MTP module's, this share's four experts
+    pairs = np.asarray(pairs)[0]
+    assert pairs.shape == (3, SMALL["n_routed_experts"])
+    assert (pairs.sum(axis=1) <= T * SMALL["num_experts_per_tok"]).all()
+    assert pairs.sum() > 0
+
+
+def test_three_adamw_steps_through_hvd_match_the_reference(
+        exact_attention, one_device):
+    """The benchmark's own path (hvd.init -> DistributedOptimizer ->
+    hvd.spmd) on one device, float32 moments so that only the order of
+    the arithmetic differs; the step's counters; the measured ones."""
+    ref = _reference()
+    start = {n: np.asarray(a) for n, a in ref.p.items()}
+    opt = hvd.DistributedOptimizer(optim.adamw(
+        OPT["learning_rate"], b1=OPT["b1"], b2=OPT["b2"], eps=OPT["eps"],
+        weight_decay=OPT["weight_decay"], moment_dtype=jnp.float32))
+    loss_fn = transformer.make_loss_fn(CFG, fused_head=True,
+                                       with_expert_pairs=True)
+
+    def train_step(p, s, toks):
+        (loss, pairs), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(p, toks)
+        updates, s = opt.update(grads, s, p)
+        return (optax.apply_updates(p, updates), s, hvd.allreduce(loss),
+                pairs)
+
+    step = hvd.spmd(train_step, donate_argnums=(0, 1))
+    params = RUNNER._to_tree(ref.p)
+    ps, ss = hvd.replicate(params), hvd.replicate(opt.init(params))
+    with jax.default_matmul_precision("highest"):
+        for k in range(3):
+            ps, ss, loss, pairs = step(ps, ss, hvd.rank_stack([_tokens(k)]))
+            want, _ = ref.step(list(_tokens(k)))
+            np.testing.assert_allclose(np.asarray(loss)[0], want, rtol=1e-5)
+    now = RUNNER._by_name(jax.tree.map(lambda a: np.asarray(a[0]), ps),
+                          list(start))
+    timeline.session().count_measured("moe.local_pairs",
+                                      float(np.asarray(pairs).sum()))
+    [program] = [p for p in timeline.record()["programs"].values()
+                 if p["dispatches"] == 3]
+    for name, p0 in start.items():
+        moved = np.asarray(ref.p[name]) - p0
+        gap = np.linalg.norm((now[name] - p0) - moved)
+        assert gap <= 2e-3 * np.linalg.norm(moved), name
+    counters = program["counters"]
+    assert counters["model.block_applications"] == 4  # 3 and the MTP's
+    assert counters["model.recomputed_blocks"] == 0
+    assert counters["model.head_applications"] == 2
+    assert counters["model.moe_layers"] == 3  # two layers and the MTP's
+    assert counters["model.experts_held"] == 4
+    assert counters["model.experts_total"] == 16
+    assert counters["model.moe_pair_capacity"] == T * 3
+    assert counters["moe.local_pairs"] == float(np.asarray(pairs).sum())
+
+
+@pytest.mark.parametrize("variant", ["dropped_tokens", "no_mtp",
+                                     "half_batch"])
+def test_a_planted_fault_is_another_model(variant):
+    toks = _tokens(0)[0]
+    want, good = _gradients(_reference(), toks)
+    got, bad = _gradients(_reference(variant), toks)
+    assert abs(float(got) - float(want)) > 1e-4 * float(want)
+    if variant == "no_mtp":  # nothing reaches the MTP module's leaves
+        assert float(jnp.max(jnp.abs(bad["mtp.weh"]))) == 0.0
+        assert float(jnp.max(jnp.abs(good["mtp.weh"]))) > 0.0
+
+
+# ---------------------------------------------------------------------------
+# (b) the share test, (c) no token dropped
+# ---------------------------------------------------------------------------
+
+
+def _layer_inputs(total=16, tokens=64, e=32, f=24, seed=3):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    normal = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
+    lp = {"wr": normal(keys[0], e, total), "eg": 0.2 * normal(keys[1], total, e, f),
+          "eu": 0.2 * normal(keys[2], total, e, f),
+          "ed": 0.2 * normal(keys[3], total, f, e),
+          "sg": 0.2 * normal(keys[4], e, f), "su": 0.2 * normal(keys[5], e, f),
+          "sd": 0.2 * normal(keys[6], f, e)}
+    return lp, normal(keys[7], tokens, e)
+
+
+def _program_layer(lp, x, first, held, shared):
+    """The program's expert layer (``transformer.MoE``) holding experts
+    ``first .. first + held`` of ``lp``'s."""
+    total = lp["wr"].shape[1]
+    cfg = CFG._replace(moe=CFG.moe._replace(
+        total=total, held=held, first=first, shared_experts=int(shared)))
+    part = slice(first, first + held)
+    params = {"router": lp["wr"], "wg": lp["eg"][part],
+              "wu": lp["eu"][part], "wd": lp["ed"][part]}
+    if shared:
+        params.update({f"shared_{n}": {"kernel": lp["s" + n[0]]}
+                       for n in ("gate", "up", "down")})
+    with jax.default_matmul_precision("highest"):
+        out, sown = transformer.MoE(cfg).apply(
+            {"params": params}, x[None], mutable=[transformer.EXPERT_PAIRS])
+    return out[0], sown[transformer.EXPERT_PAIRS]["pairs"][0]
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """The routed parts that the 4 shares of a layer give, plus the shared
+    expert counted once, are the uncut reference layer with all 16
+    experts; every pair of the batch is some share's."""
+    lp, x = _layer_inputs()
+    with jax.default_matmul_precision("highest"):
+        want = REFERENCE.moe(lp, x, SMALL, False, first=0)
+    total, pairs = 0.0, 0
+    for share in range(4):
+        out, took = _program_layer(lp, x, first=4 * share, held=4,
+                                   shared=share == 0)
+        total, pairs = total + out, pairs + int(took.sum())
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
+    assert pairs == x.shape[0] * SMALL["num_experts_per_tok"]
+    # ... and one share alone is that share's part by the reference
+    with jax.default_matmul_precision("highest"):
+        part = REFERENCE.moe({**lp, **{n: lp[n][4:8] for n in
+                                       ("eg", "eu", "ed")}}, x, SMALL,
+                             False, first=4, shared=False)
+    np.testing.assert_allclose(
+        _program_layer(lp, x, 4, 4, False)[0], part, rtol=1e-4, atol=1e-5)
+
+
+def test_the_sliced_heads_logits_side_by_side_are_the_uncut_heads(
+        exact_attention):
+    """A model holding 1/4 of the vocabulary gives, for tokens of its
+    slice, the uncut model's logits of its columns."""
+    shares, v = 4, SMALL["vocab_size"]
+    full = CFG._replace(vocab_size=shares * v)
+    params = transformer.init_params(full, seed=1)
+    toks = jnp.asarray(_tokens(1))  # ids of slice 0
+    want = transformer.Transformer(full).apply({"params": params}, toks)
+    got = []
+    for s in range(shares):
+        mine = dict(params)
+        mine["Embed_0"] = {"embedding": params["Embed_0"]["embedding"][:v]}
+        mine["lm_head"] = {
+            "kernel": params["lm_head"]["kernel"][:, s * v:(s + 1) * v]}
+        got.append(transformer.Transformer(CFG).apply({"params": mine},
+                                                      toks))
+    np.testing.assert_allclose(jnp.concatenate(got, -1), want, rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("favoured", [(4, 5, 6, 7), (5,)],
+                         ids=["all_here", "one_expert"])
+def test_no_token_is_dropped_whatever_the_imbalance(favoured):
+    """A bias that sends every token's choices to the held experts (all
+    three to experts 4-7; then one of the three to expert 5 for every
+    token): the layer still equals the reference, and the experts took
+    every pair."""
+    lp, x = _layer_inputs(tokens=128)
+    bias = jnp.zeros((16,)).at[jnp.asarray(favoured)].set(10.0)
+    first, held, k = 4, 4, SMALL["num_experts_per_tok"]
+    with jax.default_matmul_precision("highest"):
+        want = REFERENCE.moe(
+            {**lp, **{n: lp[n][first:first + held] for n in
+                      ("eg", "eu", "ed")}}, x, SMALL, False, bias=bias,
+            first=first, shared=False)
+        idx, gates = moe_ops.route(x, lp["wr"], bias, k, 1.8)
+        got, pairs = moe_ops.routed_experts(
+            x, idx, gates, lp["eg"][first:first + held],
+            lp["eu"][first:first + held], lp["ed"][first:first + held],
+            first=first)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    if len(favoured) == held:
+        assert int(pairs.sum()) == x.shape[0] * k  # the buffer is full
+    else:
+        assert int(pairs[1]) == x.shape[0]  # every token, eight times the mean
+    # ... and the gradient through the buffer, full or a third full, is
+    # the reference's.
+    mine = {n: lp[n][first:first + held] for n in ("eg", "eu", "ed")}
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda x, w: jnp.sum(REFERENCE.moe(
+            {**lp, **w}, x, SMALL, False, bias=bias, first=first,
+            shared=False) ** 2), argnums=(0, 1))(x, mine)
+        got = jax.grad(lambda x, w: jnp.sum(moe_ops.routed_experts(
+            x, *moe_ops.route(x, lp["wr"], bias, k, 1.8), w["eg"], w["eu"],
+            w["ed"], first=first)[0] ** 2), argnums=(0, 1))(x, mine)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=2e-5 * float(jnp.max(jnp.abs(b))))
+
+
+# ---------------------------------------------------------------------------
+# (d) latent attention against the reference
+# ---------------------------------------------------------------------------
+
+
+def _mla_case(cfg_dict, t, seed=5):
+    """(program output, reference output) of one MLA on seeded weights."""
+    mcfg = RUNNER.model_config(cfg_dict)._replace(dtype=jnp.float32)
+    specs = [(n.split(".")[1], shape, init) for n, shape, init in
+             REFERENCE._block_specs(cfg_dict, "l0", "dense")
+             if n.split(".")[1] in ("wqa", "lnq", "wqb", "wkva", "lnkv",
+                                    "wkvb", "wo")]
+    lp = jax.jit(lambda k: SEEDED.leaves(k, specs))(SEEDED.key(seed))
+    params = {}
+    for name, leaf in lp.items():
+        *parents, last = RUNNER._BLOCK[name][1:]
+        node = params
+        for key in parents:
+            node = node.setdefault(key, {})
+        node[last] = leaf
+    x = jax.random.normal(jax.random.PRNGKey(seed),
+                          (t, cfg_dict["hidden_size"]), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = transformer.Attention(mcfg).apply(
+            {"params": params}, x[None], jnp.arange(t))[0]
+        want = REFERENCE.mla(lp, x, cfg_dict, False)
+    return got, want
+
+
+def test_mla_with_unequal_widths_matches_the_reference_on_the_xla_path():
+    """nope 6, rope 4, value 12: the value heads are wider than the
+    queries' and keys', which are padded. ``local_attention`` rounds q
+    and k to bfloat16: 2e-2 of the largest output."""
+    got, want = _mla_case(SMALL, T)
+    np.testing.assert_allclose(got, want,
+                               atol=2e-2 * float(jnp.max(jnp.abs(want))))
+    wider_qk = dict(SMALL, qk_nope_head_dim=12, v_head_dim=6)
+    got, want = _mla_case(wider_qk, T)
+    np.testing.assert_allclose(got, want,
+                               atol=2e-2 * float(jnp.max(jnp.abs(want))))
+
+
+def test_mla_through_the_flash_kernel_at_256_wide_heads(monkeypatch):
+    """The published head widths (192 + 64 / 256) through the Pallas
+    kernel, interpreted off the TPU, at its D > 128 default blocks."""
+    monkeypatch.setattr(sequence, "local_attention_impl", lambda t: "flash")
+    wide = dict(SMALL, num_attention_heads=2, num_key_value_heads=2,
+                qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256)
+    got, want = _mla_case(wide, 128)
+    np.testing.assert_allclose(got, want,
+                               atol=2e-2 * float(jnp.max(jnp.abs(want))))
+
+
+# ---------------------------------------------------------------------------
+# (e) what raises, (f) the defaults
+# ---------------------------------------------------------------------------
+
+
+def _forward(cfg, **kwargs):
+    toks = jnp.asarray(_tokens(0))
+    params = jax.eval_shape(lambda: transformer.init_params(
+        cfg._replace(decode=False, attention="local", window=None,
+                     recurrent_steps=1, exit_gate=False)))
+    return jax.eval_shape(lambda p: transformer.Transformer(cfg).apply(
+        {"params": p}, toks, **kwargs), params)
+
+
+@pytest.mark.parametrize("change, says", [
+    (dict(decode=True), "ROADMAP M5"),
+    (dict(attention="ring"), "attention='local' only"),
+    (dict(attention="ulysses"), "attention='local' only"),
+    (dict(window=8), "window"),
+    (dict(recurrent_steps=2), "looped model"),
+    (dict(recurrent_steps=2, moe=None), "looped model"),
+], ids=["mla_decode", "mla_ring", "mla_ulysses", "mla_window",
+        "experts_looped", "mtp_looped"])
+def test_combinations_that_raise(change, says):
+    with pytest.raises(ValueError, match=says):
+        _forward(CFG._replace(**change))
+
+
+def test_mtp_under_sequence_parallelism_raises():
+    with pytest.raises(ValueError, match="sequence parallelism"):
+        transformer.make_loss_fn(CFG, sp_rank=lambda: 0)
+    with pytest.raises(ValueError, match="expert layers"):
+        transformer.make_loss_fn(CFG._replace(moe=None),
+                                 with_expert_pairs=True)
+
+
+def test_a_share_that_is_no_share_raises():
+    with pytest.raises(hvd.HorovodError, match="not a share"):
+        _forward(CFG._replace(moe=CFG.moe._replace(first=14)))
+
+
+# What the parent commit's models gave (seed 0's init, these tokens):
+# the new fields' defaults must reproduce them.
+OLD = {"plain": (dict(), 5.083866119384766, 27),
+       "gqa_swiglu": (dict(num_kv_heads=2, ffn="swiglu", sandwich_norm=True,
+                           rope_theta=1e6), 5.0577521324157715, 36),
+       "looped": (dict(ffn="swiglu", recurrent_steps=3, exit_gate=True),
+                  5.060669898986816, 32)}
+
+
+@pytest.mark.parametrize("name", sorted(OLD))
+def test_defaults_give_the_old_models(name):
+    change, loss, leaves = OLD[name]
+    cfg = transformer.TransformerConfig(
+        vocab_size=96, num_layers=3, num_heads=4, embed_dim=32, mlp_dim=48,
+        max_seq_len=64, dtype=jnp.float32)._replace(**change)
+    params = transformer.init_params(cfg)
+    assert len(jax.tree.leaves(params)) == leaves
+    names = {jax.tree_util.keystr(p)
+             for p, _ in jax.tree_util.tree_leaves_with_path(params)}
+    assert not any(w in n for n in names
+                   for w in ("moe", "mtp", "q_a", "kv_a"))
+    got = transformer.make_loss_fn(cfg, fused_head=True)(
+        params, jnp.asarray(_tokens(0, rows=2)))
+    np.testing.assert_allclose(got, loss, rtol=1e-6)

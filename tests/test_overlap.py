@@ -354,3 +354,63 @@ class TestLoopedStepKeepsWhatTheKernelWrote:
             for name in ("hvd_flash_fwd", "hvd_flash_bwd")}
         assert calls == {"hvd_flash_fwd": cfg.num_layers,
                          "hvd_flash_bwd": cfg.num_layers}
+
+
+class TestExpertLayerStepCompilesForTheChip:
+    def test_mla_at_256_wide_heads_and_the_grouped_products(self):
+        """The latent-attention / expert-layer step in a REAL executable
+        (models/transformer.py, ops/moe.py), compiled for one v5e chip
+        above T=2048 at the published head and expert widths: the flash
+        kernels at D = 256 with their swept default blocks (one forward
+        and one backward a block application, the MTP module's included),
+        and the routed experts as Pallas grouped matmuls under the
+        ``experts`` scope, in both buffer sizes the step chooses between —
+        forward, the backward's second forward and the two transposes of
+        three products, twice."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from horovod_tpu.core.state import AXIS_NAME
+        from horovod_tpu.models import transformer
+
+        devices = _topo(4, "v5e:2x2")[:1]
+        hvd.shutdown()
+        hvd.init(devices=devices)
+        cfg = transformer.TransformerConfig(
+            vocab_size=512, num_layers=2, num_heads=2, embed_dim=256,
+            mlp_dim=512, max_seq_len=4096, ffn="swiglu", norm_eps=1e-5,
+            mla=transformer.MLAConfig(q_rank=128, kv_rank=128, nope_dim=192,
+                                      rope_dim=64, v_dim=256),
+            moe=transformer.MoEConfig(total=64, held=8, top_k=4,
+                                      expert_dim=1536, shared_experts=1,
+                                      scale=1.8, dense_layers=1),
+            mtp=transformer.MTPConfig())
+        loss_fn = transformer.make_loss_fn(cfg, fused_head=True,
+                                           with_expert_pairs=True)
+
+        def grad_step(params, tokens):
+            (loss, pairs), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, tokens)
+            return hvd.allreduce_gradients(grads), hvd.allreduce(loss), pairs
+
+        shard = NamedSharding(hvd.get_group(0).mesh, P(AXIS_NAME))
+        stacked = lambda a, dtype: jax.ShapeDtypeStruct(
+            (1,) + a.shape, dtype, sharding=shard)
+        params = jax.tree.map(
+            lambda a: stacked(a, a.dtype),
+            jax.eval_shape(lambda: transformer.init_params(cfg)))
+        tokens = stacked(jnp.zeros((1, 4096)), jnp.int32)
+        with jax.enable_x64(False):
+            txt = hvd.spmd(grad_step).lower(params, tokens).compile(
+                ).as_text()
+        hvd.shutdown()
+        count = lambda name: len(re.findall(
+            rf"= [^\n]* custom-call\([^\n]*{name}", txt))
+        blocks, expert_layers = cfg.num_layers + 1, cfg.num_layers
+        assert count("hvd_flash_fwd") == count("hvd_flash_bwd") == blocks
+        # (three products forward, the same three again in the backward —
+        # the buffer is a ``jax.checkpoint``'s — and their six transposes)
+        grouped = re.findall(
+            r"= [^\n]* custom-call\([^\n]*tpu_custom_call[^\n]*"
+            r'op_name="[^"\n]*/moe/[^"\n]*experts\)*/', txt)
+        assert len(grouped) == expert_layers * (3 + 3 + 6)
+        assert "ragged-dot" not in txt
