@@ -649,6 +649,10 @@ class Transformer(nn.Module):
             tl.count_plan("model.experts_total", cfg.moe.total)
             tl.count_plan("model.moe_pair_capacity",
                           tokens.size * cfg.moe.top_k)
+            # The rows a round of the layer's device-sized passes takes:
+            # a pass visits the routed rows and less than one block more.
+            tl.count_plan("model.moe_row_block",
+                          _moe.row_block(tokens.size * cfg.moe.top_k))
 
         def stack(block, x):
             """One pass: the blocks and the final norm."""

@@ -325,6 +325,182 @@ def test_no_token_is_dropped_whatever_the_imbalance(favoured):
             a, b, rtol=1e-4, atol=2e-5 * float(jnp.max(jnp.abs(b))))
 
 
+def _routed_case(routed, tokens=64, k=3, total=16, first=4, held=4, e=24,
+                 f=8, seed=7):
+    """A layer's inputs with exactly ``routed`` of the ``tokens x k`` pairs
+    held here (experts ``first .. first + held``), every token's choices
+    distinct: ``(x, idx, gates, wg, wu, wd)``, float32."""
+    rng = np.random.default_rng(seed)
+    mine = np.zeros(tokens * k, bool)
+    mine[rng.permutation(tokens * k)[:routed]] = True
+    here = np.arange(first, first + held)
+    away = np.setdiff1d(np.arange(total), here)
+    idx = np.empty((tokens, k), np.int32)
+    for t, row in enumerate(mine.reshape(tokens, k)):
+        idx[t, row] = rng.permutation(here)[:row.sum()]
+        idx[t, ~row] = rng.permutation(away)[:k - row.sum()]
+    normal = lambda *shape: jnp.asarray(
+        rng.standard_normal(shape), jnp.float32)
+    gates = jnp.abs(normal(tokens, k)) + 0.1
+    return (normal(tokens, e), jnp.asarray(idx), gates,
+            0.3 * normal(held, e, f), 0.3 * normal(held, e, f),
+            0.3 * normal(held, f, e))
+
+
+def _dense_part(x, idx, gates, wg, wu, wd, first=4):
+    """The routed part with no buffer: every pair through its own
+    expert's matrices, the pairs held elsewhere weighed with zero."""
+    out = 0.0
+    for j in range(idx.shape[1]):
+        local = idx[:, j] - first
+        mine = (local >= 0) & (local < wg.shape[0])
+        at = jnp.clip(local, 0, wg.shape[0] - 1)
+        h = jax.nn.silu(jnp.einsum("te,tef->tf", x, wg[at])) \
+            * jnp.einsum("te,tef->tf", x, wu[at])
+        out = out + jnp.where(mine, gates[:, j], 0.0)[:, None] \
+            * jnp.einsum("tf,tfe->te", h, wd[at])
+    return out
+
+
+def _part_and_gradients(fn, case):
+    """``fn``'s routed part of ``case`` and the gradient of a weighted
+    sum of it with respect to x, the gates and the three matrices."""
+    x, idx, gates, wg, wu, wd = case
+    probe = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+    with jax.default_matmul_precision("highest"):
+        out = fn(x, idx, gates, wg, wu, wd)
+        grads = jax.grad(
+            lambda x, gates, wg, wu, wd: jnp.sum(
+                probe * fn(x, idx, gates, wg, wu, wd)),
+            argnums=(0, 1, 2, 3, 4))(x, gates, wg, wu, wd)
+    return (out, *grads)
+
+
+def _program_part(x, idx, gates, wg, wu, wd):
+    return moe_ops.routed_experts(x, idx, gates, wg, wu, wd, first=4)[0]
+
+
+@pytest.fixture
+def rounds_of_32(monkeypatch):
+    """``ops/moe.ROW_BLOCK`` = 32 for one test: a 192-row buffer is six
+    rounds. The traces JAX keeps of the layer's functions were made under
+    one block size and know no other: dropped before and after."""
+    monkeypatch.setattr(moe_ops, "ROW_BLOCK", 32)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("routed", [0, 1, 32, 33, 192],
+                         ids=["none", "one_row", "one_block",
+                              "one_block_and_a_row", "full_buffer"])
+def test_the_routed_part_at_every_extent(rounds_of_32, routed):
+    """The passes between the grouped products get their extent from the
+    routed-row count: the part and every gradient are the dense
+    reference's with no row routed here, one, exactly one round's block,
+    one row more, and the whole 192-row worst-case buffer."""
+    case = _routed_case(routed)
+    assert moe_ops.row_block(case[1].size) == 32
+    assert "slice_sizes=(32, 24)" in str(jax.make_jaxpr(_program_part)(*case))
+    pairs = moe_ops.routed_experts(*case, first=4)[1]
+    assert int(pairs.sum()) == routed
+    for got, want in zip(_part_and_gradients(_program_part, case),
+                         _part_and_gradients(_dense_part, case)):
+        np.testing.assert_allclose(
+            got, want, rtol=1e-4,
+            atol=2e-5 * max(float(jnp.max(jnp.abs(want))), 1e-3))
+
+
+def _poisoned(grouped_matmul):
+    """``grouped_matmul`` as a kernel whose grid follows the groups may
+    leave it: NaN in every row past the last group, of the product and of
+    its transpose; what such rows of a cotangent hold is ignored."""
+    def rows(a, sizes):
+        return (jnp.arange(a.shape[0]) < jnp.sum(sizes))[:, None]
+
+    def product(x, w, sizes):
+        @jax.custom_vjp
+        def f(x, w):
+            return jnp.where(rows(x, sizes), grouped_matmul(x, w, sizes),
+                             jnp.nan)
+
+        def fwd(x, w):
+            return f(x, w), (x, w)
+
+        def bwd(res, g):
+            x, w = res
+            back = jax.vjp(lambda x, w: grouped_matmul(x, w, sizes), x, w)[1]
+            dx, dw = back(jnp.where(rows(g, sizes), g, 0))
+            return jnp.where(rows(dx, sizes), dx, jnp.nan), dw
+        f.defvjp(fwd, bwd)
+        return f(x, w)
+    return product
+
+
+@pytest.mark.parametrize("routed", [0, 33, 192],
+                         ids=["none", "a_block_and_a_row", "full_buffer"])
+def test_no_pass_reads_a_row_nobody_was_routed_to(monkeypatch, rounds_of_32,
+                                                 routed):
+    """What replaces the masks: with NaN left in every buffer row past the
+    routed ones, by every grouped product and every transpose of one, and
+    NaN in every row of a buffer before a pass lands its blocks in it (on
+    a TPU nothing clears one), the part and its gradients stay finite and
+    equal."""
+    case = _routed_case(routed)
+    want = _part_and_gradients(_program_part, case)
+    monkeypatch.setattr(moe_ops, "grouped_matmul",
+                        _poisoned(moe_ops.grouped_matmul))
+    monkeypatch.setattr(moe_ops, "_buffer", lambda rows, width, dtype:
+                        jnp.full((rows, width), jnp.nan, dtype))
+    got = _part_and_gradients(_program_part, case)
+    for a, b in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_array_equal(a, b)
+
+
+def _buffer_sized_values(jaxpr, rows, inside=False, found=None):
+    """``[(primitive, shape, inside a device-sized loop or kernel)]`` of
+    every equation of ``jaxpr`` and its sub-jaxprs whose result is a
+    matrix of ``rows`` rows."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        sized = name in ("while", "ragged_dot_general", "pallas_call")
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if len(shape) == 2 and shape[0] == rows:
+                found.append((name, shape, inside))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _buffer_sized_values(sub, rows, inside or sized, found)
+    return found
+
+
+def test_nothing_outside_a_device_sized_pass_makes_a_buffer():
+    """At the cell's N = 8,192 tokens and k = 4: outside the loops whose
+    trip count the routed rows give and the grouped products, the only
+    (N k, ·) matrices of the layer, forward, second forward and
+    backward, are the buffers' initialisations — no gather, mask,
+    activation, sum or cast walks the 32,768 rows."""
+    n, k, e, f = 8192, 4, 24, 8
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((n, e), jnp.float32), ((n, k), jnp.int32), ((n, k), jnp.float32),
+        ((4, e, f), jnp.float32), ((4, e, f), jnp.float32),
+        ((4, f, e), jnp.float32))]
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x, idx, gates, wg, wu, wd: jnp.sum(_program_part(
+            x, idx, gates, wg, wu, wd)), argnums=(0, 2, 3, 4, 5)))(*shapes)
+    made = _buffer_sized_values(jaxpr.jaxpr, n * k)
+    assert any(inside for _, _, inside in made)
+    # (index arithmetic makes (N k, 1)- and (N k, held)-shaped integers)
+    outside = {(name, shape[1]) for name, shape, inside in made
+               if not inside and shape[1] in (e, f, 2 * f)}
+    assert {name for name, _ in outside} <= {
+        "broadcast_in_dim",            # a buffer's one initialisation
+        "while", "ragged_dot_general", "pallas_call",  # the passes' results
+        "custom_vjp_call", "custom_jvp_call", "pjit", "jit", "checkpoint",
+        "remat", "closed_call", "custom_vjp_call_jaxpr"}, outside
+
+
 # ---------------------------------------------------------------------------
 # (d) latent attention against the reference
 # ---------------------------------------------------------------------------

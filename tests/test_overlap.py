@@ -364,9 +364,10 @@ class TestExpertLayerStepCompilesForTheChip:
         kernels at D = 256 with their swept default blocks (one forward
         and one backward a block application, the MTP module's included),
         and the routed experts as Pallas grouped matmuls under the
-        ``experts`` scope, in both buffer sizes the step chooses between —
-        forward, the backward's second forward and the two transposes of
-        three products, twice."""
+        ``experts`` scope — forward, the backward's second forward and
+        the two transposes of the two products (gate and up side by side
+        as one, and down) — with, between them, loops whose trip count
+        the device computes from the routed rows."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from horovod_tpu.core.state import AXIS_NAME
@@ -407,10 +408,23 @@ class TestExpertLayerStepCompilesForTheChip:
             rf"= [^\n]* custom-call\([^\n]*{name}", txt))
         blocks, expert_layers = cfg.num_layers + 1, cfg.num_layers
         assert count("hvd_flash_fwd") == count("hvd_flash_bwd") == blocks
-        # (three products forward, the same three again in the backward —
-        # the buffer is a ``jax.checkpoint``'s — and their six transposes)
+        # (two products forward — gate and up side by side as one, and
+        # down —, the same two again in the backward — the buffer is a
+        # ``jax.checkpoint``'s — and their four transposes)
         grouped = re.findall(
             r"= [^\n]* custom-call\([^\n]*tpu_custom_call[^\n]*"
-            r'op_name="[^"\n]*/moe/[^"\n]*experts\)*/', txt)
-        assert len(grouped) == expert_layers * (3 + 3 + 6)
+            r'op_name="[^"\n]*/moe/[^"\n]*experts\)*/jit\(t?gmm\)/', txt)
+        assert len(grouped) == expert_layers * (2 + 2 + 4)
+        # (the buffers the rounds land their blocks in are Pallas calls
+        # that write nothing: the rows and the activation, forward and
+        # again, and the rows' cotangents)
+        assert count("hvd_moe_buffer") == expert_layers * (2 + 2 + 1)
         assert "ragged-dot" not in txt
+        # ... and the eight passes between them (three forward; the
+        # gather and the activation again, whose way back to the tokens
+        # the backward does not need; three transposes) are ``while``
+        # loops of the layer's own scopes.
+        loops = re.findall(
+            r'= [^\n]* while\([^\n]*op_name="[^"\n]*/moe/[^"\n]*'
+            r'(?:dispatch|experts|combine)/while"', txt)
+        assert len(loops) == expert_layers * (3 + 2 + 3)

@@ -231,6 +231,27 @@ def test_a_looped_steps_record_counts_the_kept_attention_outputs(
         "model.head_applications": 1}
 
 
+def test_an_expert_layer_steps_record_counts_its_row_block():
+    """The plan of the expert layers beside ``model.moe_pair_capacity``:
+    ``model.moe_row_block``, the rows a round of the layer's device-sized
+    passes takes (``ops/moe.py`` ``row_block``): a pass visits the routed
+    rows and less than one such block more, never the whole buffer."""
+    from horovod_tpu.ops import moe
+
+    _world4()
+    cfg = CFG._replace(moe=transformer.MoEConfig(
+        total=8, held=2, top_k=3, expert_dim=16, dense_layers=1))
+    step, ps, ss, toks, _ = _lm_step(cfg=cfg)
+    step(ps, ss, toks)
+    counters = timeline.record()["programs"][TAG]["counters"]
+    hvd.shutdown()
+    capacity = 2 * 16 * 3  # a rank's tokens x top_k
+    assert {k: v for k, v in counters.items() if "moe" in k} == {
+        "model.moe_layers": 1, "model.moe_pair_capacity": capacity,
+        "model.moe_row_block": moe.row_block(capacity)}
+    assert moe.row_block(capacity) == 32 and moe.row_block(32768) == 512
+
+
 def _stablehlo_ops(step, *args):
     """``[(op, result type, location's name)]`` of the lowered module's
     one-line operations (an ``all_reduce`` holds a region: not one)."""
