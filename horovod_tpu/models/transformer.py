@@ -49,6 +49,21 @@ such as GLM-4.7-Flash uses all three, ``benchmark/configs``):
   through the SAME embedding and the SAME head, trained by
   :func:`make_loss_fn` on ``t_{i+2}`` with weight ``mtp.weight``.
 
+**The mixer is a slot too** (PR 33; an LFM2-style hybrid uses it,
+``benchmark/configs/lfm2_24b_a2b.json``): ``layer_types`` names each
+layer's kind in :data:`MIXER` — ``'attention'`` (:class:`Attention`) or
+``'conv'`` (:class:`ShortConv`: a gated depthwise causal convolution of
+``conv_taps`` taps between two projections, ``ops/short_conv.py``) — as
+DATA of the configuration, read by :func:`mixer_of_layer` as
+:func:`ffn_of_layer` reads the expert configuration; ``None`` is attention
+everywhere. ``qk_norm`` RMSNorms every head's query and key over its own
+channels before the rotary embedding, one learned scale for the queries
+and one for the keys (the GQA path; latent attention has norms of its
+own). A ``'conv'`` layer RAISES with ``decode=True`` (its state is the
+last ``conv_taps - 1`` gated inputs, not a KV cache: serving it is
+ROADMAP M6) and with ``attention='ring'`` / ``'ulysses'`` (a shard's first
+positions would need a halo of ``conv_taps - 1`` from the shard before).
+
 ``norm_eps`` is every RMSNorm's epsilon. Combinations that RAISE, each
 where it is first seen: ``mla`` with ``decode=True`` (caching the latent
 is serving's work and waits for a serving metric: ROADMAP M5), with
@@ -93,6 +108,7 @@ import optax
 from horovod_tpu.core import state as _state
 from horovod_tpu.core import timeline as _timeline
 from horovod_tpu.ops import moe as _moe
+from horovod_tpu.ops import short_conv as _short_conv
 from horovod_tpu.ops.flash_attention import LSE_RESIDUAL, OUT_RESIDUAL
 
 
@@ -152,6 +168,12 @@ class TransformerConfig(NamedTuple):
                                   # raises with recurrent_steps > 1
     mtp: MTPConfig | None = None  # multi-token prediction; raises with
                                   # recurrent_steps > 1 and under SP
+    layer_types: tuple | None = None  # each layer's mixer, a kind of MIXER
+                                      # ('attention' | 'conv'); None: all
+                                      # attention. 'conv' raises with decode
+                                      # and 'ring' / 'ulysses'
+    conv_taps: int = 3            # a 'conv' layer's taps (conv_L_cache)
+    qk_norm: bool = False         # RMSNorm on each head's q and k (GQA)
 
 
 def _rotary(x, positions, theta=10000.0):
@@ -250,6 +272,10 @@ class Attention(nn.Module):
         if cfg.mla is not None:
             segs = {} if segment_ids is None else dict(
                 q_segment_ids=segment_ids, kv_segment_ids=segment_ids)
+            if cfg.qk_norm:
+                raise ValueError(
+                    "qk_norm is the GQA path's: latent attention (mla=) "
+                    "norms its query and key/value latents itself.")
             return _mla_attention(cfg, x, positions, segs)
         if cfg.embed_dim % cfg.num_heads != 0:
             raise ValueError(
@@ -271,9 +297,17 @@ class Attention(nn.Module):
             raise ValueError(
                 "kv_view= (paged KV cache) is only meaningful with "
                 "decode=True — the serving engine's one-token step.")
-        q = _rotary(dense("query", h)(x), positions, cfg.rope_theta)
-        k = _rotary(dense("key", hkv)(x), positions, cfg.rope_theta)
-        v = dense("value", hkv)(x)
+        q, k, v = dense("query", h)(x), dense("key", hkv)(x), \
+            dense("value", hkv)(x)
+        if cfg.qk_norm:
+            # Over each head's own channels, one scale vector for the
+            # queries and one for the keys, before the rotary embedding.
+            with jax.named_scope("qk_norm"):
+                norm = lambda name: nn.RMSNorm(
+                    epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+                q, k = norm("q_norm")(q), norm("k_norm")(k)
+        q = _rotary(q, positions, cfg.rope_theta)
+        k = _rotary(k, positions, cfg.rope_theta)
 
         import horovod_tpu as hvd
 
@@ -444,6 +478,66 @@ class Attention(nn.Module):
                                use_bias=False, name="out")(out)
 
 
+class ShortConv(nn.Module):
+    """The ``'conv'`` mixer: ``[B | C | u] = y W_in`` (three streams of
+    the model's width), ``out = (C * taps(B * u)) W_out`` with ``taps`` a
+    depthwise causal convolution of ``conv_taps`` taps
+    (``ops/short_conv.py``); no bias, no activation. The ``gate`` scope is
+    everything between the two projections."""
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, y, segment_ids=None):
+        cfg = self.config
+        if cfg.decode:
+            raise ValueError(
+                "decode=True does not run a 'conv' layer (layer_types): "
+                "its state is the last conv_taps - 1 gated inputs of the "
+                "short convolution, not a KV cache — that state, its "
+                "decode branch and a cache that knows a layer's kind are "
+                "serving's work (ROADMAP M6).")
+        if cfg.attention != "local":
+            raise ValueError(
+                f"a 'conv' layer (layer_types) runs attention='local' "
+                f"only, not {cfg.attention!r}: the short convolution of a "
+                f"sequence shard's first positions would need a halo of "
+                f"conv_taps - 1 = {cfg.conv_taps - 1} positions from the "
+                f"shard before, which no exchange brings.")
+        dense = lambda width, name: nn.Dense(width, dtype=cfg.dtype,
+                                             use_bias=False, name=name)
+        taps = self.param(  # (a channel's fan-in is its own taps)
+            "taps", nn.initializers.lecun_normal(in_axis=-1, out_axis=-2),
+            (cfg.embed_dim, cfg.conv_taps))
+        bcu = dense(3 * cfg.embed_dim, "in_proj")(y)
+        with jax.named_scope("gate"):
+            out = _short_conv.gated_short_conv(bcu, taps, segment_ids)
+        return dense(cfg.embed_dim, "out_proj")(out)
+
+
+def _attention_mixer(cfg, y, positions, segment_ids, kv_view):
+    return Attention(cfg, name="attn")(y, positions, segment_ids,
+                                       kv_view=kv_view)
+
+
+def _conv_mixer(cfg, y, positions, segment_ids, kv_view):
+    return ShortConv(cfg, name="conv")(y, segment_ids)
+
+
+# The block's mixer slot: a kind -> (cfg, y, positions, segment_ids,
+# kv_view) -> y, building its module in the calling Block's scope under the
+# kind's own name (``attn``, ``conv``: the scopes the tracing reads).
+MIXER = {"attention": _attention_mixer, "conv": _conv_mixer}
+
+
+def mixer_of_layer(cfg: TransformerConfig, i: int) -> str:
+    """The mixer kind of layer ``i``: what ``cfg.layer_types`` says, and
+    attention where it says nothing (no pattern; the MTP module's block,
+    which is one past the stack)."""
+    if cfg.layer_types is None or i >= cfg.num_layers:
+        return "attention"
+    return cfg.layer_types[i]
+
+
 def _gelu_ffn(cfg, y):
     y = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, use_bias=False)(y)
     y = nn.gelu(y)
@@ -522,6 +616,7 @@ def ffn_of_layer(cfg: TransformerConfig, i: int) -> str:
 class Block(nn.Module):
     config: TransformerConfig
     ffn: str | None = None        # this layer's kind; None: ``config.ffn``
+    mixer: str = "attention"      # this layer's mixer, a kind of MIXER
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, kv_view=None):
@@ -530,13 +625,16 @@ class Block(nn.Module):
         if ffn not in FFN:
             raise ValueError(f"Unknown ffn {ffn!r}; one of "
                              f"{sorted(FFN)}.")
+        if self.mixer not in MIXER:
+            raise ValueError(f"Unknown mixer {self.mixer!r} in "
+                             f"layer_types; one of {sorted(MIXER)}.")
         norm = lambda: nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
         # Sandwich norms: a branch's output is normed before it joins the
         # residual stream, so four RMSNorms a block for two.
         post = (lambda y: norm()(y)) if cfg.sandwich_norm else (lambda y: y)
         y = norm()(x)
-        x = x + post(Attention(cfg, name="attn")(y, positions, segment_ids,
-                                                 kv_view=kv_view))
+        x = x + post(MIXER[self.mixer](cfg, y, positions, segment_ids,
+                                       kv_view))
         y = norm()(x)
         with jax.named_scope("moe" if ffn == "moe" else "mlp"):
             y = FFN[ffn](cfg, y)
@@ -544,10 +642,12 @@ class Block(nn.Module):
 
 
 def _attends_through_the_kernel(cfg: TransformerConfig, t_local: int) -> bool:
-    """Whether a block's attention is a call of the Pallas flash kernel
-    that the block's ``nn.remat`` sees, so that its policy finds the
-    kernel's two named residuals. Ring attention's steps sit under a bare
-    ``jax.checkpoint`` of their own, which saves nothing, named or not."""
+    """Whether an ATTENTION block's mixer is a call of the Pallas flash
+    kernel that the block's ``nn.remat`` sees, so that its policy finds
+    the kernel's two named residuals (a ``'conv'`` block has no kernel and
+    names nothing: it is recomputed whole). Ring attention's steps sit
+    under a bare ``jax.checkpoint`` of their own, which saves nothing,
+    named or not."""
     from horovod_tpu.parallel.sequence import local_attention_impl
 
     if cfg.attention == "local":
@@ -607,6 +707,11 @@ class Transformer(nn.Module):
         if cfg.recurrent_steps < 1:
             raise ValueError(
                 f"recurrent_steps must be >= 1, got {cfg.recurrent_steps}.")
+        if cfg.layer_types is not None \
+                and len(cfg.layer_types) != cfg.num_layers:
+            raise ValueError(
+                f"layer_types names {len(cfg.layer_types)} layers' mixers "
+                f"for num_layers={cfg.num_layers}.")
         looped = cfg.recurrent_steps > 1
         if cfg.exit_gate and not looped:
             raise ValueError(
@@ -630,15 +735,19 @@ class Transformer(nn.Module):
         # rank (core/timeline.py count_plan; dropped where none is).
         applied = cfg.num_layers * cfg.recurrent_steps \
             + (cfg.mtp is not None)  # the MTP module is one more block
+        convs = cfg.recurrent_steps * sum(
+            mixer_of_layer(cfg, i) == "conv" for i in range(cfg.num_layers))
         tl = _timeline.session()
         tl.count_plan("model.block_applications", applied)
+        tl.count_plan("model.attention_layers", applied - convs)
+        tl.count_plan("model.conv_layers", convs)
         tl.count_plan("model.recomputed_blocks", applied if looped else 0)
-        # Of those, the ones whose backward reads the attention kernel's
-        # output and log-sum-exp back and does not run the kernel again.
+        # Of the attention blocks, the ones whose backward reads the
+        # kernel's output and log-sum-exp back and does not run it again.
         tl.count_plan(
             "model.kept_attention_outputs",
-            applied if looped and _attends_through_the_kernel(cfg, t_local)
-            else 0)
+            applied - convs
+            if looped and _attends_through_the_kernel(cfg, t_local) else 0)
         if cfg.moe is not None:
             # The plan of the expert layers (the MTP module's is one more);
             # what the experts TOOK is the step's output, not the plan's.
@@ -657,7 +766,8 @@ class Transformer(nn.Module):
         def stack(block, x):
             """One pass: the blocks and the final norm."""
             for i in range(cfg.num_layers):
-                x = block(cfg, ffn=ffn_of_layer(cfg, i), name=f"block_{i}")(
+                x = block(cfg, ffn=ffn_of_layer(cfg, i),
+                          mixer=mixer_of_layer(cfg, i), name=f"block_{i}")(
                     x, positions, segment_ids,
                     None if kv_views is None else kv_views[i])
             return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,

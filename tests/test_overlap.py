@@ -428,3 +428,56 @@ class TestExpertLayerStepCompilesForTheChip:
             r'= [^\n]* while\([^\n]*op_name="[^"\n]*/moe/[^"\n]*'
             r'(?:dispatch|experts|combine)/while"', txt)
         assert len(loops) == expert_layers * (3 + 2 + 3)
+
+
+class TestConvAndAttentionStepCompilesForTheChip:
+    def test_gqa_at_64_wide_heads_beside_gated_short_convolutions(self):
+        """A stack whose mixer is a slot (models/transformer.py
+        ``layer_types``, ops/short_conv.py) in a REAL executable, compiled
+        for one v5e chip above T=2048 at the published head geometry — 64-
+        wide heads, four query heads a KV head, q/k norms — and model
+        width: the flash kernels once forward and once backward for the
+        ATTENTION layers alone, and the conv layers' gate-and-tap pass
+        under the ``conv/gate`` scope with no kernel and no convolution
+        instruction of its own (three shifted products, fused), run again
+        in the backward (``jax.checkpoint``)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from horovod_tpu.core.state import AXIS_NAME
+        from horovod_tpu.models import transformer
+
+        devices = _topo(4, "v5e:2x2")[:1]
+        hvd.shutdown()
+        hvd.init(devices=devices)
+        cfg = transformer.TransformerConfig(
+            vocab_size=512, num_layers=3, num_heads=8, num_kv_heads=2,
+            embed_dim=512, mlp_dim=512, max_seq_len=4096, ffn="swiglu",
+            norm_eps=1e-5, rope_theta=1e6, qk_norm=True,
+            layer_types=("conv", "attention", "conv"))
+        loss_fn = transformer.make_loss_fn(cfg, fused_head=True)
+
+        def grad_step(params, tokens):
+            loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+            return hvd.allreduce_gradients(grads), hvd.allreduce(loss)
+
+        shard = NamedSharding(hvd.get_group(0).mesh, P(AXIS_NAME))
+        stacked = lambda a, dtype: jax.ShapeDtypeStruct(
+            (1,) + a.shape, dtype, sharding=shard)
+        params = jax.tree.map(
+            lambda a: stacked(a, a.dtype),
+            jax.eval_shape(lambda: transformer.init_params(cfg)))
+        tokens = stacked(jnp.zeros((1, 4096)), jnp.int32)
+        with jax.enable_x64(False):
+            txt = hvd.spmd(grad_step).lower(params, tokens).compile(
+                ).as_text()
+        hvd.shutdown()
+        count = lambda name: len(re.findall(
+            rf"= [^\n]* custom-call\([^\n]*{name}", txt))
+        assert count("hvd_flash_fwd") == count("hvd_flash_bwd") == 1
+        assert txt.count('custom_call_target="tpu_custom_call"') == 2
+        gate = re.findall(r'op_name="[^"\n]*/block_[02]/conv/gate/[^"\n]*"',
+                          txt)
+        assert gate and not [n for n in gate if "conv_general" in n]
+        assert [n for n in gate if "rematted_computation" in n]
+        assert not re.findall(r'op_name="[^"\n]*/block_1/conv/', txt)
+        assert re.findall(r'op_name="[^"\n]*/block_1/attn/qk_norm/', txt)

@@ -180,7 +180,9 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
         # applies each of its CFG.num_layers blocks once, recomputes none
         # and has one head
         "model.block_applications": 2, "model.recomputed_blocks": 0,
-        "model.kept_attention_outputs": 0, "model.head_applications": 1}
+        "model.kept_attention_outputs": 0, "model.head_applications": 1,
+        # ... all of them attention (no ``layer_types``: PR 33)
+        "model.attention_layers": 2, "model.conv_layers": 0}
 
 
 def test_exchange_async_bytes_are_the_leaves_that_go_round_the_ring(
@@ -212,22 +214,29 @@ def test_exchange_async_bytes_are_the_leaves_that_go_round_the_ring(
         assert counters["exchange.wire_bytes"] == (8 << 20) + 2048
 
 
-@pytest.mark.parametrize("impl", ["flash", "xla"])
+@pytest.mark.parametrize("impl,layer_types,kept", [
+    ("flash", None, 6), ("xla", None, 0),
+    ("flash", ("conv", "attention"), 3)],
+    ids=["flash", "xla", "flash_beside_a_conv_layer"])
 def test_a_looped_steps_record_counts_the_kept_attention_outputs(
-        monkeypatch, impl):
-    """A looped stack recomputes every block application, and of each one
-    whose attention is the Pallas kernel (here interpreted) its backward
-    reads the kernel's output back; where attention does not go through
-    the kernel nothing is named, so nothing is kept."""
+        monkeypatch, impl, layer_types, kept):
+    """A looped stack recomputes every block application, and of each
+    ATTENTION one whose mixer is the Pallas kernel (here interpreted) its
+    backward reads the kernel's output back; where attention does not go
+    through the kernel nothing is named, so nothing is kept, and a
+    ``'conv'`` layer has no kernel to keep anything of."""
     monkeypatch.setattr(sequence, "local_attention_impl", lambda t: impl)
     _world4()
-    step, ps, ss, toks, _ = _lm_step(cfg=CFG._replace(recurrent_steps=3))
+    step, ps, ss, toks, _ = _lm_step(cfg=CFG._replace(
+        recurrent_steps=3, layer_types=layer_types))
     step(ps, ss, toks)
     counters = timeline.record()["programs"][TAG]["counters"]
     hvd.shutdown()
+    convs = 0 if layer_types is None else 3
     assert {k: v for k, v in counters.items() if k.startswith("model.")} == {
         "model.block_applications": 6, "model.recomputed_blocks": 6,
-        "model.kept_attention_outputs": 6 if impl == "flash" else 0,
+        "model.attention_layers": 6 - convs, "model.conv_layers": convs,
+        "model.kept_attention_outputs": kept,
         "model.head_applications": 1}
 
 
