@@ -107,6 +107,7 @@ import optax
 
 from horovod_tpu.core import state as _state
 from horovod_tpu.core import timeline as _timeline
+from horovod_tpu.ops import flash_attention as _flash
 from horovod_tpu.ops import moe as _moe
 from horovod_tpu.ops import short_conv as _short_conv
 from horovod_tpu.ops.flash_attention import LSE_RESIDUAL, OUT_RESIDUAL
@@ -650,13 +651,18 @@ def _attends_through_the_kernel(cfg: TransformerConfig, t_local: int) -> bool:
     named or not."""
     from horovod_tpu.parallel.sequence import local_attention_impl
 
+    t = _kernel_tokens(cfg, t_local)
+    return t is not None and local_attention_impl(t) == "flash"
+
+
+def _kernel_tokens(cfg: TransformerConfig, t_local: int) -> int | None:
+    """The sequence length a block's ``local_attention`` call sees, None
+    where the block makes no such call under its own ``nn.remat``."""
     if cfg.attention == "local":
-        t = t_local
-    elif cfg.attention == "ulysses":  # the full sequence, H/g heads
-        t = t_local * _state.get_group(cfg.sp_group).size
-    else:
-        return False
-    return local_attention_impl(t) == "flash"
+        return t_local
+    if cfg.attention == "ulysses":  # the full sequence, H/g heads
+        return t_local * _state.get_group(cfg.sp_group).size
+    return None
 
 
 class Transformer(nn.Module):
@@ -742,12 +748,31 @@ class Transformer(nn.Module):
         tl.count_plan("model.attention_layers", applied - convs)
         tl.count_plan("model.conv_layers", convs)
         tl.count_plan("model.recomputed_blocks", applied if looped else 0)
+        # Whether an attention block's mixer is a call of the Pallas kernel:
+        # asked for a looped stack (whose policy keeps the kernel's
+        # residuals) and for an open record (hvd.spmd's: a world exists
+        # there, which the 'ulysses' answer needs).
+        kernel = (looped or tl.building is not None) \
+            and _attends_through_the_kernel(cfg, t_local)
         # Of the attention blocks, the ones whose backward reads the
         # kernel's output and log-sum-exp back and does not run it again.
-        tl.count_plan(
-            "model.kept_attention_outputs",
-            applied - convs
-            if looped and _attends_through_the_kernel(cfg, t_local) else 0)
+        tl.count_plan("model.kept_attention_outputs",
+                      applied - convs if looped and kernel else 0)
+        if kernel:
+            # What the mask leaves visible of the kernels' scores and what
+            # they compute (ops/flash_attention.score_counts, the kernels'
+            # own classification): a forward and a backward call a block
+            # application, every batch row and head. Segment ids are data
+            # and not counted.
+            t = _kernel_tokens(cfg, t_local)  # ulysses: g x the tokens,
+            heads = cfg.num_heads * t_local // t  # a g-th of the heads
+            visible, computed = _flash.score_counts(
+                t, t, cfg.embed_dim // cfg.num_heads if cfg.mla is None
+                else max(cfg.mla.nope_dim + cfg.mla.rope_dim,
+                         cfg.mla.v_dim), window=cfg.window)
+            calls = (applied - convs) * tokens.shape[0] * heads
+            tl.count_plan("flash.scores_visible", calls * visible)
+            tl.count_plan("flash.scores_computed", calls * computed)
         if cfg.moe is not None:
             # The plan of the expert layers (the MTP module's is one more);
             # what the experts TOOK is the step's output, not the plan's.

@@ -10,11 +10,30 @@ materializes the (Tq, Tk) score matrix in HBM — a 16k-token context costs
   differentiates through the scan, and is the reference/recompute path.
 * :func:`flash_attention` — a pallas TPU kernel of the same math: grid over
   (batch, heads, q-blocks, k-blocks), running max/normalizer/accumulator in
-  VMEM scratch, causal blocks skipped via ``pl.when``, MXU matmuls in bf16
-  with fp32 accumulation. Backward is a single fused FlashAttention-2-style
-  pallas kernel producing dq, dk and dv in one sweep (5 matmuls per block
-  pair — the score/dp recompute is shared instead of being done once per
-  output as in the classic two-pass dq + dk/dv decomposition).
+  VMEM scratch, MXU matmuls in bf16 with fp32 accumulation. Backward is a
+  single fused FlashAttention-2-style pallas kernel producing dq, dk and dv
+  in one sweep (5 matmuls per block pair — the score/dp recompute is shared
+  instead of being done once per output as in the classic two-pass dq +
+  dk/dv decomposition).
+
+**The kernels follow the mask** (:func:`_block_visibility`): a (q block, k
+block) pair the causal line, the sliding window or the padding leaves
+nothing of is neither computed NOR FETCHED — the index maps of K and V
+(forward) and of the q-side operands (backward) name, for such a pair, the
+block the nearest visible pair names (:func:`_block_range`), and Pallas
+moves nothing for an index that did not change; a pair the mask leaves
+whole is computed with no mask at all; a pair the mask's line crosses — an
+EDGE: the causal diagonal, the window's far edge — is walked by sub-tiles
+(:func:`_sub_tile`: halves of the block's sides) in a rolled loop, the
+wholly masked sub-tiles left out: the backward computes a wholly visible
+sub-tile with no mask and a crossed one masked (one body each in its
+text); the forward computes the sub-tiles a row of them does not skip as
+one masked rectangle, so that the running softmax's per-row work is paid
+once a row. At T = 8192 full
+causal with the 1024-wide blocks 106 % of the visible scores are computed
+(112.5 % when an edge pair was computed whole), and at most 36 of the 64
+pairs fetch a K/V block where all 64 did. :func:`score_counts` gives both
+numbers for any call, from the same classification.
 
 Both support **grouped-query attention** (fewer K/V heads than Q heads —
 ``H % Hkv == 0``, each K/V head serves a contiguous group of Q heads) and
@@ -190,49 +209,109 @@ def blockwise_attention(q, k, v, causal: bool = True,
 # ---------------------------------------------------------------------------
 
 
-def _block_visibility(q_off, kv_off, iq, ik, causal, block_q, block_k, tk,
-                      has_segs=False, window=None):
-    """Classify a (q-block, k-block) pair for causal/padding masking.
+def _block_visibility(q_first, q_len, k_first, k_len, k_end, causal,
+                      window=None, has_segs=False):
+    """Classify the rectangle of scores between ``q_len`` query rows from
+    global position ``q_first`` and ``k_len`` keys from ``k_first``:
+    ``(skip, interior)``. ONE function for a (q block, k block) pair of a
+    kernel's grid, for a sub-tile of such a pair, and for the counts of
+    :func:`score_counts`; its arguments may be Python ints, numpy arrays
+    or traced scalars (``&`` and ``|`` only).
 
-    Returns (skip, interior, q_first, k_first): ``skip`` — the K block is
-    entirely in the Q block's future (or, with a sliding ``window``,
-    entirely beyond its past horizon), nothing to accumulate; ``interior``
-    — every (q, k) pair in the block is visible and unpadded, so the
-    kernel can skip the position-mask VPU work entirely (most blocks of a
-    long sequence are interior — this is where causal flash attention
-    wins its VPU time back); ``q_first``/``k_first`` — the blocks' global
-    start positions, for the callers' mask iotas. Positions are global,
-    so sequence-parallel shards classify correctly against their true
-    offsets. With segment ids there is no interior fast path (any block
-    may straddle a segment boundary). ``window`` (sliding-window
-    attention, causal only): query p sees keys in [p-window+1, p].
+    ``skip`` — nothing in the rectangle is visible: every key is in every
+    row's future (causal), or beyond every row's past horizon (``window``:
+    query p sees keys [p-window+1, p]), or padding (``k_end`` is the
+    global position one past the last real key). A skipped PAIR is not
+    computed and not fetched: the K/V (forward) and q-side (backward)
+    index maps name the block the nearest visible pair names
+    (:func:`_block_range`), and Pallas fetches nothing for an index that
+    did not change. ``interior`` — every score in it is visible and
+    unpadded: computed with no position mask at all (the iota-and-select
+    VPU passes are where causal flash attention wins its time back). A
+    pair that is neither is an EDGE (the causal diagonal, the window's far
+    edge, the sequence's end): the kernels walk its sub-tiles
+    (:func:`_sub_tile`) and classify each with this same function — the
+    wholly masked ones are left out; of the rest the backward computes the
+    wholly visible ones unmasked and only the ones the mask's line crosses
+    masked, the forward a row's as one masked rectangle (``_fwd_kernel``
+    says why). With segment ids there is no interior (any rectangle may
+    straddle a segment boundary); the skipping still applies.
     """
-    q_first = q_off + iq * block_q
-    q_last = q_first + block_q - 1
-    k_first = kv_off + ik * block_k
-    k_last = k_first + block_k - 1
-    skip = jnp.logical_or(
-        jnp.logical_and(bool(causal), q_last < k_first),
-        ik * block_k >= tk)                    # block is entirely padding
-    interior_vis = jnp.logical_or(not causal, q_first >= k_last)
+    q_last = q_first + q_len - 1
+    k_last = k_first + k_len - 1
+    skip = k_first >= k_end                    # entirely padding
+    interior = k_last < k_end
+    if causal:
+        skip = skip | (q_last < k_first)
+        interior = interior & (q_first >= k_last)
     if window is not None:
-        # Query p sees keys [p-window+1, p]; the FIRST (smallest) query row
-        # sees the oldest keys, so the block is skippable only when its
-        # newest key is older than even that row's horizon.
-        skip = jnp.logical_or(skip, k_last < q_first - (window - 1))
-        # Interior needs every pair visible: the LAST query row must still
-        # see the block's oldest key.
-        interior_vis = jnp.logical_and(
-            interior_vis, k_first >= q_last - (window - 1))
-    unpadded = (ik + 1) * block_k <= tk
-    interior = jnp.logical_and(unpadded, interior_vis)
+        # The FIRST (smallest) query row sees the oldest keys: skippable
+        # only when the newest key is older than even that row's horizon;
+        # interior only when the LAST row still sees the oldest key.
+        skip = skip | (k_last < q_first - (window - 1))
+        interior = interior & (k_first >= q_last - (window - 1))
     if has_segs:
-        interior = jnp.logical_and(interior, False)
-    return skip, interior, q_first, k_first
+        interior = interior & False
+    return skip, interior
+
+
+def _block_range(i, x_lo, x_hi, off, block, n):
+    """Block index ``i`` (of ``n`` blocks of ``block`` positions from
+    global position ``off``) moved into the blocks that reach position
+    ``x_lo`` or later and start at ``x_hi`` or before — what
+    :func:`_block_visibility`'s two ``skip`` inequalities leave, solved
+    for the index: ``(i+1)*block - 1 + off >= x_lo`` and
+    ``i*block + off <= x_hi``. ``None`` leaves a side open. An index map
+    built on it names, for a skipped pair, a block a visible pair names;
+    where nothing is visible it names some one block, all the same."""
+    lo, hi = 0, n - 1
+    if x_lo is not None:
+        lo = jnp.minimum(jnp.maximum(x_lo - off, 0) // block, hi)
+    if x_hi is not None:
+        hi = jnp.minimum(jnp.maximum(x_hi - off, 0) // block, hi)
+        lo = jnp.minimum(lo, hi)
+    return jnp.clip(i, lo, hi)
+
+
+def _sub_tile(block: int, q_lanes: int | None = None) -> int:
+    """The side of an edge pair's sub-tiles along a block side: half the
+    block where the half is still a whole number of 128-lane tiles, else
+    the block (a small block is its own sub-tile: computed whole and
+    masked, as every edge pair was before). ``q_lanes``: the backward's q
+    block, the lanes of its score tile, which stays whole — a sub-tile of
+    keys taller than that is wide holds masked scores wherever the
+    diagonal crosses it, so the keys' side is cut down to it (D > 128: 256
+    q lanes, sub-tiles of 256 keys; 12.02 ms a backward call for 12.17 at
+    halves, my chip run, PR 35). At the 1024-wide defaults an edge pair is
+    2 x 2 (forward) or 2 x 1 (backward) sub-tiles of 512, one of four —
+    one of two — left out on the diagonal: 106 % of the visible scores are
+    computed at T = 8192 full causal where whole edge blocks made it
+    112.5 %."""
+    sub = block // 2 if block % 256 == 0 else block
+    if q_lanes is not None and q_lanes % 128 == 0 and sub % q_lanes == 0:
+        sub = q_lanes
+    return sub
+
+
+def _fwd_blocks(tq, tk, block_q, block_k):
+    """The forward grid of a call: ``(block_q, block_k, nq, nk)``."""
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    return block_q, block_k, -(-tq // block_q), -(-tk // block_k)
+
+
+def _bwd_blocks(tq, tk, block_q, block_kc, block_kv_mem):
+    """The backward grid of a call: ``(block_q, block_kc, bkv_mem, nq,
+    nkm)``. kv memory block: how much K/V sits VMEM-resident per grid
+    step. The dq partial-sum dimension is ceil(Tk / block_kv_mem) — one
+    memory block (a no-op reduction) whenever Tk fits."""
+    block_q, block_kc = min(block_q, tq), min(block_kc, tk)
+    bkv_mem = block_kc * max(1, min(block_kv_mem, tk) // block_kc)
+    return block_q, block_kc, bkv_mem, -(-tq // block_q), -(-tk // bkv_mem)
 
 
 def _fwd_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale, block_q,
-                block_k, nk, tk, has_segs, window, compact_lse):
+                block_k, sub_q, sub_k, nk, tk, has_segs, window,
+                compact_lse):
     if has_segs:
         (q_ref, k_ref, v_ref, qseg_ref, kvseg_ref,
          o_ref, lse_ref, m_scr, l_scr, acc_scr) = refs
@@ -248,55 +327,91 @@ def _fwd_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale, block_q,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q_off = qoff_ref[0]
     kv_off = kvoff_ref[0]
-    skip, interior, q_first, k_first = _block_visibility(
-        q_off, kv_off, iq, ik, causal, block_q, block_k, tk, has_segs,
-        window)
+    q_first = qoff_ref[0] + iq * block_q
+    k_first = kv_off + ik * block_k
+    k_end = kv_off + tk
+    classify = functools.partial(_block_visibility, k_end=k_end,
+                                 causal=causal, window=window,
+                                 has_segs=has_segs)
+    skip, interior = classify(q_first, block_q, k_first, block_k)
 
-    def _accumulate(masked):
-        q = q_ref[...]                                        # (bq, D)
+    def _accumulate(r0, nr, c0, nc, masked, kv_segs=None):
+        """Rows [r0, r0+nr) x keys [c0, c0+nc) of the pair."""
+        rows, cols = pl.ds(r0, nr), pl.ds(c0, nc)
         s = jax.lax.dot_general(
-            q, k_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bq, bk)
+            q_ref[rows, :], k_ref[cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (nr, nc)
         if sm_scale != 1.0:
             s = s * sm_scale
         if masked:
-            kpos = k_first + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = kpos < (kv_off + tk)                      # strip padding
+            kpos = k_first + c0 + jax.lax.broadcasted_iota(
+                jnp.int32, (nr, nc), 1)
+            valid = kpos < k_end                              # strip padding
             if causal:
-                qpos = (q_first + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0))
+                qpos = q_first + r0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (nr, nc), 0)
                 valid = jnp.logical_and(valid, qpos >= kpos)
                 if window is not None:
                     valid = jnp.logical_and(
                         valid, kpos > qpos - window)
             if has_segs:
-                valid = jnp.logical_and(
-                    valid, qseg_ref[:, :1] == kvseg_ref[:1, :])
+                valid = jnp.logical_and(valid,
+                                        qseg_ref[rows, :1] == kv_segs)
             s = jnp.where(valid, s, _NEG_INF)
         # Running softmax in base 2 (operands carry the log2e factor).
-        m_prev = m_scr[:, :1]                                 # (bq, 1)
-        l_prev = l_scr[:, :1]
+        m_prev = m_scr[rows, :1]                              # (nr, 1)
+        l_prev = l_scr[rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp2(m_prev - m_new)
         p = jnp.exp2(s - m_new)
         if masked:
             p = jnp.where(valid, p, 0.0)
-        l_scr[:, :1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[:, :1] = m_new
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+        l_scr[rows, :1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_scr[rows, :1] = m_new
+        acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[cols, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(interior)
     def _fast():
-        _accumulate(masked=False)
+        _accumulate(0, block_q, 0, block_k, masked=False)
+
+    # An edge pair by sub-tiles, one row of them a round of a rolled loop.
+    # A row sees an interval of keys, so the sub-tiles a row of them does
+    # not skip are neighbours: they are computed as ONE masked rectangle
+    # (the running softmax's per-row work — the maximum, the rescaling of
+    # the accumulator — is paid once a row, as in a whole block, whatever
+    # the number of sub-tiles; paid a sub-tile it cost more than the
+    # masked quarter saved). The kernel's text holds one masked body an
+    # extent (one and two sub-tiles at the defaults), whatever the mask.
+    n_sub_k = block_k // sub_k
+
+    def _sub_row(r, carry):
+        r0 = pl.multiple_of(r * sub_q, sub_q)
+        seen = [~classify(q_first + r0, sub_q, k_first + c * sub_k, sub_k)[0]
+                for c in range(n_sub_k)]
+        extent = sum(s.astype(jnp.int32) for s in seen)
+        first, none_yet = 0, True       # the sub-tiles skipped before them
+        for s in seen[:-1]:
+            none_yet = none_yet & ~s
+            first = first + none_yet.astype(jnp.int32)
+        for n in range(1, n_sub_k + 1):
+            @pl.when(extent == n)
+            def _rectangle(n=n):
+                start = first if n < n_sub_k else 0
+                _accumulate(
+                    r0, sub_q, pl.multiple_of(start * sub_k, sub_k),
+                    n * sub_k, masked=True,
+                    kv_segs=jnp.concatenate(
+                        [kvseg_ref[pl.ds(start + i, 1), :]
+                         for i in range(n)], axis=1) if has_segs else None)
+
+        return carry
 
     @pl.when(jnp.logical_and(~skip, ~interior))
     def _edge():
-        _accumulate(masked=True)
+        lax.fori_loop(0, block_q // sub_q, _sub_row, 0)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -321,21 +436,106 @@ def _fwd_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale, block_q,
             lse_ref[...] = jnp.broadcast_to(lse_col, (block_q, 128))
 
 
+@functools.lru_cache(maxsize=None)
+def _fwd_call(b, h, hkv, tq, tk, d, dtype, causal, offsets, block_q,
+              block_k, has_segs, window, interpret):
+    """The forward ``pallas_call`` of one geometry, built ONCE a process.
+    ``pl.pallas_call`` returns a fresh ``jax.jit`` every time, whose trace
+    cache a second building cannot hit: built a call site, the kernel's
+    body is traced again wherever the model is — 24 times for the looped
+    cell's 8 layers (flax's ``nn.scan`` traces its body four times and
+    ``nn.remat`` each block inside it), seconds of every start (PERF.md,
+    PR 35). ``offsets``: the two static offsets, or None where they are
+    traced (the index maps then read the prefetched scalars)."""
+    g = h // hkv
+    block_q, block_k, nq, nk = _fwd_blocks(tq, tk, block_q, block_k)
+    sub_q, sub_k = _sub_tile(block_q), _sub_tile(block_k)
+    # Compact lse tiles need block_q//128 to satisfy pallas's
+    # divisible-by-8 second-to-last-dim rule (see _finalize).
+    compact_lse = block_q % (8 * 128) == 0
+
+    def k_block(iq, ik, qoff_ref, kvoff_ref):
+        """The K/V block pair (iq, ik) reads: ik, or for a skipped pair
+        the nearest block a visible pair of this q block names."""
+        q_off, kv_off = offsets or (qoff_ref[0], kvoff_ref[0])
+        q_first = q_off + iq * block_q
+        return _block_range(
+            ik, None if window is None else q_first - (window - 1),
+            q_first + block_q - 1 if causal else None, kv_off, block_k, nk)
+
+    q_spec = pl.BlockSpec((None, None, block_q, d),
+                          lambda b_, h_, iq, ik, *_: (b_, h_, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, d),
+        lambda b_, h_, iq, ik, *offs: (b_, h_ // g, k_block(iq, ik, *offs),
+                                       0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    if has_segs:
+        # q segs lane-broadcast (B, L, 128): a per-row column; kv segs a
+        # row a column sub-tile, (B, nk, block_k / sub_k, sub_k).
+        in_specs += [
+            pl.BlockSpec((None, block_q, 128),
+                         lambda b_, h_, iq, ik, *_: (b_, iq, 0)),
+            pl.BlockSpec((None, None, block_k // sub_k, sub_k),
+                         lambda b_, h_, iq, ik, *offs:
+                         (b_, k_block(iq, ik, *offs), 0, 0)),
+        ]
+    # One derived row count keeps the lse spec/shape/kernel in sync.
+    lse_rows = block_q // 128 if compact_lse else block_q
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, causal=causal, sm_scale=1.0, block_q=block_q,
+            block_k=block_k, sub_q=sub_q, sub_k=sub_k, nk=nk, tk=tk,
+            has_segs=has_segs, window=window, compact_lse=compact_lse),
+        name="hvd_flash_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            # The two offsets, in SMEM before the pipeline starts: the
+            # index maps read them where they are traced (ring attention).
+            num_scalar_prefetch=2,
+            grid=(b, h, nq, nk),
+            in_specs=in_specs,
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((None, None, lse_rows, 128),
+                             lambda b_, h_, iq, ik, *_: (b_, h_, iq, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),      # running max
+                pltpu.VMEM((block_q, 128), jnp.float32),      # normalizer
+                pltpu.VMEM((block_q, d), jnp.float32),        # accumulator
+            ]),
+        compiler_params=_FWD_SEMANTICS,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, nq * block_q, d), dtype),
+            # Log-sum-exp: compact (block_q//128, 128) tiles per q-block
+            # (see _finalize), reshaped to (B, H, L) by the caller; legacy
+            # lane-broadcast rows when the block is too small for
+            # pallas's divisible-by-8 rule.
+            jax.ShapeDtypeStruct((b, h, nq * lse_rows, 128), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+
+
+def _static_offsets(q_offset, kv_offset):
+    """``(q_offset, kv_offset)`` where both are Python ints, else None."""
+    if isinstance(q_offset, int) and isinstance(kv_offset, int):
+        return q_offset, kv_offset
+    return None
+
+
 def _flash_fwd(q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
                block_q, block_k, interpret, window=None):
     b, tq, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
-    g = _check_gqa(h, hkv)
+    _check_gqa(h, hkv)
     has_segs = qseg is not None
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    nq = -(-tq // block_q)
-    nk = -(-tk // block_k)
+    call = _fwd_call(b, h, hkv, tq, tk, d, q.dtype, causal,
+                     _static_offsets(q_offset, kv_offset), block_q, block_k,
+                     has_segs, window, interpret)
+    block_q, block_k, nq, nk = _fwd_blocks(tq, tk, block_q, block_k)
     pad_q = nq * block_q - tq
     pad_k = nk * block_k - tk
-    # Compact lse tiles need block_q//128 to satisfy pallas's
-    # divisible-by-8 second-to-last-dim rule (see _finalize).
-    compact_lse = block_q % (8 * 128) == 0
 
     # Fold the softmax scale AND the exp→exp2 conversion factor into the
     # operands (√(scale·log2e) each side): the kernel then skips both the
@@ -350,73 +550,21 @@ def _flash_fwd(q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
     if pad_k:
         kT = jnp.pad(kT, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vT = jnp.pad(vT, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                # q_offset
-        pl.BlockSpec(memory_space=pltpu.SMEM),                # kv_offset
-        pl.BlockSpec((None, None, block_q, d),
-                     lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda b_, h_, iq, ik, g=g: (b_, h_ // g, ik, 0)),
-        pl.BlockSpec((None, None, block_k, d),
-                     lambda b_, h_, iq, ik, g=g: (b_, h_ // g, ik, 0)),
-    ]
     args = [jnp.asarray([q_offset], jnp.int32),
             jnp.asarray([kv_offset], jnp.int32),
             qT.astype(jnp.bfloat16), kT.astype(jnp.bfloat16),
             vT.astype(jnp.bfloat16)]
     if has_segs:
-        # q segs lane-broadcast (B, L, 128): the fwd layout needs them as a
-        # per-row column; kv segs as a per-block row (B, 1, Lk).
+        sub_k = _sub_tile(block_k)
         qseg_b = jnp.pad(qseg, ((0, 0), (0, pad_q)), constant_values=-1)
-        qseg_b = jnp.broadcast_to(qseg_b[..., None],
-                                  qseg_b.shape + (128,))
-        kvseg_b = jnp.pad(kvseg, ((0, 0), (0, pad_k)),
-                          constant_values=-2)[:, None, :]
-        in_specs += [
-            pl.BlockSpec((None, block_q, 128),
-                         lambda b_, h_, iq, ik: (b_, iq, 0)),
-            pl.BlockSpec((None, 1, block_k),
-                         lambda b_, h_, iq, ik: (b_, 0, ik)),
-        ]
-        args += [qseg_b, kvseg_b]
-
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, sm_scale=1.0,
-        block_q=block_q, block_k=block_k, nk=nk, tk=tk, has_segs=has_segs,
-        window=window, compact_lse=compact_lse)
-    # One derived row count keeps the lse spec/shape/kernel in sync.
-    lse_rows = block_q // 128 if compact_lse else block_q
-    out, lse = pl.pallas_call(
-        kernel,
-        name="hvd_flash_fwd",
-        grid=(b, h, nq, nk),
-        compiler_params=_FWD_SEMANTICS,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-            pl.BlockSpec((None, None, lse_rows, 128),
-                         lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(qT.shape, q.dtype),
-            # Log-sum-exp: compact (block_q//128, 128) tiles per q-block
-            # (see _finalize), reshaped to (B, H, L) below; legacy
-            # lane-broadcast rows when the block is too small for
-            # pallas's divisible-by-8 rule.
-            jax.ShapeDtypeStruct((b, h, nq * lse_rows, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),          # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),          # normalizer
-            pltpu.VMEM((block_q, d), jnp.float32),            # accumulator
-        ],
-        interpret=interpret,
-    )(*args)
+        args += [jnp.broadcast_to(qseg_b[..., None], qseg_b.shape + (128,)),
+                 jnp.pad(kvseg, ((0, 0), (0, pad_k)),
+                         constant_values=-2).reshape(
+                             b, nk, block_k // sub_k, sub_k)]
+    out, lse = call(*args)
     if pad_q:
         out = out[:, :, :tq]
-    if compact_lse:
+    if lse.shape[2] * 128 == nq * block_q:
         # The residual arrives compact: (B, H, nq·bq/128, 128) tiles
         # reshape contiguously to (B, H, L).
         lse_c = lse.reshape(b, h, nq * block_q)
@@ -446,8 +594,8 @@ def _flash_fwd(q, k, v, qseg, kvseg, causal, sm_scale, q_offset, kv_offset,
 
 
 def _bwd_fused_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale,
-                      block_q, block_kc, bkv_mem, nq, tk, heads_per_kv,
-                      has_segs, may_have_dead, window):
+                      block_q, block_kc, sub_k, bkv_mem, nq, tk,
+                      heads_per_kv, has_segs, may_have_dead, window):
     if has_segs:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, qseg_ref, kvseg_ref,
          dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr) = refs
@@ -467,12 +615,14 @@ def _bwd_fused_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale,
 
     dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q_off = qoff_ref[0]
     kv_off = kvoff_ref[0]
-    q_first = q_off + iq * block_q
-    q_last = q_first + block_q - 1
-    k_mem_first_idx = ikm * bkv_mem                           # local index
+    q_first = qoff_ref[0] + iq * block_q
+    k_mem_first = kv_off + ikm * bkv_mem          # global position
+    k_end = kv_off + tk
     nkc = bkv_mem // block_kc
+    classify = functools.partial(_block_visibility, q_first, block_q,
+                                 k_end=k_end, causal=causal, window=window,
+                                 has_segs=has_segs)
 
     q = q_ref[...]                                            # (bq, D)
     do = do_ref[...]                                          # (bq, D)
@@ -485,22 +635,22 @@ def _bwd_fused_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale,
     # common same-shard call skips the guard (two VPU passes per block).
     dead_row = (lse_row <= _NEG_INF * 0.5) if may_have_dead else None
 
-    def _compute_block(i, masked):
-        sl = pl.ds(i * block_kc, block_kc)
-        k_c = k_ref[sl, :]                                    # (bkc, D)
+    def _compute(k0, nkr, masked):
+        """Keys [k0, k0+nkr) of the resident memory block x the q block."""
+        sl = pl.ds(k0, nkr)
+        k_c = k_ref[sl, :]                                    # (nkr, D)
         v_c = v_ref[sl, :]
         s = lax.dot_general(k_c, q, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         if sm_scale != 1.0:
             s = s * sm_scale
         if masked:
-            k_first = kv_off + k_mem_first_idx + i * block_kc
-            kpos = k_first + lax.broadcasted_iota(
-                jnp.int32, (block_kc, block_q), 0)
-            valid = kpos < (kv_off + tk)                      # strip padding
+            kpos = k_mem_first + k0 + lax.broadcasted_iota(
+                jnp.int32, (nkr, block_q), 0)
+            valid = kpos < k_end                              # strip padding
             if causal:
                 qpos = q_first + lax.broadcasted_iota(
-                    jnp.int32, (block_kc, block_q), 1)
+                    jnp.int32, (nkr, block_q), 1)
                 valid = jnp.logical_and(valid, qpos >= kpos)
                 if window is not None:
                     valid = jnp.logical_and(
@@ -534,38 +684,51 @@ def _bwd_fused_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale,
             ds, k_c, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    def _sub(i):
+        """The edge compute block ``i`` by its sub-tiles of ``sub_k`` keys
+        (the q block whole), a rolled loop: one unmasked and one masked
+        sub-tile body in the kernel's text."""
+        def body(j, carry):
+            k0 = pl.multiple_of(i * block_kc + j * sub_k, sub_k)
+            sub_skip, sub_interior = classify(k_mem_first + k0, sub_k)
+
+            @pl.when(sub_interior)
+            def _visible():
+                _compute(k0, sub_k, masked=False)
+
+            @pl.when(jnp.logical_and(~sub_skip, ~sub_interior))
+            def _crossed():
+                _compute(k0, sub_k, masked=True)
+
+            return carry
+
+        lax.fori_loop(0, block_kc // sub_k, body, 0)
+
     def _step(i, carry):
-        # Same classification as the forward, at the global compute-block
-        # index within the full (padded) K sequence.
-        k_idx = k_mem_first_idx // block_kc + i
-        skip, interior, _, _ = _block_visibility(
-            q_off, kv_off, iq, k_idx, causal, block_q, block_kc, tk,
-            has_segs, window)
+        # Same classification as the forward, at the compute block's
+        # global position within the full (padded) K sequence.
+        k0 = pl.multiple_of(i * block_kc, block_kc)
+        skip, interior = classify(k_mem_first + k0, block_kc)
 
         @pl.when(interior)
         def _fast():
-            _compute_block(i, masked=False)
+            _compute(k0, block_kc, masked=False)
 
         @pl.when(jnp.logical_and(~skip, ~interior))
         def _edge():
-            _compute_block(i, masked=True)
+            _sub(i)
 
         return carry
 
-    # Whole-step causal skip: the entire kv memory block is in this q
-    # block's future. dq still gets a (zero) write — the partial-sum
-    # reduction reads every slot.
-    step_active = jnp.logical_or(
-        not causal, q_last >= kv_off + k_mem_first_idx)
-    if window is not None:
-        # The whole memory block can also be beyond the past horizon.
-        k_mem_last = kv_off + k_mem_first_idx + bkv_mem - 1
-        step_active = jnp.logical_and(
-            step_active, k_mem_last >= q_first - (window - 1))
+    # Whole-step skip: the entire kv memory block is in this q block's
+    # future, or beyond its past horizon (its q-side operands were not
+    # fetched for it either: _flash_bwd's index maps). dq still gets a
+    # (zero) write — the partial-sum reduction reads every slot.
+    step_skip, _ = classify(k_mem_first, bkv_mem)
 
-    @pl.when(step_active)
+    @pl.when(~step_skip)
     def _run():
-        lax.fori_loop(0, nkc, _step, 0, unroll=True)
+        lax.fori_loop(0, nkc, _step, 0)
 
     dq_ref[...] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -575,23 +738,104 @@ def _bwd_fused_kernel(qoff_ref, kvoff_ref, *refs, causal, sm_scale,
         dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.lru_cache(maxsize=None)
+def _bwd_call(b, h, hkv, tq, tk, d, dtypes, causal, offsets, block_q,
+              block_kc, block_kv_mem, has_segs, window, interpret):
+    """The backward ``pallas_call`` of one geometry, built once a process
+    (as :func:`_fwd_call`, for its reason). ``dtypes``: q's, k's, v's."""
+    g_heads = h // hkv
+    block_q, block_kc, bkv_mem, nq, nkm = _bwd_blocks(
+        tq, tk, block_q, block_kc, block_kv_mem)
+
+    def q_block(ikm, iq, qoff_ref, kvoff_ref):
+        """The q-side block step (ikm, iq) reads: iq, or for a skipped
+        step the nearest q block an active step of this memory block
+        names."""
+        q_off, kv_off = offsets or (qoff_ref[0], kvoff_ref[0])
+        k_mem_first = kv_off + ikm * bkv_mem
+        return _block_range(
+            iq, k_mem_first if causal else None,
+            None if window is None else k_mem_first + bkv_mem - 1
+            + (window - 1), q_off, block_q, nq)
+
+    qspec = pl.BlockSpec((None, None, block_q, d),
+                         lambda b_, ikm, hq, iq, *offs:
+                         (b_, hq, q_block(ikm, iq, *offs), 0))
+    kspec = pl.BlockSpec((None, None, bkv_mem, d),
+                         lambda b_, ikm, hq, iq, *_: (b_, hq // g_heads,
+                                                      ikm, 0))
+    rowspec = pl.BlockSpec((None, None, 1, block_q),
+                           lambda b_, ikm, hq, iq, *offs:
+                           (b_, hq, 0, q_block(ikm, iq, *offs)))
+    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    if has_segs:
+        # bwd layout: q segs as a lane row (B, 1, L); kv segs
+        # sublane-broadcast (B, Lk, 128).
+        in_specs += [
+            pl.BlockSpec((None, 1, block_q),
+                         lambda b_, ikm, hq, iq, *offs:
+                         (b_, 0, q_block(ikm, iq, *offs))),
+            pl.BlockSpec((None, bkv_mem, 128),
+                         lambda b_, ikm, hq, iq, *_: (b_, ikm, 0)),
+        ]
+    # Static elision of the dead-row guard: with concrete offsets where the
+    # K/V shard starts at or before the q shard (the plain same-sequence
+    # call), every causal row sees at least one key. Traced offsets (ring
+    # attention) keep the guard.
+    may_have_dead = has_segs or not (
+        offsets is not None and (not causal or offsets[1] <= offsets[0]))
+    q_dtype, k_dtype, v_dtype = dtypes
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_fused_kernel, causal=causal, sm_scale=1.0,
+            block_q=block_q, block_kc=block_kc,
+            sub_k=_sub_tile(block_kc, block_q),
+            bkv_mem=bkv_mem, nq=nq, tk=tk, heads_per_kv=g_heads,
+            has_segs=has_segs, may_have_dead=may_have_dead, window=window),
+        name="hvd_flash_bwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                # the offsets, as forward
+            grid=(b, nkm, h, nq),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((None, None, None, block_q, d),
+                             lambda b_, ikm, hq, iq, *_:
+                             (ikm, b_, hq, iq, 0)),
+                kspec,
+                kspec,
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),        # dq acc
+                pltpu.VMEM((bkv_mem, d), jnp.float32),        # dk acc
+                pltpu.VMEM((bkv_mem, d), jnp.float32),        # dv acc
+            ]),
+        compiler_params=_BWD_SEMANTICS,
+        out_shape=[
+            # One memory block: the partial IS the result — emit in q's
+            # dtype. Several: keep partials fp32 so the cross-block sum
+            # rounds once, like the single-scratch accumulation it replaces.
+            jax.ShapeDtypeStruct((nkm, b, h, nq * block_q, d),
+                                 q_dtype if nkm == 1 else jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, nkm * bkv_mem, d), k_dtype),
+            jax.ShapeDtypeStruct((b, hkv, nkm * bkv_mem, d), v_dtype),
+        ],
+        interpret=interpret,
+    )
+
+
 def _flash_bwd(q, k, v, out, lse_c, g_out, qseg, kvseg, causal, sm_scale,
                q_offset, kv_offset, block_q, block_kc, block_kv_mem,
                interpret, g_lse=None, window=None):
     """Fused backward. ``lse_c``: compact (B, H, Tq) fp32 from the forward."""
     b, tq, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
-    g_heads = _check_gqa(h, hkv)
+    _check_gqa(h, hkv)
     has_segs = qseg is not None
-
-    block_q = min(block_q, tq)
-    block_kc = min(block_kc, tk)
-    # kv memory block: how much K/V sits VMEM-resident per grid step. The
-    # dq partial-sum dimension is ceil(Tk / block_kv_mem) — one memory
-    # block (a no-op reduction) whenever Tk fits.
-    bkv_mem = block_kc * max(1, min(block_kv_mem, tk) // block_kc)
-    nq = -(-tq // block_q)
-    nkm = -(-tk // bkv_mem)
+    call = _bwd_call(b, h, hkv, tq, tk, d, (q.dtype, k.dtype, v.dtype),
+                     causal, _static_offsets(q_offset, kv_offset), block_q,
+                     block_kc, block_kv_mem, has_segs, window, interpret)
+    block_q, block_kc, bkv_mem, nq, nkm = _bwd_blocks(
+        tq, tk, block_q, block_kc, block_kv_mem)
     pad_q = nq * block_q - tq
     pad_k = nkm * bkv_mem - tk
 
@@ -623,84 +867,18 @@ def _flash_bwd(q, k, v, out, lse_c, g_out, qseg, kvseg, causal, sm_scale,
     if pad_k:
         pads = ((0, 0), (0, 0), (0, pad_k), (0, 0))
         kT, vT = jnp.pad(kT, pads), jnp.pad(vT, pads)
-    lse_p = lse_p[:, :, None, :]                              # (B, H, 1, L)
-    di_p = di_p[:, :, None, :]
-
-    L = nq * block_q
-    Lk = nkm * bkv_mem
-    qb = qT.astype(jnp.bfloat16)
-    kb = kT.astype(jnp.bfloat16)
-    vb = vT.astype(jnp.bfloat16)
-    dob = doT.astype(jnp.bfloat16)
-
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    qspec = pl.BlockSpec((None, None, block_q, d),
-                         lambda b_, ikm, hq, iq: (b_, hq, iq, 0))
-    kspec = pl.BlockSpec((None, None, bkv_mem, d),
-                         lambda b_, ikm, hq, iq, g=g_heads:
-                         (b_, hq // g, ikm, 0))
-    rowspec = pl.BlockSpec((None, None, 1, block_q),
-                           lambda b_, ikm, hq, iq: (b_, hq, 0, iq))
-    in_specs = [smem, smem, qspec, kspec, kspec, qspec, rowspec, rowspec]
     args = [jnp.asarray([q_offset], jnp.int32),
             jnp.asarray([kv_offset], jnp.int32),
-            qb, kb, vb, dob, lse_p, di_p]
+            qT.astype(jnp.bfloat16), kT.astype(jnp.bfloat16),
+            vT.astype(jnp.bfloat16), doT.astype(jnp.bfloat16),
+            lse_p[:, :, None, :], di_p[:, :, None, :]]    # (B, H, 1, L)
     if has_segs:
-        # bwd layout: q segs as a lane row (B, 1, L); kv segs
-        # sublane-broadcast (B, Lk, 128).
         qseg_b = jnp.pad(qseg, ((0, 0), (0, pad_q)),
                          constant_values=-1)[:, None, :]
         kvseg_b = jnp.pad(kvseg, ((0, 0), (0, pad_k)), constant_values=-2)
-        kvseg_b = jnp.broadcast_to(kvseg_b[..., None],
-                                   kvseg_b.shape + (128,))
-        in_specs += [
-            pl.BlockSpec((None, 1, block_q),
-                         lambda b_, ikm, hq, iq: (b_, 0, iq)),
-            pl.BlockSpec((None, bkv_mem, 128),
-                         lambda b_, ikm, hq, iq: (b_, ikm, 0)),
-        ]
-        args += [qseg_b, kvseg_b]
-
-    # Static elision of the dead-row guard: with concrete offsets where the
-    # K/V shard starts at or before the q shard (the plain same-sequence
-    # call), every causal row sees at least one key. Traced offsets (ring
-    # attention) keep the guard.
-    concrete_offs = isinstance(q_offset, int) and isinstance(kv_offset, int)
-    may_have_dead = has_segs or not (
-        concrete_offs and (not causal or kv_offset <= q_offset))
-    kernel = functools.partial(
-        _bwd_fused_kernel, causal=causal, sm_scale=1.0,
-        block_q=block_q, block_kc=block_kc, bkv_mem=bkv_mem, nq=nq, tk=tk,
-        heads_per_kv=g_heads, has_segs=has_segs,
-        may_have_dead=may_have_dead, window=window)
-    dq_part, dk, dv = pl.pallas_call(
-        kernel,
-        name="hvd_flash_bwd",
-        grid=(b, nkm, h, nq),
-        compiler_params=_BWD_SEMANTICS,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, None, None, block_q, d),
-                         lambda b_, ikm, hq, iq: (ikm, b_, hq, iq, 0)),
-            kspec,
-            kspec,
-        ],
-        out_shape=[
-            # One memory block: the partial IS the result — emit in q's
-            # dtype. Several: keep partials fp32 so the cross-block sum
-            # rounds once, like the single-scratch accumulation it replaces.
-            jax.ShapeDtypeStruct((nkm, b, h, L, d),
-                                 q.dtype if nkm == 1 else jnp.float32),
-            jax.ShapeDtypeStruct(kT.shape, k.dtype),
-            jax.ShapeDtypeStruct(vT.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),            # dq acc
-            pltpu.VMEM((bkv_mem, d), jnp.float32),            # dk acc
-            pltpu.VMEM((bkv_mem, d), jnp.float32),            # dv acc
-        ],
-        interpret=interpret,
-    )(*args)
+        args += [qseg_b, jnp.broadcast_to(kvseg_b[..., None],
+                                          kvseg_b.shape + (128,))]
+    dq_part, dk, dv = call(*args)
 
     dq_sum = dq_part[0] if nkm == 1 else jnp.sum(dq_part, axis=0)
     # Residual √(scale·ln2) from the operand folding (the base-2 softmax
@@ -798,6 +976,50 @@ def _default_blocks(d, t, block_q, block_k, bwd_q, bwd_k, bwd_mem):
             (bwd_q or (256 if big else _BWD_BLOCK_Q)),
             (bwd_k or bwd_k_default),
             (bwd_mem or _BWD_BLOCK_KV_MEM))
+
+
+def score_counts(tq: int, tk: int, d: int, causal: bool = True,
+                 window: int | None = None, q_offset: int = 0,
+                 kv_offset: int = 0, block_q=None, block_k=None,
+                 block_q_bwd=None, block_k_bwd=None, block_kv_mem=None):
+    """``(visible, computed)``: the scores the mask leaves visible, and the
+    scores the kernels compute, over ONE forward and ONE backward call of
+    :func:`flash_attention` at these lengths and this head width, a
+    (batch, head) — static arithmetic (numpy), from the blocks
+    :func:`_default_blocks` resolves and the classification the kernels
+    themselves run (:func:`_block_visibility`, pair by pair and, in an
+    edge pair, sub-tile by sub-tile). ``visible`` counts each call's
+    mask once: ``computed / visible`` is 1 for a kernel that computes
+    nothing masked, 1.0624 at T = 8192 full causal with the 1024-wide
+    defaults (1.125 when edge pairs were computed whole). Segment ids are
+    data and are not counted (they mask more, and skip nothing)."""
+    bq, bk, bq_b, bk_b, bm = _default_blocks(
+        d, max(tq, tk), block_q, block_k, block_q_bwd, block_k_bwd,
+        block_kv_mem)
+    rows = q_offset + np.arange(tq, dtype=np.int64) - kv_offset
+    newest = np.minimum(rows, tk - 1) if causal else np.full(tq, tk - 1)
+    oldest = np.maximum(rows - (window - 1), 0) if window is not None else 0
+    visible = int(np.maximum(newest - oldest + 1, 0).sum())
+
+    def walked(block_q, sub_q, nq, block_k, sub_k, nk):
+        def classified(len_q, n_q, len_k, n_k):
+            return _block_visibility(
+                q_offset + np.arange(n_q)[:, None] * len_q, len_q,
+                kv_offset + np.arange(n_k)[None, :] * len_k, len_k,
+                kv_offset + tk, causal, window)
+        skip, interior = classified(block_q, nq, block_k, nk)
+        edge = ~(skip | interior)
+        rq, rk = block_q // sub_q, block_k // sub_k
+        sub_skip, _ = classified(sub_q, nq * rq, sub_k, nk * rk)
+        in_edge = np.repeat(np.repeat(edge, rq, axis=0), rk, axis=1)
+        return int(interior.sum()) * block_q * block_k \
+            + int((in_edge & ~sub_skip).sum()) * sub_q * sub_k
+
+    fq, fk, nq, nk = _fwd_blocks(tq, tk, bq, bk)
+    computed = walked(fq, _sub_tile(fq), nq, fk, _sub_tile(fk), nk)
+    gq, gk, mem, nq, nkm = _bwd_blocks(tq, tk, bq_b, bk_b, bm)
+    computed += walked(gq, gq, nq, gk, _sub_tile(gk, gq), nkm * mem // gk)
+    return 2 * visible, computed
 
 
 @functools.partial(jax.custom_vjp,

@@ -5,6 +5,7 @@ pieces it is built from: the per-position fused head, the exit
 distribution, weight sharing, the recomputation rule, and that a plain
 configuration still computes the old model."""
 
+import collections
 import importlib.util
 import json
 import os
@@ -303,6 +304,76 @@ def test_the_backward_reads_the_attention_kernels_output_back(
                 jax.tree.leaves(grads[other])):
             np.testing.assert_array_equal(
                 a, b, err_msg=other + jax.tree_util.keystr(path))
+
+
+def test_the_passes_scan_stacks_the_two_named_residuals_and_nothing_new(
+        kernel_attention):
+    """What crosses the ``nn.scan`` x ``nn.remat`` of the looped step,
+    with the kernels that follow the mask (PR 35) as before them: a
+    layer's input, its attention kernel's output and log-sum-exp — the two
+    names, once a layer —, the pass's norms; twelve float arrays stacked a
+    pass, no table, no integer."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    params = _tree(_weights(gate_std=20.0))
+    toks = jnp.asarray(_tokens(4, rows=2))
+    layers, heads = SMALL["num_hidden_layers"], SMALL["num_attention_heads"]
+    jaxpr = jax.make_jaxpr(jax.grad(transformer.make_loss_fn(
+        CFG, fused_head=True, exit_beta=BETA)))(params, toks).jaxpr
+    names = [eqn.params["name"] for eqn in equations(jaxpr)
+             if eqn.primitive.name == "name"]
+    assert sorted(names) == sorted(
+        [fa.OUT_RESIDUAL, fa.LSE_RESIDUAL] * layers)
+    forward = next(eqn for eqn in equations(jaxpr)
+                   if eqn.primitive.name == "scan"
+                   and eqn.params["length"] == SMALL["total_ut_steps"])
+    stacked = [v.aval for v in forward.outvars[forward.params["num_carry"]:]]
+    assert all(a.dtype == jnp.float32 for a in stacked)
+    shapes = [a.shape[1:] for a in stacked]
+    rows = toks.shape[0]
+    assert shapes.count((rows, T, heads, SMALL["head_dim"])) == layers
+    assert shapes.count((rows, heads, T)) == layers
+    assert len(stacked) == 12
+
+
+def test_the_looped_step_traces_each_kernel_once(monkeypatch,
+                                                 kernel_attention):
+    """Set-up's guard: flax's ``nn.scan`` traces its body several times
+    and ``nn.remat`` each block again, so a ``pallas_call`` built a call
+    site had its kernel's body traced three times a layer (24 times in the
+    looped cell, seconds of every start); built once a geometry
+    (``ops/flash_attention._fwd_call`` / ``_bwd_call``) it is traced once
+    or twice a process, whatever the model around it."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    traced = collections.Counter()
+
+    def counting(kernel):
+        def counted(*args, **kwargs):
+            traced[kernel.__name__] += 1
+            return kernel(*args, **kwargs)
+        return counted
+
+    monkeypatch.setattr(fa, "_fwd_kernel", counting(fa._fwd_kernel))
+    monkeypatch.setattr(fa, "_bwd_fused_kernel",
+                        counting(fa._bwd_fused_kernel))
+    fa._fwd_call.cache_clear()  # built anew, around the counting bodies
+    fa._bwd_call.cache_clear()
+    try:
+        params = _tree(_weights())
+        toks = jnp.asarray(_tokens(4, rows=2))
+        jaxpr = jax.make_jaxpr(jax.grad(transformer.make_loss_fn(
+            CFG, fused_head=True, exit_beta=BETA)))(params, toks).jaxpr
+    finally:
+        fa._fwd_call.cache_clear()  # nothing keeps the counting bodies
+        fa._bwd_call.cache_clear()
+    layers = SMALL["num_hidden_layers"]
+    assert kernel_calls(jaxpr) == {"hvd_flash_fwd": layers,
+                                   "hvd_flash_bwd": layers}
+    # (the forward's once more where JAX keys its trace cache apart: the
+    # primal's call beside the VJP rule's; never once a call site)
+    assert traced["_bwd_fused_kernel"] == 1
+    assert 1 <= traced["_fwd_kernel"] <= 2 < 3 * layers
 
 
 def test_a_stack_run_once_has_no_checkpoint_and_keeps_nothing_by_name(

@@ -238,6 +238,35 @@ def test_a_looped_steps_record_counts_the_kept_attention_outputs(
         "model.attention_layers": 6 - convs, "model.conv_layers": convs,
         "model.kept_attention_outputs": kept,
         "model.head_applications": 1}
+    # The kernels' scores (PR 35), a forward and a backward call an
+    # attention block application, a row and head: T = 16 is one block,
+    # computed whole (256 scores a call) for 136 visible.
+    calls = (6 - convs) * toks.shape[1] * CFG.num_heads
+    assert {k: v for k, v in counters.items() if k.startswith("flash.")} == (
+        {"flash.scores_visible": calls * 2 * 136,
+         "flash.scores_computed": calls * 2 * 256} if impl == "flash" else {})
+
+
+@pytest.mark.parametrize("window", [None, 5])
+def test_a_plain_steps_record_counts_the_kernels_scores(monkeypatch, window):
+    """``flash.scores_visible`` is the mask counted pair by pair, twice (a
+    forward and a backward call), over the step's block applications,
+    rows and heads; ``flash.scores_computed`` what
+    ``ops/flash_attention.score_counts`` walks."""
+    from horovod_tpu.ops import flash_attention
+
+    monkeypatch.setattr(sequence, "local_attention_impl", lambda t: "flash")
+    _world4()
+    step, ps, ss, toks, _ = _lm_step(cfg=CFG._replace(window=window))
+    step(ps, ss, toks)
+    counters = timeline.record()["programs"][TAG]["counters"]
+    hvd.shutdown()
+    t = toks.shape[-1]
+    seen = sum(min(p + 1, window or t) for p in range(t))
+    calls = CFG.num_layers * toks.shape[1] * CFG.num_heads
+    assert counters["flash.scores_visible"] == calls * 2 * seen
+    assert counters["flash.scores_computed"] == calls * \
+        flash_attention.score_counts(t, t, 16, window=window)[1]
 
 
 def test_an_expert_layer_steps_record_counts_its_row_block():

@@ -1,6 +1,6 @@
 """Compile a benchmark cell's training step for v5e WITHOUT a chip and say
 what came out: ``python tools/aot_step.py <cell> [<cell> ...] [--root DIR]
-[--text FILE]``.
+[--text FILE] [--lowered]``.
 
 The step is the one the cell's runner builds — ``hvd.init`` →
 ``hvd.DistributedOptimizer(ops/optim.adamw)`` → ``hvd.spmd`` around the
@@ -13,7 +13,11 @@ md5 of the text less what names a checkout and not the program
 (``metadata=``, the location tables, the Pallas bodies) — two trees whose
 digests agree run the same instructions. ``--root`` takes the program and
 the benchmark from another checkout (the parent's, unpacked beside this
-one); ``--text`` writes the last cell's stripped text there. Sizes and
+one); ``--text`` writes the last cell's text there (stripped; whole with
+``--lowered``). ``--lowered``
+stops before the compiler: the seconds tracing and lowering took here and
+an md5 of the WHOLE lowered text, which is what the compile cache's key is
+made of — two processes of one tree must print the same. Sizes and
 instructions only: a time comes from the chip.
 """
 
@@ -26,6 +30,7 @@ import json
 import os
 import re
 import sys
+import time
 
 
 def _without(text: str, opener: str) -> str:
@@ -77,7 +82,7 @@ def model_config(runner, cfg: dict):
         attention="local", window=cfg["sliding_window"])
 
 
-def compile_cell(root: str, name: str):
+def lower_cell(root: str, name: str):
     import jax
     import jax.numpy as jnp
     import optax
@@ -129,10 +134,10 @@ def compile_cell(root: str, name: str):
     state = jax.tree.map(stacked, jax.eval_shape(opt.init, shapes))
     tokens = stacked(jax.ShapeDtypeStruct(
         (traffic["batch_per_chip"], traffic["seq_len"]), jnp.int32))
-    compiled = hvd.spmd(train_step, donate_argnums=(0, 1)).lower(
-        params, state, tokens).compile()
+    lowered = hvd.spmd(train_step, donate_argnums=(0, 1)).lower(
+        params, state, tokens)
     hvd.shutdown()
-    return compiled
+    return lowered
 
 
 def main():
@@ -141,12 +146,23 @@ def main():
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     ap.add_argument("--text")
+    ap.add_argument("--lowered", action="store_true")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     sys.path.insert(0, root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     for name in args.cells:
-        compiled = compile_cell(root, name)
+        t0 = time.perf_counter()
+        lowered = lower_cell(root, name)
+        if args.lowered:
+            text = lowered.as_text()
+            print(json.dumps({
+                "cell": name, "root": root,
+                "trace_and_lower_s": round(time.perf_counter() - t0, 1),
+                "lowered_md5": hashlib.md5(text.encode()).hexdigest()}),
+                flush=True)
+            continue
+        compiled = lowered.compile()
         text = stripped(compiled.as_text())
         mem = compiled.memory_analysis()
         gb = lambda b: round(b / 1e9, 3)
