@@ -17,6 +17,10 @@ Public API parity map (reference → here):
   → :mod:`horovod_tpu.core.timeline`, ``HOROVOD_TIMELINE`` etc.
 """
 
+import time as _time
+
+_t0 = _time.perf_counter_ns()  # the record's ``hvd/import`` row starts here
+
 from horovod_tpu.utils.env import apply_platform_overrides as _apply_env
 
 _apply_env()  # HOROVOD_CPU_DEVICES=N: simulated pod, before any backend exists
@@ -103,6 +107,10 @@ from horovod_tpu import training  # noqa: E402
 # (keras/callbacks.py; used as hvd.callbacks.BroadcastGlobalVariablesCallback
 # in examples/keras_mnist.py:71-75).
 from horovod_tpu.training import callbacks  # noqa: E402
+
+from horovod_tpu.core import timeline as _timeline  # noqa: E402
+
+_timeline.session().imported(_t0, _time.perf_counter_ns())  # and ends here
 
 __all__ = [
     "AXIS_NAME",

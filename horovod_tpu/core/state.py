@@ -156,6 +156,7 @@ def init(group_ranks: Sequence[Sequence[int]] | None = None,
     # A new world starts a new record (the last one stayed readable
     # after shutdown); its first span is the program's share of set-up.
     _timeline.session().clear_record()
+    _timeline.listen()
     with _timeline.span("hvd/init"):
         _init(group_ranks, devices)
 

@@ -32,9 +32,25 @@ File or no file, the same session keeps the program's account of itself:
 :func:`span` (host spans on the profiler's clock, in a bounded in-memory
 ring, B/E pairs on the ``_hvd`` row), and per compiled ``hvd.spmd``
 program its dispatches, its exchange plan's counters and — lazily, never
-by compiling — its instructions' named scopes.
+by compiling — its instructions' named scopes and its executable's
+memory analysis.
 :func:`record` snapshots it: readable after ``hvd.shutdown()``, cleared
 by the next ``hvd.init()``.
+
+What JAX says of its own work (:func:`listen`: ``jax.monitoring``'s
+tracing, lowering and backend-compile durations and the persistent
+cache's hits and misses) is kept in the same record. Inside a
+``hvd/spmd/build`` span each event is a child row of that span —
+``hvd/spmd/build/trace``, ``/lower``, ``/compile`` where the backend
+compiled and ``/load`` where the persistent cache hit; what is left of
+the span is the first call, which has no row — and a count of the built
+program (``build.cache_hits``, ``build.cache_misses``). Outside any build
+the events are only summed, under the record's ``compiles``: seconds by
+kind, a count of programs (backend events) and the stamp of the last of
+them, apart for before the session's first
+dispatch, after it, and after it inside a ``hvd/spmd/dispatch`` span —
+a program ``jax.jit`` compiled again under a key the wrapper took for
+built.
 """
 
 from __future__ import annotations
@@ -44,14 +60,23 @@ import collections
 import json
 import threading
 import time
+import traceback
 import weakref
 
 import jax
+from jax import monitoring as _monitoring
 
 from horovod_tpu.utils import env as _env
 
 RING_SPANS = 4096  # newest spans kept once the first step was dispatched
 SPAN_ROW = "_hvd"  # the Chrome file's row of span() pairs
+BUILD, DISPATCH = "hvd/spmd/build", "hvd/spmd/dispatch"
+# jax.monitoring's duration events, by the part of a build they are.
+_PARTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+          "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+          "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE = {"/jax/compilation_cache/cache_hits": "build.cache_hits",
+          "/jax/compilation_cache/cache_misses": "build.cache_misses"}
 
 
 class _ChromeTraceWriter:
@@ -170,6 +195,8 @@ class Timeline:
         # sampled ``jax.profiler`` capture with device timestamps.
         self.device_mode = False
         self._local = threading.local()
+        self._import: tuple | None = None  # the process's: never cleared
+        self.hears = False  # JAX's events, from hvd.init to hvd.shutdown
         self.clear_record()
 
     def clear_record(self) -> None:
@@ -179,7 +206,18 @@ class Timeline:
         self.dispatched = False  # set by hvd.spmd's first dispatch
         self.programs: dict = {}
         self.building: str | None = None  # the program being traced
-        self._texts: dict = {}  # tag → weak or pinned owner of hlo_text()
+        self._owners: dict = {}  # tag → weak or pinned owner of executable()
+        # JAX's work outside any build span, summed by where it fell.
+        self.compiles = {where: {"programs": 0, "trace_s": 0.0,
+                                 "lower_s": 0.0, "backend_s": 0.0,
+                                 "last_ns": 0}
+                         for where in ("before_dispatch", "after_dispatch",
+                                       "in_dispatch")}
+
+    def imported(self, start_ns: int, end_ns: int) -> None:
+        """The ``hvd/import`` row: ``import horovod_tpu`` from its first
+        line to its last."""
+        self._import = ("hvd/import", start_ns, end_ns, None)
 
     def _stack(self) -> list:  # the open spans of this thread
         return self._local.__dict__.setdefault("stack", [])
@@ -211,54 +249,124 @@ class Timeline:
             step["counters"][name] = value
 
     def add_program(self, tag: str, owner) -> str:
-        """Open the record of one compiled program; ``owner.hlo_text()``
-        gives its optimized text or None (held weakly until a dispatch of
-        it is profiled: :meth:`pin`). Returns the tag made unique."""
+        """Open the record of one compiled program; ``owner.executable()``
+        gives what it was compiled to or None (held weakly until a
+        dispatch of it is profiled: :meth:`pin`). Returns the tag made
+        unique."""
         base, k = tag, 1
         while tag in self.programs:
             k += 1
             tag = f"{base}#{k}"
-        self.programs[tag] = {"dispatches": 0, "counters": {},
-                              "scopes": None}
-        self._texts[tag] = weakref.ref(owner)
+        self.programs[tag] = {
+            "dispatches": 0, "scopes": None, "memory": None,
+            "counters": {"build.cache_hits": 0, "build.cache_misses": 0}}
+        self._owners[tag] = weakref.ref(owner)
         return tag
 
     def pin(self, tag: str, owner) -> None:
         """A capture holds events of this program: keep it until its
         scope map is resolved (at ``hvd.shutdown()`` at the latest)."""
-        if tag in self._texts:
-            self._texts[tag] = lambda: owner
+        if tag in self._owners:
+            self._owners[tag] = lambda: owner
 
     def resolve_scopes(self, shutdown: bool = False) -> None:
         """Fill ``programs[tag]["scopes"]`` from the programs' compiled
-        text (``analysis/hlo.scope_map``). Never traces or compiles: a
-        program JAX's caches no longer hold keeps None. At ``shutdown``
-        only pinned programs are read, and all are let go."""
+        text (``analysis/hlo.scope_map``) and ``["memory"]`` from the
+        executable's own memory analysis (bytes a device: arguments,
+        outputs, the part of them aliased, XLA's temporaries, generated
+        code). Never traces or compiles: a program JAX's caches no longer
+        hold keeps None. At ``shutdown`` only pinned programs are read,
+        and all are let go."""
         from horovod_tpu.analysis import hlo as _hlo
 
-        for tag, ref in list(self._texts.items()):
+        for tag, ref in list(self._owners.items()):
             owner = ref()
             if owner is None or (shutdown and isinstance(ref, weakref.ref)):
                 continue
-            text = owner.hlo_text()
-            if text is not None:
-                self.programs[tag]["scopes"] = _hlo.scope_map(text)
-            del self._texts[tag]
+            del self._owners[tag]
+            try:
+                exe = owner.executable()
+                if exe is None:
+                    continue
+                entry = self.programs[tag]
+                entry["scopes"] = _hlo.scope_map(exe.as_text())
+                m = exe.memory_analysis()  # None where the backend has none
+                entry["memory"] = m and {
+                    f"{k}_bytes": getattr(m, f"{k}_size_in_bytes")
+                    for k in ("argument", "output", "alias", "temp",
+                              "generated_code")}
+            except Exception:  # the record is best effort: shutdown goes on
+                traceback.print_exc()
         if shutdown:
-            self._texts.clear()
+            self._owners.clear()
+
+    def jax_event(self, part: str, seconds: float) -> None:
+        """One of JAX's ``_PARTS`` ended on this thread after
+        ``seconds``. Inside a build span it is a child row of that span
+        (a tracing nested in another is dropped when the outer one
+        arrives: they come inner first); elsewhere it is summed."""
+        end = time.perf_counter_ns()
+        start = end - int(seconds * 1e9)
+        local = self._local.__dict__
+        if part == "compile" and local.pop("cache_hit", False):
+            part = "load"
+        stack = self._stack()
+        if BUILD in stack:
+            name = f"{BUILD}/{part}"
+            rows = self._ring if self.dispatched else self._setup
+            i = len(rows)
+            while i and rows[i - 1][1] >= start:
+                i -= 1
+                if rows[i][0] == name:
+                    del rows[i]
+            if self.dispatched or len(rows) < RING_SPANS:
+                rows.append((name, start, end, BUILD))
+            self.event_at(SPAN_ROW, name,
+                          time.monotonic_ns() / 1e3 - seconds * 1e6,
+                          seconds * 1e6)
+            return
+        sums = self.compiles[
+            "in_dispatch" if stack and stack[-1] == DISPATCH else
+            "after_dispatch" if self.dispatched else "before_dispatch"]
+        if part == "trace":
+            # (start, seconds) of the tracings summed so far that a later,
+            # outer one may still hold: it takes their seconds back.
+            summed = local.setdefault("traces", [])
+            whole = seconds
+            while summed and summed[-1][0] >= start:
+                seconds -= summed.pop()[1]
+            summed.append((start, whole))
+            del summed[:-64]  # deeper nestings than this are not met
+        elif part != "lower":  # one backend event a program
+            sums["programs"], sums["last_ns"] = sums["programs"] + 1, end
+        key = "backend_s" if part in ("compile", "load") else f"{part}_s"
+        sums[key] += seconds
+
+    def cache_event(self, counter: str) -> None:
+        """The persistent cache hit or missed on this thread: the backend
+        event that follows a hit is a load; the program being built
+        counts both."""
+        if counter == "build.cache_hits":
+            self._local.cache_hit = True
+        if BUILD in self._stack():
+            self.count_plan(counter, 1)
 
     def record(self, scopes: bool = False) -> dict:
-        """A plain, JSON-able snapshot: ``spans`` (set-up's, then the
-        newest ``RING_SPANS``) and ``programs`` (each with its
-        ``dispatches``, its plan's ``counters`` and its ``scopes``).
-        ``scopes`` resolves the live programs' scope maps first; else they
-        hold what ``hvd.shutdown()`` resolved (profiled programs only) or
-        None."""
+        """A plain, JSON-able snapshot: ``spans`` (``hvd/import``,
+        set-up's, then the newest ``RING_SPANS``), ``programs`` (each with
+        its ``dispatches``, its ``counters`` — the plan's and the
+        build's —, its ``scopes`` and its ``memory``) and ``compiles``
+        (JAX's work outside any build). ``scopes`` resolves the live
+        programs' scope maps and memory first; else they hold what
+        ``hvd.shutdown()`` resolved (profiled programs only) or None."""
         if scopes:
             self.resolve_scopes()
-        return {"spans": [list(s) for s in self._setup + list(self._ring)],
+        spans = [self._import] if self._import else []
+        return {"spans": [list(s) for s in
+                          spans + self._setup + list(self._ring)],
                 "programs": {t: dict(p, counters=dict(p["counters"]))
-                             for t, p in self.programs.items()}}
+                             for t, p in self.programs.items()},
+                "compiles": {w: dict(s) for w, s in self.compiles.items()}}
 
     def start(self, path: str) -> None:
         if self.active:
@@ -298,6 +406,7 @@ class Timeline:
             writer.event_at(tensor, activity, ts_us, dur_us)
 
     def stop(self) -> None:
+        self.hears = False
         writer, self._writer = self._writer, None
         if writer is not None:
             writer.close()
@@ -308,6 +417,34 @@ _session = Timeline()
 
 def session() -> Timeline:
     return _session
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    part = _PARTS.get(event)
+    if part is not None and _session.hears:
+        _session.jax_event(part, seconds)
+
+
+def _on_event(event: str, **_kw) -> None:
+    counter = _CACHE.get(event)
+    if counter is not None and _session.hears:
+        _session.cache_event(counter)
+
+
+_listening = False
+
+
+def listen() -> None:
+    """Hear ``jax.monitoring``'s compile events into the session's
+    record until ``hvd.shutdown()``: one listener a process on its
+    durations and one on its plain events, however many times
+    ``hvd.init`` runs."""
+    global _listening
+    if not _listening:
+        _listening = True
+        _monitoring.register_event_duration_secs_listener(_on_duration)
+        _monitoring.register_event_listener(_on_event)
+    _session.hears = True
 
 
 def maybe_start() -> None:

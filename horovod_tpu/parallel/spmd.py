@@ -124,7 +124,7 @@ def spmd(fn: Callable, group: int = 0,
 
     def compile_program(g, args, tl, multihost) -> _Program:
         """Build the program of this argument signature by one of the
-        four compile paths (inside the caller's ``hvd/spmd/build`` span,
+        three compile paths (inside the caller's ``hvd/spmd/build`` span,
         which also holds its first call: the lazy path compiles there)."""
         jitted, schedule = build(g, len(args))
         prog = _Program(jitted, schedule, args)
@@ -143,20 +143,9 @@ def spmd(fn: Callable, group: int = 0,
             lowered = jitted.lower(*args)
             _mh.negotiator().validate_schedule(prog.tag, schedule)
             prog.call = lowered.compile(**copts)
-        elif tl.active:
-            # With the timeline on, compile explicitly so the trace-time
-            # schedule exists BEFORE the first execution — negotiation
-            # and compilation become visible timeline spans (the analog
-            # of the reference's per-step NEGOTIATE_* phases, hoisted to
-            # compile time like the negotiation itself).
-            prog_row = f"_program/{prog.tag}"
-            tl.start_activity(prog_row, "TRACE_AND_COMPILE")
-            prog.call = jitted.lower(*args).compile(**copts)
-            tl.end_activity(prog_row, "TRACE_AND_COMPILE")
-            for nm, op, *_ in schedule:
-                tl.start_activity(nm, f"NEGOTIATE_{op}")
-                tl.end_activity(nm, f"NEGOTIATE_{op}")
-        elif xla_opts:
+        elif xla_opts or (tl.active and tl.device_mode):
+            # The device-fidelity timeline samples the FIRST execution
+            # against the trace-time schedule: it must exist before it.
             prog.call = jitted.lower(*args).compile(**copts)
         return prog
 
@@ -212,7 +201,15 @@ def spmd(fn: Callable, group: int = 0,
             try:
                 prog = compiled[key] = compile_program(g, args, tl,
                                                        _mh.active())
-                return dispatch(prog, args, tl)
+                out = dispatch(prog, args, tl)
+                # Every collective the trace negotiated, on its own row
+                # of the timeline file (the reference's per-step
+                # NEGOTIATE_* phases, hoisted to compile time like the
+                # negotiation itself).
+                for nm, op, *_ in prog.schedule:
+                    tl.start_activity(nm, f"NEGOTIATE_{op}")
+                    tl.end_activity(nm, f"NEGOTIATE_{op}")
+                return out
             finally:
                 tl.building = None
 
@@ -256,22 +253,17 @@ class _Program:
                 weak_type=getattr(a, "weak_type", False))
             if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
 
-    def hlo_text(self) -> str | None:
-        """The optimized program's text, from the executable the first
-        call built (``lower`` and ``compile`` on the first call's shapes
-        and shardings are two cache hits); None where JAX's caches no
-        longer hold it and asking would trace and compile anew."""
-        try:
-            if self.call is not self.jitted:
-                return self.call.as_text()
-            if not _compat.jit_cache_size(self.jitted):
-                return None
-            return self.jitted.lower(*self.structs).compile().as_text()
-        except Exception:  # the record is best effort: shutdown goes on
-            import traceback
-
-            traceback.print_exc()
+    def executable(self) -> jax.stages.Compiled | None:
+        """What the first call built, to read its optimized text and its
+        memory analysis from (``lower`` and ``compile`` on the first
+        call's shapes and shardings are two cache hits); None where JAX's
+        caches no longer hold it and asking would trace and compile
+        anew."""
+        if self.call is not self.jitted:
+            return self.call
+        if not _compat.jit_cache_size(self.jitted):
             return None
+        return self.jitted.lower(*self.structs).compile()
 
 
 def _sample_device_step(tl, prog, args):

@@ -262,7 +262,7 @@ class TestTimelineEndToEnd:
         """A Trainer.fit run under HOROVOD_TIMELINE shows one B/E
         ``hvd/spmd/dispatch`` pair per training step on the ``_hvd`` row
         (the per-step row; per-collective truth is device mode's) plus
-        trace-time NEGOTIATE rows and the program-compile span — and the
+        trace-time NEGOTIATE rows and the parts of each build — and the
         timeline waits for no step: turning it on must not remove the
         pipelining it is there to show."""
         import json
@@ -318,11 +318,14 @@ class TestTimelineEndToEnd:
         assert ar_pids, f"no allreduce rows in {sorted(procs.values())}"
         assert not [e for e in events if e["name"] == "XLA_ALLREDUCE"]
         assert any(e["name"] == "NEGOTIATE_ALLREDUCE" for e in events)
-        assert any(nm.startswith("_program/") for nm in procs.values())
         # One B/E dispatch pair a call of a compiled program, properly
         # nested inside its program's build on the first call.
         hvd_pid = next(pid for pid, nm in procs.items() if nm == "_hvd")
         row = [e for e in events if e["pid"] == hvd_pid]
+        # Each build's parts, as JAX timed them: complete events there.
+        for part in ("trace", "lower", "compile"):
+            assert len([e for e in row if e["ph"] == "X" and e["name"]
+                        == f"hvd/spmd/build/{part}"]) >= 2
         for ph in "BE":
             assert len([e for e in row if e["ph"] == ph
                         and e["name"] == "hvd/spmd/dispatch"]) >= 2 * n_steps
@@ -476,12 +479,12 @@ class TestXprofSpanMapping:
         # exactly one sample: the marker appears once, not once per step
         assert len([e for e in events if e["name"] == "NO_DEVICE_PLANE"]) \
             == 1
-        # Trace-time negotiation rows + the compile span are present.
+        # Trace-time negotiation rows + the build's parts are present.
         assert any(e["name"] == "NEGOTIATE_ALLREDUCE" for e in events)
-        prog_rows = [nm for nm in procs.values()
-                     if nm.startswith("_program/")]
-        assert prog_rows, "missing _program compile row"
-        assert any(e["name"] == "TRACE_AND_COMPILE" for e in events)
+        hvd_pid = next(p for p, nm in procs.items() if nm == "_hvd")
+        assert {"hvd/spmd/build/trace", "hvd/spmd/build/lower",
+                "hvd/spmd/build/compile"} <= {
+                    e["name"] for e in events if e["pid"] == hvd_pid}
 
     def test_device_mode_interval_resamples(self, tmp_path):
         """HOROVOD_TIMELINE_DEVICE_INTERVAL=2: executions 0, 2 and 4 of

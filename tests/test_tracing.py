@@ -79,8 +79,8 @@ def compiled_texts(request):
                 texts[built] = step.lower(ps, ss, toks).compile().as_text()
             else:  # as a run reads it: from the executable the call built
                 step(ps, ss, toks)
-                texts[built] = spmd_mod._Program.hlo_text(
-                    timeline.session()._texts[TAG]())
+                texts[built] = timeline.session()._owners[
+                    TAG]().executable().as_text()
             hvd.shutdown()
     finally:
         jax.config.update("jax_include_full_tracebacks_in_locations", True)
@@ -144,7 +144,7 @@ def test_record_after_shutdown_holds_spans_and_programs():
     assert names["hvd/replicate"] == 2 and names["hvd/rank_stack"] == 1
     assert names["hvd/spmd/build"] == 2
     assert names["hvd/spmd/dispatch"] == n + 1
-    assert sorted(rec) == ["programs", "spans"]
+    assert sorted(rec) == ["compiles", "programs", "spans"]
     # the first call of a program lies inside its build
     parents = [s[3] for s in rec["spans"] if s[0] == "hvd/spmd/dispatch"]
     assert parents == ["hvd/spmd/build"] + [None] * (n - 1) + [
@@ -156,7 +156,8 @@ def test_record_after_shutdown_holds_spans_and_programs():
 
     json.dumps(rec)  # plain data
     hvd.init()  # the next world starts a new record
-    assert [s[0] for s in timeline.record()["spans"]] == ["hvd/init"]
+    assert [s[0] for s in timeline.record()["spans"]] == [
+        "hvd/import", "hvd/init"]
     hvd.shutdown()
 
 
@@ -182,7 +183,9 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
         "model.block_applications": 2, "model.recomputed_blocks": 0,
         "model.kept_attention_outputs": 0, "model.head_applications": 1,
         # ... all of them attention (no ``layer_types``: PR 33)
-        "model.attention_layers": 2, "model.conv_layers": 0}
+        "model.attention_layers": 2, "model.conv_layers": 0,
+        # the build's own: no persistent cache here, so neither
+        "build.cache_hits": 0, "build.cache_misses": 0}
 
 
 def test_exchange_async_bytes_are_the_leaves_that_go_round_the_ring(
@@ -394,10 +397,17 @@ def test_scope_map_resolves_without_compiling(compile_events):
     ps, ss, _ = step(ps, ss, toks)
     assert compile_events  # the first call did compile
     del compile_events[:]
-    scopes = timeline.record(scopes=True)["programs"][TAG][
-        "scopes"]
+    program = timeline.record(scopes=True)["programs"][TAG]
+    scopes = program["scopes"]
     hvd.shutdown()
     assert compile_events == []
+    # ... and with the text the executable's own memory analysis
+    assert sorted(program["memory"]) == [
+        "alias_bytes", "argument_bytes", "generated_code_bytes",
+        "output_bytes", "temp_bytes"]
+    assert all(isinstance(v, int) for v in program["memory"].values())
+    nbytes = sum(l.nbytes for l in jax.tree.leaves((ps, ss, toks))) // 4
+    assert program["memory"]["argument_bytes"] == nbytes  # a device's
     phases = collections.Counter()
     for op_name, members in scopes.values():
         for name in members or [op_name]:
@@ -415,11 +425,218 @@ def test_scope_map_resolves_without_compiling(compile_events):
     assert any(n.endswith("/shard_map/psum") for n in names)
 
 
+def _children(rec, part=None):
+    """The rows JAX's events made inside build spans."""
+    return [r for r in rec["spans"] if r[0].startswith("hvd/spmd/build/")
+            and (part is None or r[0].endswith("/" + part))]
+
+
+def test_a_build_span_holds_its_parts_as_child_rows():
+    """Tracing, lowering and the backend's compile of one ``hvd.spmd``
+    program, as JAX's own events time them: rows of the record whose
+    parent is the build span and which lie inside it; a second call of
+    the same signature builds nothing and adds none."""
+    import json
+
+    _world4()
+    step, ps, ss, toks, _ = _lm_step()
+    ps, ss, _ = step(ps, ss, toks)
+    rec = timeline.record()
+    (build,) = [r for r in rec["spans"] if r[0] == "hvd/spmd/build"]
+    kids = _children(rec)
+    assert {r[0].rsplit("/", 1)[1] for r in kids} == {
+        "trace", "lower", "compile"}  # no persistent cache: no load
+    for name, start, end, parent in kids:
+        assert parent == "hvd/spmd/build"
+        assert build[1] <= start <= end <= build[2]
+    # the tracings nested in the step's own are dropped when it arrives
+    assert len([r for r in kids if r[0].endswith("/trace")]) < 8
+    assert sum(r[2] - r[1] for r in kids) <= build[2] - build[1]
+    ps, ss, _ = step(ps, ss, toks)
+    again = timeline.record()
+    assert _children(again) == _children(rec)
+    json.dumps(again)  # plain data still
+    hvd.shutdown()
+
+
+def test_a_tracing_nested_in_another_counts_once():
+    """JAX reports a nested tracing before the one that holds it: the
+    row (in a build) or the seconds (outside) of the inner one go when
+    the outer one arrives."""
+    tl = timeline.Timeline()
+    with tl.span("hvd/spmd/build"):
+        tl.jax_event("trace", 0.001)
+        tl.jax_event("trace", 0.002)
+        tl.jax_event("lower", 0.0005)
+        tl.jax_event("trace", 0.5)  # holds the two tracings before it
+    rows = _children(tl.record())
+    assert [r[0].rsplit("/", 1)[1] for r in rows] == ["lower", "trace"]
+    assert rows[1][2] - rows[1][1] == 500_000_000
+    tl.jax_event("trace", 0.001)
+    tl.jax_event("trace", 0.25)
+    tl.jax_event("trace", 0.5)
+    sums = tl.record()["compiles"]["before_dispatch"]
+    assert sums["trace_s"] == pytest.approx(0.5) and sums["programs"] == 0
+
+
+def test_a_jit_outside_any_build_is_summed_and_makes_no_row():
+    """The small programs of a run (weights, placement, readings) are
+    seconds by kind and a count, before and after the first dispatch."""
+    _world4()
+    a, b, ones = jnp.arange(5.0), jnp.arange(7.0), hvd.replicate(jnp.ones(3))
+    before = timeline.record()
+    jax.jit(lambda x: x * 3 + 1)(a)
+    rec = timeline.record()
+    assert len(rec["spans"]) == len(before["spans"])
+    sums = rec["compiles"]["before_dispatch"]
+    was = before["compiles"]["before_dispatch"]
+    assert sums["programs"] == was["programs"] + 1
+    assert all(sums[k] > was[k] for k in ("trace_s", "lower_s", "backend_s"))
+    assert rec["compiles"]["after_dispatch"]["programs"] == 0
+    hvd.spmd(lambda x: hvd.allreduce(x))(ones)
+    rows = len(timeline.record()["spans"])
+    jax.jit(lambda x: x * 5 - 1)(b)
+    rec = timeline.record()
+    assert len(rec["spans"]) == rows
+    assert rec["compiles"]["before_dispatch"] == sums
+    late = rec["compiles"]["after_dispatch"]
+    assert late["programs"] == 1 and late["backend_s"] > 0
+    assert late["last_ns"] > sums["last_ns"]
+    assert rec["compiles"]["in_dispatch"]["programs"] == 0
+    hvd.shutdown()
+
+
+@pytest.mark.parametrize("change", [
+    lambda x: jax.device_put(x, jax.sharding.NamedSharding(
+        hvd.get_group(0).mesh, jax.sharding.PartitionSpec())),
+    lambda x: np.asarray(x),
+    lambda x: jnp.broadcast_to(jnp.asarray(2.0), x.shape),
+], ids=["sharding", "uncommitted", "weak_type"])
+def test_a_recompile_under_an_unchanged_signature_is_counted(change):
+    """``hvd.spmd`` keys its programs by shape and dtype; ``jax.jit``
+    compiles again for what else it keys on. No build span sees that
+    compile: it is counted as one inside a dispatch."""
+    _world4()
+    step = hvd.spmd(lambda x: hvd.allreduce(x) * 2.0)
+    x = hvd.replicate(jnp.ones((8,)))
+    step(x)
+    step(x)
+    assert timeline.record()["compiles"]["in_dispatch"]["programs"] == 0
+    other = change(x)
+    assert spmd_mod._args_signature((other,)) == spmd_mod._args_signature(
+        (x,))
+    first = [s for s in timeline.record()["spans"]
+             if s[0] == "hvd/spmd/dispatch"][0][1]
+    step(other)
+    rec = timeline.record()
+    hidden = rec["compiles"]["in_dispatch"]
+    assert hidden["programs"] >= 1 and hidden["backend_s"] > 0
+    assert hidden["last_ns"] > first
+    # what ``lm_window_builds`` counts saw nothing
+    assert not [s for s in rec["spans"]
+                if s[0] == "hvd/spmd/build" and s[1] > first]
+    assert _count(rec, "hvd/spmd/build") == 1
+    hvd.shutdown()
+
+
+def test_init_shutdown_cycles_leave_one_listener_and_keep_the_import():
+    from jax._src import monitoring
+
+    def ours():
+        return ([l for l in monitoring.get_event_duration_listeners()
+                 if l is timeline._on_duration],
+                [l for l in monitoring.get_event_listeners()
+                 if l is timeline._on_event])
+
+    rows = []
+    for _ in range(2):
+        _world4()
+        rows.append(timeline.record()["spans"][0])
+        assert [len(l) for l in ours()] == [1, 1]
+        hvd.shutdown()
+        # the record is closed: what JAX does now is not this world's
+        closed = timeline.record()["compiles"]
+        jax.jit(lambda x: x - 11)(jnp.arange(3.0))
+        assert timeline.record()["compiles"] == closed
+    assert rows[0] == rows[1] and rows[0][0] == "hvd/import"
+    assert rows[0][3] is None and 0 < rows[0][1] < rows[0][2]
+
+
+def test_a_warm_persistent_cache_makes_the_build_a_load(tmp_path):
+    """With JAX's persistent cache on, a program's first build is a miss
+    and a ``compile`` row; the same program built by the next world is a
+    hit and a ``load`` row, each counted on the program built."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        seen = []
+        for _ in range(2):
+            _world4()
+            x = hvd.replicate(jnp.ones((16,), jnp.float32))
+            jax.clear_caches()
+            hvd.spmd(lambda x: hvd.allreduce(x) * 7.0 + 3.0)(x)
+            rec = timeline.record()
+            (tag,) = [t for t in rec["programs"] if "cache_makes" in t]
+            build = [r for r in rec["spans"]
+                     if r[0] == "hvd/spmd/build"][-1]
+            seen.append((
+                {r[0].rsplit("/", 1)[1] for r in _children(rec)
+                 if r[1] >= build[1]},
+                rec["programs"][tag]["counters"]))
+            hvd.shutdown()
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert seen[0][0] == {"trace", "lower", "compile"}
+    built = [{k: v for k, v in c.items() if k.startswith("build.")}
+             for _, c in seen]
+    assert built[0] == {"build.cache_hits": 0, "build.cache_misses": 1}
+    assert seen[1][0] == {"trace", "lower", "load"}
+    assert built[1] == {"build.cache_hits": 1, "build.cache_misses": 0}
+
+
+def test_build_split_prints_the_records_rows():
+    """``tools/build_split.py`` keeps no listener of its own: the parts
+    it prints a build are the record's child rows."""
+    import json
+    import subprocess
+    import sys
+
+    tool = os.path.join(ROOT, "tools", "build_split.py")
+    with open(tool) as f:
+        assert "_listener(" not in f.read()
+    out = subprocess.run(
+        [sys.executable, tool, "lm_sc2_3b_t8k_1chip", "--rehearse"],
+        capture_output=True, text=True, check=True).stdout
+    line = json.loads(out.splitlines()[-1])
+    assert len(line["builds"]) == 2  # the broadcast's and the step's
+    for build in line["builds"]:
+        assert sorted(build) == [
+            "build_s", "cache_hits", "cache_misses", "cache_retrieval_s",
+            "compile_or_load_s", "first_call_and_rest_s", "largest",
+            "lower_s", "trace_s"]
+        parts = [build[k] for k in ("trace_s", "lower_s",
+                                    "compile_or_load_s",
+                                    "first_call_and_rest_s")]
+        assert all(p > 0 for p in parts)
+        assert sum(parts) == pytest.approx(build["build_s"])
+        assert build["largest"][0][0] in ("trace", "lower", "compile")
+
+
 def test_nobody_pays_who_is_not_looking(monkeypatch):
     """No profiler session, no HOROVOD_TIMELINE: the run resolves no
-    scope map and never asks a program for its text."""
+    scope map and never asks a program for its executable."""
     asked = []
-    monkeypatch.setattr(spmd_mod._Program, "hlo_text",
+    monkeypatch.setattr(spmd_mod._Program, "executable",
                         lambda self: asked.append(self.tag))
     _world4()
     step, ps, ss, toks, _ = _lm_step()

@@ -3,19 +3,21 @@ tools/build_split.py <cell> [--seed N] [--root DIR]`` ON THE CHIP.
 
 Runs the cell's set-up exactly as ``benchmark/run.py`` does (the runner's
 ``setup``: ``hvd.init``, the seed's weights, the followed steps through
-``hvd.spmd`` — no window, no reference) with JAX's own monitoring events
-listened to, and prints one JSON line: ``setup_s``, every
-``hvd/spmd/build`` span of the record, and inside each the seconds JAX
-spent tracing (``jaxpr_trace_duration``: the step's Python, the models',
-the kernels' bodies), lowering (``jaxpr_to_mlir_module_duration``: a
-Pallas kernel is lowered to Mosaic there, a call site at a time), and
-compiling OR loading (``backend_compile_duration``; with
-``cache_hits`` / ``cache_misses`` of the persistent cache and the seconds
-its retrieval took). What is left of the span is the first call: the
-dispatch of the loaded program, and whatever it waits for. ``--root``
-takes the program and the benchmark from another checkout (the parent's,
-unpacked beside this one): its compile cache is that checkout's own, so
-run a tree twice in one call for a warm reading.
+``hvd.spmd`` — no window, no reference) and prints one JSON line from the
+program's own record (``core/timeline.record()``): ``setup_s``, every
+``hvd/spmd/build`` span, and inside each the child rows the program keeps
+of JAX's monitoring events — the seconds JAX spent tracing (the step's
+Python, the models', the kernels' bodies), lowering (a Pallas kernel is
+lowered to Mosaic there, a call site at a time), and compiling OR loading
+(with the built program's ``cache_hits`` / ``cache_misses`` of the
+persistent cache; a load's seconds are the cache's retrieval). What is
+left of the span is the first call: the dispatch of the loaded program,
+and whatever it waits for. ``compiles`` is the record's account of the
+JAX programs outside any build. ``--root`` takes the program and the benchmark
+from another checkout (the parent's, unpacked beside this one): its
+compile cache is that checkout's own, so run a tree twice in one call for
+a warm reading. A tree older than PR 36 records no parts: the tool says
+so and exits 4.
 """
 
 from __future__ import annotations
@@ -28,25 +30,7 @@ import sys
 import time
 import types
 
-EVENTS = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
-    "/jax/core/compile/backend_compile_duration": "compile_or_load_s",
-    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
-}
-COUNTS = {
-    "/jax/compilation_cache/cache_hits": "cache_hits",
-    "/jax/compilation_cache/cache_misses": "cache_misses",
-}
-
-
-def _union_s(intervals) -> float:
-    """Seconds covered by the ``(start_ns, end_ns)`` intervals."""
-    total, reach = 0.0, float("-inf")
-    for start, end in sorted(intervals):
-        total += max(end - max(start, reach), 0.0)
-        reach = max(reach, end)
-    return total / 1e9
+BUILD = "hvd/spmd/build"
 
 
 def main():
@@ -67,21 +51,11 @@ def main():
 
     _, cell, config, traffic = run.load_cell(args.cell, args.rehearse)
     import jax
-    from jax import monitoring
 
     if not args.rehearse and jax.devices()[0].platform != "tpu":
         print("no TPU: this tool reads a chip's set-up", file=sys.stderr)
         return 3
     run.configure_jax(args.rehearse)
-    seen = []  # (kind, end_ns, seconds or 1, fun_name)
-    monitoring.register_event_duration_secs_listener(
-        lambda event, seconds, **kw: seen.append(
-            (EVENTS[event], time.perf_counter_ns(), seconds,
-             kw.get("fun_name", ""))) if event in EVENTS else None)
-    monitoring.register_event_listener(
-        lambda event, **kw: seen.append(
-            (COUNTS[event], time.perf_counter_ns(), 1, ""))
-        if event in COUNTS else None)
 
     runner = run.load_module("runners", config["runner"])
     ctx = types.SimpleNamespace(
@@ -95,25 +69,38 @@ def main():
 
     from horovod_tpu.core import timeline
 
+    record = timeline.session().record()
+    if "compiles" not in record:
+        print(f"{root} records no parts of a build (a tree older than "
+              f"PR 36): nothing to split", file=sys.stderr)
+        session.release()
+        return 4
     builds = []
-    for name, start, end, _ in timeline.session().record()["spans"]:
-        if name != "hvd/spmd/build":
-            continue
-        row = {"build_s": (end - start) / 1e9}
-        inside = [e for e in seen if start <= e[1] <= end]
-        for kind in EVENTS.values():  # nested traces are counted once
-            row[kind] = _union_s([(e[1] - e[2] * 1e9, e[1]) for e in inside
-                                  if e[0] == kind])
-        for kind in COUNTS.values():
-            row[kind] = sum(e[2] for e in inside if e[0] == kind)
+    spans = [s for s in record["spans"] if s[0] == BUILD]
+    # every build opened one program's record, in this order
+    for (_, start, end, _), program in zip(spans,
+                                           record["programs"].values()):
+        inside = [r for r in record["spans"] if r[0].startswith(BUILD + "/")
+                  and start <= r[1] and r[2] <= end]
+        # the program drops a tracing nested in another: plain sums
+        part = {p: sum((r[2] - r[1]) / 1e9 for r in inside
+                       if r[0] == f"{BUILD}/{p}")
+                for p in ("trace", "lower", "compile", "load")}
+        row = {"build_s": (end - start) / 1e9, "trace_s": part["trace"],
+               "lower_s": part["lower"],
+               "compile_or_load_s": part["compile"] + part["load"],
+               "cache_retrieval_s": part["load"],
+               "cache_hits": program["counters"]["build.cache_hits"],
+               "cache_misses": program["counters"]["build.cache_misses"]}
         row["first_call_and_rest_s"] = row["build_s"] - sum(
             row[k] for k in ("trace_s", "lower_s", "compile_or_load_s"))
         row["largest"] = sorted(
-            ([e[0], round(e[2], 3), e[3]] for e in inside
-             if e[0] in EVENTS.values()), key=lambda r: -r[1])[:4]
+            ([r[0].rsplit("/", 1)[1], round((r[2] - r[1]) / 1e9, 3)]
+             for r in inside), key=lambda r: -r[1])[:4]
         builds.append(row)
     print(json.dumps({"cell": args.cell, "root": root, "seed": args.seed,
-                      "setup_s": setup_s, "builds": builds}), flush=True)
+                      "setup_s": setup_s, "builds": builds,
+                      "compiles": record["compiles"]}), flush=True)
     session.release()
     return 0
 
