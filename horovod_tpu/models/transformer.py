@@ -747,6 +747,11 @@ class Transformer(nn.Module):
         tl.count_plan("model.block_applications", applied)
         tl.count_plan("model.attention_layers", applied - convs)
         tl.count_plan("model.conv_layers", convs)
+        # Of them, those whose gate-and-tap pass is the Pallas kernel pair
+        # (ops/short_conv.py runs_kernels: a TPU, bfloat16, whole lanes).
+        tl.count_plan("model.conv_kernel_layers", convs if convs and (
+            _short_conv.runs_kernels(t_local, cfg.embed_dim, cfg.conv_taps,
+                                     cfg.dtype)) else 0)
         tl.count_plan("model.recomputed_blocks", applied if looped else 0)
         # Whether an attention block's mixer is a call of the Pallas kernel:
         # asked for a looped stack (whose policy keeps the kernel's

@@ -437,10 +437,12 @@ class TestConvAndAttentionStepCompilesForTheChip:
         for one v5e chip above T=2048 at the published head geometry — 64-
         wide heads, four query heads a KV head, q/k norms — and model
         width: the flash kernels once forward and once backward for the
-        ATTENTION layers alone, and the conv layers' gate-and-tap pass
-        under the ``conv/gate`` scope with no kernel and no convolution
-        instruction of its own (three shifted products, fused), run again
-        in the backward (``jax.checkpoint``)."""
+        ATTENTION layer alone, and each conv layer's gate-and-tap pass as
+        the conv kernel pair (PR 37): ``hvd_conv_fwd`` under ``conv/gate``
+        in the forward and ``hvd_conv_bwd`` under it in the backward
+        (``transpose(jvp(...))``), no convolution instruction, no float32
+        copy of the (T, 3E) streams anywhere in the step, and nothing of
+        the gate marked recomputed (no ``jax.checkpoint`` there)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from horovod_tpu.core.state import AXIS_NAME
@@ -473,11 +475,55 @@ class TestConvAndAttentionStepCompilesForTheChip:
         hvd.shutdown()
         count = lambda name: len(re.findall(
             rf"= [^\n]* custom-call\([^\n]*{name}", txt))
+        convs = 2
         assert count("hvd_flash_fwd") == count("hvd_flash_bwd") == 1
-        assert txt.count('custom_call_target="tpu_custom_call"') == 2
+        assert count("hvd_conv_fwd") == count("hvd_conv_bwd") == convs
+        assert txt.count('custom_call_target="tpu_custom_call"') \
+            == 2 + 2 * convs
+        calls = lambda name: re.findall(
+            rf'= [^\n]* custom-call\([^\n]*op_name="([^"\n]*{name}[^"\n]*)"',
+            txt)
+        fwd, bwd = calls("hvd_conv_fwd"), calls("hvd_conv_bwd")
+        assert len(fwd) == len(bwd) == convs
+        assert all("/conv/gate/" in n and "transpose(" not in n for n in fwd)
+        assert all("/conv/gate/" in n and "transpose(jvp(" in n for n in bwd)
+        assert not re.findall(r"f32\[(1,)?4096,1536\]", txt)  # (T, 3E)
         gate = re.findall(r'op_name="[^"\n]*/block_[02]/conv/gate/[^"\n]*"',
                           txt)
         assert gate and not [n for n in gate if "conv_general" in n]
-        assert [n for n in gate if "rematted_computation" in n]
+        assert not [n for n in gate if "rematted_computation" in n]
         assert not re.findall(r'op_name="[^"\n]*/block_1/conv/', txt)
         assert re.findall(r'op_name="[^"\n]*/block_1/attn/qk_norm/', txt)
+
+    @pytest.mark.parametrize("documents", [False, True],
+                             ids=["one_document", "packed_documents"])
+    def test_the_conv_kernels_compile_at_the_cells_width(self, documents):
+        """The gate-and-tap pass's kernel pair at ``lfm2_24b_a2b``'s
+        geometry (T = 8192, E = 2048, K = 3; ops/short_conv.py), forward
+        and backward, with and without packed documents: what Mosaic
+        refuses here (a tiling, a rotation, a DMA, VMEM) costs no chip
+        time. The backward writes ONE (T, 3E) array: no concatenation."""
+        from jax.sharding import SingleDeviceSharding
+
+        from horovod_tpu.ops import short_conv
+
+        one = SingleDeviceSharding(_topo(4, "v5e:2x2")[0])
+        shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)
+        t, e, taps = 8192, 2048, 3
+        tiles = short_conv._tiles(t, e, taps)
+
+        def step(bcu, w, g, segs):
+            keep = short_conv._keep(segs, taps) if documents else None
+            out, vjp = jax.vjp(lambda x, w: short_conv._kernels(
+                x, w, keep, tiles, False), bcu, w)
+            return out, vjp(g)
+
+        with jax.enable_x64(False):
+            txt = jax.jit(step).lower(
+                shape((1, t, 3 * e), jnp.bfloat16),
+                shape((e, taps), jnp.float32),
+                shape((1, t, e), jnp.bfloat16),
+                shape((1, t), jnp.int32)).compile().as_text()
+        assert txt.count('custom_call_target="tpu_custom_call"') == 2
+        assert not re.findall(r"= bf16\[1,8192,6144\][^\n]* concatenate\(",
+                              txt)

@@ -184,6 +184,7 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
         "model.kept_attention_outputs": 0, "model.head_applications": 1,
         # ... all of them attention (no ``layer_types``: PR 33)
         "model.attention_layers": 2, "model.conv_layers": 0,
+        "model.conv_kernel_layers": 0,
         # the build's own: no persistent cache here, so neither
         "build.cache_hits": 0, "build.cache_misses": 0}
 
@@ -239,6 +240,8 @@ def test_a_looped_steps_record_counts_the_kept_attention_outputs(
     assert {k: v for k, v in counters.items() if k.startswith("model.")} == {
         "model.block_applications": 6, "model.recomputed_blocks": 6,
         "model.attention_layers": 6 - convs, "model.conv_layers": convs,
+        # (a CPU step: the gate-and-tap pass is the plain form, PR 37)
+        "model.conv_kernel_layers": 0,
         "model.kept_attention_outputs": kept,
         "model.head_applications": 1}
     # The kernels' scores (PR 35), a forward and a backward call an
