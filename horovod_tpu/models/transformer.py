@@ -778,12 +778,16 @@ class Transformer(nn.Module):
             calls = (applied - convs) * tokens.shape[0] * heads
             tl.count_plan("flash.scores_visible", calls * visible)
             tl.count_plan("flash.scores_computed", calls * computed)
+        moe_layers = 0 if cfg.moe is None else max(
+            cfg.num_layers - cfg.moe.dense_layers, 0) + (cfg.mtp is not None)
+        # Of the expert layers, those whose backward reads back the
+        # gate-and-up product its forward kept and runs no grouped product
+        # a second time (ops/moe.py routed_experts): every one.
+        tl.count_plan("model.moe_kept_products", moe_layers)
         if cfg.moe is not None:
             # The plan of the expert layers (the MTP module's is one more);
             # what the experts TOOK is the step's output, not the plan's.
-            tl.count_plan("model.moe_layers",
-                          max(cfg.num_layers - cfg.moe.dense_layers, 0)
-                          + (cfg.mtp is not None))
+            tl.count_plan("model.moe_layers", moe_layers)
             tl.count_plan("model.experts_held", cfg.moe.held)
             tl.count_plan("model.experts_total", cfg.moe.total)
             tl.count_plan("model.moe_pair_capacity",

@@ -19,8 +19,8 @@ layer gets its EXTENT ON THE DEVICE from the routed-row count ``R``, the
 sum of the held experts' pairs. The grouped matrix products run over the
 groups' real sizes (:func:`grouped_matmul`: a kernel whose grid the
 device sizes); every pass between them — the rows' gather into expert
-order, the gated activation, the weighing with the gates and the way back
-to the tokens, and the transposes of the three — is a loop over blocks of
+order, the gated activation weighed with the gates, the way back to the
+tokens, and the transposes of the three — is a loop over blocks of
 :data:`ROW_BLOCK` rows with ``ceil(R / ROW_BLOCK)`` rounds
 (:func:`_rounds`). The layer's time follows the rows that were routed
 here, from none to the whole buffer, in steps of one block; nothing but
@@ -30,12 +30,24 @@ order and back by a sort each) follows the buffer's static size, and a
 row nobody was routed to is never read, nor is a buffer cleared
 (``tests/test_moe_lm.py``: the poison test).
 
+**The backward runs no grouped product twice.** Each row is weighed with
+its gate BEFORE the down product (``(gate h) wd``, the same sum as
+``gate (h wd)``), so a gate's cotangent is ``<d(gate h), h>``, which the
+activation's backward has in hand, and nothing reads the down product's
+result back. The gate-and-up product is kept for the backward (one
+(tokens x top_k, 2F) buffer a layer: the result of :func:`_gate_up`,
+which :func:`_down` takes); the backward computes again only the rows'
+gather (what the gate-and-up matrices' gradient reads) and the gated
+activation (what the down matrices' reads), both passes over the routed
+rows (``tests/test_moe_lm.py``: the residuals and product-count tests).
+
 All functions are plain traced code: they run inside or outside
 ``hvd.spmd``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -169,29 +181,34 @@ def _land(buf, rows, at):
     return lax.dynamic_update_slice_in_dim(buf, rows, at, 0)
 
 
-def _to_tokens(rows_of, width, token, routed, n):
-    """``out[t]`` = the sum of ``rows_of(at, block)[i]`` (float32,
-    (block, width)) over the buffer rows ``r = at + i`` below ``routed``
-    with ``token[r] == t``: the buffer's rows added back at their tokens,
-    round by round, (n, width) float32. A pair held elsewhere has no row
-    below ``routed`` and reads nothing."""
+def _gather(x, token, routed, dtype):
+    """Buffer rows ``x[token]`` below ``routed``, in ``dtype`` (``token``
+    (C,): the token of each row of the expert-ordered buffer); the rows
+    above the last round's are nobody's."""
+    def body(at, block, buf):
+        return _land(buf, x[_rows(token, at, block)].astype(dtype), at)
+    return _rounds(routed, token.shape[0], body,
+                   _buffer(token.shape[0], x.shape[1], dtype))
+
+
+def _fold(rows, token, routed, n):
+    """``out[t]`` = the sum of the rows of ``rows`` (C, width) below
+    ``routed`` whose token is ``t``: the buffer's rows added back at
+    their tokens in float32, round by round, (n, width). A pair held
+    elsewhere has no row below ``routed`` and adds nothing."""
     def body(at, block, out):
         live = (at + jnp.arange(block) < routed)[:, None]  # the last round
-        return out.at[_rows(token, at, block)].add(
-            jnp.where(live, rows_of(at, block), 0.0))
+        return out.at[_rows(token, at, block)].add(jnp.where(
+            live, _rows(rows, at, block).astype(jnp.float32), 0.0))
     return _rounds(routed, token.shape[0], body,
-                   jnp.zeros((n, width), jnp.float32))
+                   jnp.zeros((n, rows.shape[1]), jnp.float32))
 
 
 @jax.custom_vjp
 def _take(x, token, routed):
-    """Buffer rows ``x[token]`` below ``routed`` (``token`` (C,): the
-    token of each row of the expert-ordered buffer); the rows above the
-    last round's are nobody's."""
-    def body(at, block, buf):
-        return _land(buf, x[_rows(token, at, block)], at)
-    return _rounds(routed, token.shape[0], body,
-                   _buffer(token.shape[0], x.shape[1], x.dtype))
+    """The routed rows gathered into the buffer (:func:`_gather`); a
+    token's cotangent is the sum of its rows'."""
+    return _gather(x, token, routed, x.dtype)
 
 
 def _take_fwd(x, token, routed):
@@ -200,9 +217,8 @@ def _take_fwd(x, token, routed):
 
 def _take_bwd(res, g):
     token, routed, n = res
-    dx = _to_tokens(lambda at, block: _rows(g, at, block).astype(jnp.float32),
-                    g.shape[1], token, routed, n).astype(g.dtype)
-    return dx, _int_zero(token), _int_zero(routed)
+    return (_fold(g, token, routed, n).astype(g.dtype), _int_zero(token),
+            _int_zero(routed))
 
 
 _take.defvjp(_take_fwd, _take_bwd)
@@ -213,34 +229,6 @@ def _silu_mul(gu):
     return jax.nn.silu(gu[:, :f]) * gu[:, f:]
 
 
-@jax.custom_vjp
-def _gated(gu, routed):
-    """``silu(g) * u`` of the routed rows of ``gu`` = ``[g | u]`` (C, 2F):
-    (C, F), nobody's above the last round."""
-    def body(at, block, h):
-        return _land(h, _silu_mul(_rows(gu, at, block)), at)
-    return _rounds(routed, gu.shape[0], body,
-                   _buffer(gu.shape[0], gu.shape[1] // 2, gu.dtype))
-
-
-def _gated_fwd(gu, routed):
-    return _gated(gu, routed), (gu, routed)
-
-
-def _gated_bwd(res, dh):
-    gu, routed = res
-
-    def body(at, block, dgu):
-        # ``dgu`` starts as ``gu`` and each round turns one block of it
-        # into its cotangent, in place: no second buffer, none to clear.
-        _, back = jax.vjp(_silu_mul, _rows(dgu, at, block))
-        return _land(dgu, back(_rows(dh, at, block))[0], at)
-    return _rounds(routed, gu.shape[0], body, gu), _int_zero(routed)
-
-
-_gated.defvjp(_gated_fwd, _gated_bwd)
-
-
 def _in_order(values, key):
     """``values`` (C,) in the buffer's order: sorted by ``key`` as the
     pairs were (the same stable sort; a sort of C scalars takes 20 us on
@@ -249,73 +237,121 @@ def _in_order(values, key):
 
 
 @jax.custom_vjp
-def _put(ys, weight, key, order, here, routed):
-    """``out[t]`` = the sum over token ``t``'s choices held ``here`` of
-    their weight x their row of ``ys`` (float32, (N, E)), taken from the
-    buffer's side: row ``r`` below ``routed`` adds its own pair's weight x
-    ``ys[r]`` at its token (``order`` (C,) names each row's pair, ``key``
-    (C,) is what sorted them). A weight's cotangent is its row's."""
-    return _put_fwd(ys, weight, key, order, here, routed)[0]
+def _gated(gu, weight, key, order, here, routed):
+    """``gate x silu(g) * u`` of the routed rows of ``gu`` = ``[g | u]``
+    (C, 2F): (C, F) in ``gu``'s dtype, computed in float32 and rounded
+    once, nobody's above the last round. Each row is weighed with its own
+    pair's gate HERE, before the down product (``(gate h) wd`` is the
+    same sum as ``gate (h wd)``): ``weight`` (N, k) in the pairs' order,
+    brought into the buffer's by ``key`` (C,) as the pairs were sorted;
+    ``order`` (C,) names each row's pair, ``here`` (N, k) says which pairs
+    are held. A weight's cotangent is its row's ``<d(gate h), h>``, zero
+    for a pair held elsewhere."""
+    return _gated_fwd(gu, weight, key, order, here, routed)[0]
 
 
-def _put_fwd(ys, weight, key, order, here, routed):
+def _gated_fwd(gu, weight, key, order, here, routed):
     gate = _in_order(weight.reshape(-1), key)
-    token = order // weight.shape[1]
-    out = _to_tokens(
-        lambda at, block: _rows(gate, at, block)[:, None]
-        * _rows(ys, at, block).astype(jnp.float32),
-        ys.shape[1], token, routed, weight.shape[0])
-    return out, (ys, gate, token, key, order, here, routed)
+
+    def body(at, block, h):
+        return _land(h, (_rows(gate, at, block)[:, None] * _silu_mul(
+            _rows(gu, at, block).astype(jnp.float32))).astype(gu.dtype), at)
+    h = _rounds(routed, gu.shape[0], body,
+                _buffer(gu.shape[0], gu.shape[1] // 2, gu.dtype))
+    return h, (gu, gate, key, order, here, routed)
 
 
-def _put_bwd(res, g):
-    ys, gate, token, key, order, here, routed = res
+def _gated_bwd(res, dh):
+    gu, gate, key, order, here, routed = res
 
     def body(at, block, carry):
-        # (``d_ys`` lands in a buffer of its own, not over ``ys``: a round
-        # that reads a carry in one fusion and writes it in another makes
-        # XLA copy the whole carry, twice a round. PERF.md, PR 32.)
-        d_ys, d_gate = carry
-        mine = g[_rows(token, at, block)]  # each row's token's
-        d_ys = _land(d_ys, (_rows(gate, at, block)[:, None]
-                            * mine).astype(ys.dtype), at)
-        d_gate = _land(d_gate, jnp.sum(
-            mine * _rows(ys, at, block).astype(jnp.float32), axis=-1), at)
-        return d_ys, d_gate
-    d_ys, d_gate = _rounds(
-        routed, order.shape[0], body,
-        (_buffer(*ys.shape, ys.dtype), jnp.zeros(order.shape, jnp.float32)))
+        # (``dgu`` lands in a buffer of its own, not over ``gu``: the
+        # forward's product is read again by the activation's second
+        # forward, and XLA would copy it whole to free it for a pass
+        # that writes over it.) The round reads ``h`` = silu(g) * u off
+        # the same rows for the gates' cotangents.
+        dgu, d_gate = carry
+        h, back = jax.vjp(_silu_mul,
+                          _rows(gu, at, block).astype(jnp.float32))
+        d = _rows(dh, at, block).astype(jnp.float32)
+        dgu = _land(dgu, back(_rows(gate, at, block)[:, None] * d)[0]
+                    .astype(gu.dtype), at)
+        return dgu, _land(d_gate, jnp.sum(d * h, axis=-1), at)
+    dgu, d_gate = _rounds(
+        routed, gu.shape[0], body,
+        (_buffer(*gu.shape, gu.dtype), jnp.zeros(order.shape, jnp.float32)))
     # Row r's cotangent is pair order[r]'s: back in the pairs' own order
     # (a sort by ``order``, which is a permutation), the held ones kept.
     d_weight = jnp.where(here, _in_order(d_gate, order).reshape(here.shape),
                          0.0).astype(gate.dtype)
-    return (d_ys, d_weight, _int_zero(key), _int_zero(order),
+    return (dgu, d_weight, _int_zero(key), _int_zero(order),
             _int_zero(here), _int_zero(routed))
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _put(ys, token, routed, n):
+    """The buffer's rows folded back at their ``n`` tokens (:func:`_fold`,
+    summed in float32): (n, E) in ``ys``'s dtype. The gates are in the
+    rows already (:func:`_gated`). A row's cotangent is its token's,
+    gathered: no row of ``ys`` is read."""
+    return _fold(ys, token, routed, n).astype(ys.dtype)
+
+
+def _put_fwd(ys, token, routed, n):
+    return _put(ys, token, routed, n), (token, routed)
+
+
+def _put_bwd(n, res, g):
+    token, routed = res
+    return (_gather(g, token, routed, g.dtype), _int_zero(token),
+            _int_zero(routed))
 
 
 _put.defvjp(_put_fwd, _put_bwd)
 
 
 @jax.checkpoint
-def _part(x, weight, wgu, wd, key, order, here, pairs):
-    """The held experts' part over the expert-ordered buffer of all
-    ``N k`` pairs, the routed ones first: ``key`` (N k,) each pair's held
-    expert (``held`` for one held elsewhere), ``order`` (N k,) the pairs
-    sorted by it, ``here`` (N, k) whether a pair's expert is held here,
-    ``weight`` (N, k) the gates, zero where it is not, ``wgu`` (held, E,
-    2F) the gate and up matrices side by side. Nothing of the buffer is
-    kept for the backward: it is gathered and computed again there
-    (``jax.checkpoint``)."""
+def _gate_up(x, wgu, order, here, pairs):
+    """The routed rows gathered into the expert-ordered buffer of all
+    ``N k`` pairs, the routed ones first (``order`` (N k,) the pairs
+    sorted by their held expert, ``here`` (N, k) whether a pair's expert
+    is held here), and their gate-and-up product ``[g | u]`` (N k, 2F)
+    (``wgu`` (held, E, 2F) the gate and up matrices side by side).
+
+    That product is the one value of the routed part that the backward
+    keeps: it is this function's result, so nothing computes it again.
+    Inside, ``jax.checkpoint`` keeps nothing: the backward gathers the
+    rows again for ``wgu``'s gradient, cheaper than keeping them."""
     routed = jnp.sum(pairs)
     with jax.named_scope("dispatch"):
         xs = _take(x, order // here.shape[1], routed)
     with jax.named_scope("experts"):
-        ys = grouped_matmul(_gated(grouped_matmul(xs, wgu, pairs), routed),
+        return grouped_matmul(xs, wgu, pairs)
+
+
+@jax.checkpoint
+def _down(gu, weight, wd, key, order, here, pairs):
+    """The gated activation of the kept product ``gu``, weighed with the
+    gates (``weight`` (N, k), zero where a pair is held elsewhere; ``key``
+    (N k,) each pair's held expert, ``held`` for one held elsewhere), its
+    down product and the fold back to the tokens: (N, E) in ``gu``'s
+    dtype.
+
+    The backward computes the activation again from ``gu`` (what ``wd``'s
+    gradient reads) and no product: nothing reads the down product's
+    result back, since the gates weigh the rows before it and their
+    cotangents come from the activation's backward (:func:`_gated`)."""
+    routed = jnp.sum(pairs)
+    with jax.named_scope("experts"):
+        ys = grouped_matmul(_gated(gu, weight, key, order, here, routed),
                             wd, pairs)
     with jax.named_scope("combine"):
         # A pair held elsewhere has a row above ``routed``: no round
         # reads it, forward or back, whatever the kernels left there.
-        return _put(ys, weight, key, order, here, routed).astype(x.dtype)
+        return _put(ys, order // here.shape[1], routed, here.shape[0])
 
 
 def routed_experts(x, idx, gates, wg, wu, wd, first: int = 0):
@@ -334,7 +370,9 @@ def routed_experts(x, idx, gates, wg, wu, wd, first: int = 0):
     costs time, never a token. The grouped products (gate and up side by
     side as one, then down) and every pass between them run over the
     routed rows alone, in rounds of :data:`ROW_BLOCK` rows whose number
-    the device computes (PERF.md, PR 32, has the layer's time over R)."""
+    the device computes (PERF.md, PR 32, has the layer's time over R).
+    The backward keeps the gate-and-up product and runs neither product
+    forward again (module docstring)."""
     held = wg.shape[0]
     local = idx - first
     here = (local >= 0) & (local < held)
@@ -344,8 +382,9 @@ def routed_experts(x, idx, gates, wg, wu, wd, first: int = 0):
         pairs = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                         dtype=jnp.int32)
     wgu = jnp.concatenate([wg, wu], axis=2).astype(x.dtype)
-    out = _part(x, jnp.where(here, gates, 0.0), wgu, wd.astype(x.dtype),
-                key, order, here, pairs)
+    out = _down(_gate_up(x, wgu, order, here, pairs),
+                jnp.where(here, gates, 0.0), wd.astype(x.dtype), key, order,
+                here, pairs)
     return out, pairs
 
 

@@ -202,6 +202,7 @@ def test_three_adamw_steps_through_hvd_match_the_reference(
     assert counters["model.kept_attention_outputs"] == 0  # a plain stack
     assert counters["model.head_applications"] == 1
     assert counters["model.moe_layers"] == 3
+    assert counters["model.moe_kept_products"] == 3
     assert counters["model.experts_held"] == 4
     assert counters["model.experts_total"] == 16
     assert counters["model.moe_pair_capacity"] == T * 3

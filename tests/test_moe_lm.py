@@ -189,6 +189,7 @@ def test_three_adamw_steps_through_hvd_match_the_reference(
     assert counters["model.recomputed_blocks"] == 0
     assert counters["model.head_applications"] == 2
     assert counters["model.moe_layers"] == 3  # two layers and the MTP's
+    assert counters["model.moe_kept_products"] == 3  # each of them
     assert counters["model.experts_held"] == 4
     assert counters["model.experts_total"] == 16
     assert counters["model.moe_pair_capacity"] == T * 3
@@ -498,7 +499,90 @@ def test_nothing_outside_a_device_sized_pass_makes_a_buffer():
         "broadcast_in_dim",            # a buffer's one initialisation
         "while", "ragged_dot_general", "pallas_call",  # the passes' results
         "custom_vjp_call", "custom_jvp_call", "pjit", "jit", "checkpoint",
-        "remat", "closed_call", "custom_vjp_call_jaxpr"}, outside
+        "remat", "remat2", "closed_call", "custom_vjp_call_jaxpr"}, outside
+
+
+def test_the_backward_keeps_the_gate_and_up_product_alone(capsys):
+    """Of the routed part's (N k, .) buffers the backward keeps one: the
+    gate-and-up product, (N k, 2F). Not the gathered rows (N k, E), not
+    the activation (N k, F), not the down product (N k, E): those are
+    gathered, computed again or never read back."""
+    x, idx, gates, wg, wu, wd = _routed_case(100)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda x, gates, wg, wu, wd: jnp.sum(moe_ops.routed_experts(
+            x, idx, gates, wg, wu, wd, first=4)[0]), x, gates, wg, wu, wd)
+    saved = [line.split()[0] for line in
+             capsys.readouterr().out.strip().splitlines()]
+    assert saved.count("f32[192,16]") == 1, saved  # gate | up, once
+    assert not [s for s in saved if s.startswith("f32[192,")
+                and s != "f32[192,16]"], saved
+
+
+def test_the_backward_runs_each_grouped_product_forward_once():
+    """In the jaxpr of the layer's gradient the gate-and-up product
+    (N k, E) x (E, 2F) and the down product (N k, F) x (F, E) each appear
+    once, beside their four transposes: 6 grouped products. (Before the
+    product was kept, the backward ran both forward again: 8.)"""
+    case = _routed_case(100)
+    x, idx, gates, wg, wu, wd = case
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x, gates, wg, wu, wd: jnp.sum(_program_part(
+            x, idx, gates, wg, wu, wd)), argnums=(0, 1, 2, 3, 4)))(
+        x, gates, wg, wu, wd)
+
+    def products(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "ragged_dot_general":
+                found.append((eqn.invars[0].aval.shape,
+                              eqn.outvars[0].aval.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                products(sub, found)
+        return found
+    found = products(jaxpr.jaxpr, [])
+    assert len(found) == 6, found
+    assert found.count(((192, 24), (192, 16))) == 1  # x_s [wg | wu]
+    assert found.count(((192, 8), (192, 24))) == 1  # (gate h) wd
+
+
+def test_the_gates_and_the_routers_gradient_match_a_dense_form():
+    """With the gates applied before the down product, each gate's
+    cotangent is ``<d(gate h), h>``: the gradient with respect to the
+    gates, and through :func:`route` the router's and the tokens', is the
+    float32 dense form's — pairs held elsewhere (gate zero, gradient zero)
+    and tokens none of whose choices is held here among them."""
+    tokens, e, f, total, first, held, k = 64, 24, 8, 16, 4, 4, 3
+    rng = np.random.default_rng(11)
+    normal = lambda *shape: jnp.asarray(
+        rng.standard_normal(shape), jnp.float32)
+    x, wr = normal(tokens, e), 0.3 * normal(e, total)
+    wg, wu, wd = (0.3 * normal(held, e, f), 0.3 * normal(held, e, f),
+                  0.3 * normal(held, f, e))
+    bias = jnp.zeros((total,))
+    probe = jnp.cos(jnp.arange(tokens * e, dtype=jnp.float32)).reshape(
+        tokens, e)
+    idx, gates = moe_ops.route(x, wr, bias, k, 1.8)
+    mine = (idx >= first) & (idx < first + held)
+    assert bool(jnp.any(~mine))  # pairs held elsewhere
+    assert bool(jnp.any(~jnp.any(mine, axis=1)))  # a token with none here
+    assert bool(jnp.any(mine))
+
+    def through_gates(part):
+        return lambda gates: jnp.sum(
+            probe * part(x, idx, gates, wg, wu, wd))
+
+    def through_router(part):
+        return lambda x, wr: jnp.sum(
+            probe * part(x, *moe_ops.route(x, wr, bias, k, 1.8), wg, wu, wd))
+
+    with jax.default_matmul_precision("highest"):
+        got_gates = jax.grad(through_gates(_program_part))(gates)
+        want_gates = jax.grad(through_gates(_dense_part))(gates)
+        got = jax.grad(through_router(_program_part), argnums=(0, 1))(x, wr)
+        want = jax.grad(through_router(_dense_part), argnums=(0, 1))(x, wr)
+    assert not bool(jnp.any(jnp.where(mine, 0.0, got_gates)))
+    for a, b in ((got_gates, want_gates), *zip(got, want)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=2e-5 * float(jnp.max(jnp.abs(b))))
 
 
 # ---------------------------------------------------------------------------

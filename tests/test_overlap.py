@@ -364,10 +364,12 @@ class TestExpertLayerStepCompilesForTheChip:
         kernels at D = 256 with their swept default blocks (one forward
         and one backward a block application, the MTP module's included),
         and the routed experts as Pallas grouped matmuls under the
-        ``experts`` scope — forward, the backward's second forward and
-        the two transposes of the two products (gate and up side by side
-        as one, and down) — with, between them, loops whose trip count
-        the device computes from the routed rows."""
+        ``experts`` scope — forward and the two transposes of the two
+        products (gate and up side by side as one, and down), no second
+        forward: the backward reads the kept gate-and-up product, neither
+        copied whole nor rounded by a pass of its own — with, between
+        them, loops whose trip count the device computes from the routed
+        rows."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from horovod_tpu.core.state import AXIS_NAME
@@ -409,16 +411,19 @@ class TestExpertLayerStepCompilesForTheChip:
         blocks, expert_layers = cfg.num_layers + 1, cfg.num_layers
         assert count("hvd_flash_fwd") == count("hvd_flash_bwd") == blocks
         # (two products forward — gate and up side by side as one, and
-        # down —, the same two again in the backward — the buffer is a
-        # ``jax.checkpoint``'s — and their four transposes)
+        # down — and their four transposes: none runs forward again)
         grouped = re.findall(
             r"= [^\n]* custom-call\([^\n]*tpu_custom_call[^\n]*"
             r'op_name="[^"\n]*/moe/[^"\n]*experts\)*/jit\(t?gmm\)/', txt)
-        assert len(grouped) == expert_layers * (2 + 2 + 4)
+        assert len(grouped) == expert_layers * (2 + 4)
+        # ... and the kept product, (tokens x top_k, 2F), is read back as
+        # the first product wrote it: no whole copy, no rounding pass
+        assert not re.findall(
+            r"= bf16\[16384,3072\][^\n]* (?:copy|reduce-precision)\(", txt)
         # (the buffers the rounds land their blocks in are Pallas calls
         # that write nothing: the rows and the activation, forward and
-        # again, and the rows' cotangents)
-        assert count("hvd_moe_buffer") == expert_layers * (2 + 2 + 1)
+        # again, the rows' cotangents and the activation's)
+        assert count("hvd_moe_buffer") == expert_layers * (2 + 2 + 2)
         assert "ragged-dot" not in txt
         # ... and the eight passes between them (three forward; the
         # gather and the activation again, whose way back to the tokens

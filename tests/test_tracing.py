@@ -182,6 +182,8 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
         # and has one head
         "model.block_applications": 2, "model.recomputed_blocks": 0,
         "model.kept_attention_outputs": 0, "model.head_applications": 1,
+        # ... and has no expert layer whose gate-and-up product is kept
+        "model.moe_kept_products": 0,
         # ... all of them attention (no ``layer_types``: PR 33)
         "model.attention_layers": 2, "model.conv_layers": 0,
         "model.conv_kernel_layers": 0,
@@ -243,6 +245,7 @@ def test_a_looped_steps_record_counts_the_kept_attention_outputs(
         # (a CPU step: the gate-and-tap pass is the plain form, PR 37)
         "model.conv_kernel_layers": 0,
         "model.kept_attention_outputs": kept,
+        "model.moe_kept_products": 0,
         "model.head_applications": 1}
     # The kernels' scores (PR 35), a forward and a backward call an
     # attention block application, a row and head: T = 16 is one block,
@@ -291,7 +294,8 @@ def test_an_expert_layer_steps_record_counts_its_row_block():
     hvd.shutdown()
     capacity = 2 * 16 * 3  # a rank's tokens x top_k
     assert {k: v for k, v in counters.items() if "moe" in k} == {
-        "model.moe_layers": 1, "model.moe_pair_capacity": capacity,
+        "model.moe_layers": 1, "model.moe_kept_products": 1,
+        "model.moe_pair_capacity": capacity,
         "model.moe_row_block": moe.row_block(capacity)}
     assert moe.row_block(capacity) == 32 and moe.row_block(32768) == 512
 
