@@ -64,6 +64,44 @@ last ``conv_taps - 1`` gated inputs, not a KV cache: serving it is
 ROADMAP M6) and with ``attention='ring'`` / ``'ulysses'`` (a shard's first
 positions would need a halo of ``conv_taps - 1`` from the shard before).
 
+**Attention kinds by layer** (an AFMoE-style model such as Trinity-Mini
+uses them, ``benchmark/configs/trinity_mini.json``): three entries of
+:data:`MIXER` are attention, each in :data:`ATTENTION_KINDS` —
+``'attention'`` (``window``, which may be None, and rotary: what every
+layer was before kinds existed), ``'sliding'`` (``window``, which it
+needs, and rotary) and ``'full'`` (full causal and NO position encoding).
+All three build :class:`Attention` under ``attn``; the two new ones sit
+under a named scope of their own, ``sliding_attn`` / ``full_attn``.
+``head_dim`` frees a GQA head's width from ``embed_dim / num_heads``,
+``attn_gate`` multiplies every head's output by a sigmoid of the layer's
+input before the output projection, and ``embed_scale`` multiplies the
+embedding's output. Such a layer, with ``qk_norm``, ``sandwich_norm`` and
+an expert layer beside a shared expert, reads (x: (T, E); RMSNorm
+everywhere; no bias)::
+
+    h^0        = Embed(t) * embed_scale               (sqrt(E) under muP)
+    y          = RMSNorm(x)
+    q          = y W_q  (H heads of D);  k = y W_k,  v = y W_v  (G heads)
+    q, k      <- RMSNorm over each head's D channels, one scale vector
+                 for the queries and one for the keys
+    'sliding': q, k <- rope(q), rope(k);  key j visible to query i iff
+                 i - window < j <= i
+    'full':    no rotary;  j <= i
+    o          = softmax(q k^T / sqrt(D) + mask) v   (a KV head serves
+                 H / G query heads)
+    x         <- x + RMSNorm((o * sigmoid(y W_gate)) W_o)    (W_gate: E->HD)
+    z          = RMSNorm(x)
+    f          = SwiGLU(z) in the leading dense layers; else
+                 s = sigmoid(z W_r) over all experts (float32),
+                 S = top-k of s,  w_e = scale * s_e / sum_S s,
+                 f = sum_{e in S, held here} w_e SwiGLU_e(z) + SwiGLU_sh(z)
+    x         <- x + RMSNorm(f)
+
+The new kinds, ``head_dim`` other than ``embed_dim / num_heads`` and
+``attn_gate`` RAISE with ``decode=True`` and under ``'ulysses'`` (a cache
+that knows a layer's kind is ROADMAP M4 (b)); under ``'ring'`` a
+``'sliding'`` layer passes its window to the ring.
+
 ``norm_eps`` is every RMSNorm's epsilon. Combinations that RAISE, each
 where it is first seen: ``mla`` with ``decode=True`` (caching the latent
 is serving's work and waits for a serving metric: ROADMAP M5), with
@@ -175,6 +213,12 @@ class TransformerConfig(NamedTuple):
                                       # and 'ring' / 'ulysses'
     conv_taps: int = 3            # a 'conv' layer's taps (conv_L_cache)
     qk_norm: bool = False         # RMSNorm on each head's q and k (GQA)
+    head_dim: int | None = None   # a GQA head's width; None: embed_dim /
+                                  # num_heads. Another raises with decode
+                                  # and 'ulysses'
+    attn_gate: bool = False       # sigmoid gate on the heads' output
+                                  # (raises with decode and 'ulysses')
+    embed_scale: float | None = None  # the embedding's output times this
 
 
 def _rotary(x, positions, theta=10000.0):
@@ -264,12 +308,58 @@ def _mla_attention(cfg, x, positions, segs):
                                name="out")(out[..., :m.v_dim])
 
 
+class AttentionKind(NamedTuple):
+    """What an attention kind of :data:`MIXER` does beside attending."""
+    windowed: bool            # takes ``cfg.window`` (None: full causal)
+    rotary: bool              # the rotary embedding on q and k
+    scope: str | None         # its mixer's named scope (None: none)
+
+
+# ``'attention'`` is the kind every configuration had before kinds existed
+# (``cfg.window``, which may be None, and rotary). ``'sliding'`` computes
+# what ``'attention'`` computes and differs in two things only: it raises
+# where the configuration has no window (a published windowed layer never
+# runs full causal unnoticed), and its own scope parts windowed time from
+# full time, where giving ``'attention'`` a scope would rename the older
+# configurations' ops. ``'full'`` takes neither window nor rotary (no
+# position encoding at all: what such a layer knows of order is the causal
+# mask).
+ATTENTION_KINDS = {
+    "attention": AttentionKind(windowed=True, rotary=True, scope=None),
+    "sliding": AttentionKind(windowed=True, rotary=True,
+                             scope="sliding_attn"),
+    "full": AttentionKind(windowed=False, rotary=False, scope="full_attn"),
+}
+
+
+def window_of(cfg: TransformerConfig, kind: str) -> int | None:
+    """The window an attention layer of ``kind`` attends through."""
+    return cfg.window if ATTENTION_KINDS[kind].windowed else None
+
+
+def head_width(cfg: TransformerConfig) -> int:
+    """The width of a head as the attention kernels see it."""
+    if cfg.mla is not None:  # the narrower of (q, k) and v is padded
+        return max(cfg.mla.nope_dim + cfg.mla.rope_dim, cfg.mla.v_dim)
+    return cfg.head_dim or cfg.embed_dim // cfg.num_heads
+
+
 class Attention(nn.Module):
     config: TransformerConfig
+    kind: str = "attention"       # a kind of ATTENTION_KINDS
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, kv_view=None):
         cfg = self.config
+        kind = ATTENTION_KINDS[self.kind]
+        window = window_of(cfg, self.kind)
+        decoupled = cfg.head_dim is not None \
+            and cfg.head_dim * cfg.num_heads != cfg.embed_dim
+        novel = [what for what, on in (
+            (f"a {self.kind!r} layer", self.kind != "attention"),
+            ("the output gate (attn_gate)", cfg.attn_gate),
+            (f"heads of head_dim={cfg.head_dim} beside embed_dim / "
+             f"num_heads", decoupled)) if on]
         if cfg.mla is not None:
             segs = {} if segment_ids is None else dict(
                 q_segment_ids=segment_ids, kv_segment_ids=segment_ids)
@@ -277,21 +367,41 @@ class Attention(nn.Module):
                 raise ValueError(
                     "qk_norm is the GQA path's: latent attention (mla=) "
                     "norms its query and key/value latents itself.")
+            if novel or cfg.head_dim is not None:
+                raise ValueError(
+                    "latent attention (mla=) is an attention kind of its "
+                    "own: layer kinds, head_dim and attn_gate are the GQA "
+                    "path's.")
             return _mla_attention(cfg, x, positions, segs)
-        if cfg.embed_dim % cfg.num_heads != 0:
+        if novel and cfg.decode:
+            raise ValueError(
+                f"decode=True does not run {', '.join(novel)}: a cache "
+                f"that knows a layer's kind (a windowed layer holding a "
+                f"window of pages, a full one all of them) and the decode "
+                f"branch's head width and gate are serving's work (ROADMAP "
+                f"M4 (b)).")
+        if novel and cfg.attention == "ulysses":
+            raise ValueError(
+                f"attention='ulysses' does not run {', '.join(novel)}: its "
+                f"all-to-all exchanges assume every layer's one attention "
+                f"path at embed_dim / num_heads (ROADMAP M4 (b)); use "
+                f"'local' or 'ring'.")
+        if self.kind == "sliding" and window is None:
+            raise ValueError("a 'sliding' layer (layer_types) needs the "
+                             "configuration's window.")
+        if cfg.head_dim is None and cfg.embed_dim % cfg.num_heads != 0:
             raise ValueError(
                 f"embed_dim ({cfg.embed_dim}) must be divisible by num_heads "
                 f"({cfg.num_heads}).")
-        h, d = cfg.num_heads, cfg.embed_dim // cfg.num_heads
+        h, d = cfg.num_heads, head_width(cfg)
         hkv = cfg.num_kv_heads or h
         if h % hkv != 0:
             raise ValueError(
                 f"num_heads ({h}) must be a multiple of num_kv_heads "
                 f"({hkv}) for grouped-query attention.")
-        if d % 2 != 0:
+        if kind.rotary and d % 2 != 0:
             raise ValueError(
-                f"head_dim ({d} = {cfg.embed_dim}/{cfg.num_heads}) must be "
-                f"even for rotary embeddings.")
+                f"head_dim ({d}) must be even for rotary embeddings.")
         dense = lambda name, heads: nn.DenseGeneral(
             (heads, d), axis=-1, dtype=cfg.dtype, use_bias=False, name=name)
         if kv_view is not None and not cfg.decode:
@@ -307,8 +417,9 @@ class Attention(nn.Module):
                 norm = lambda name: nn.RMSNorm(
                     epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
                 q, k = norm("q_norm")(q), norm("k_norm")(k)
-        q = _rotary(q, positions, cfg.rope_theta)
-        k = _rotary(k, positions, cfg.rope_theta)
+        if kind.rotary:
+            q = _rotary(q, positions, cfg.rope_theta)
+            k = _rotary(k, positions, cfg.rope_theta)
 
         import horovod_tpu as hvd
 
@@ -444,9 +555,9 @@ class Attention(nn.Module):
                            kc.astype(jnp.float32)) * (1.0 / d ** 0.5)
             kpos = jnp.arange(kc.shape[1])
             vis = kpos[None, None, :] <= ivec[:, :, None]  # (b, w, K)
-            if cfg.window is not None:
+            if window is not None:
                 vis = vis & (kpos[None, None, :] > ivec[:, :, None]
-                             - cfg.window)
+                             - window)
             s = jnp.where(vis[:, None, None, :, :], s, -1e30)
             p = jax.nn.softmax(s, axis=-1)
             out = jnp.einsum("bhgqk,bkhd->bqhgd", p,
@@ -455,7 +566,7 @@ class Attention(nn.Module):
         elif cfg.attention == "ring":
             out = hvd.ring_attention(q, k, v, group=cfg.sp_group,
                                      causal=True, layout=cfg.sp_layout,
-                                     window=cfg.window, **segs)
+                                     window=window, **segs)
         elif cfg.attention == "ulysses":
             if hkv != h:
                 # Ulysses all-to-alls the head axis against the sequence
@@ -464,7 +575,7 @@ class Attention(nn.Module):
                 # parameters; the ring strategy also saves wire traffic.)
                 k = jnp.repeat(k, h // hkv, axis=2)
                 v = jnp.repeat(v, h // hkv, axis=2)
-            if cfg.window is not None:
+            if window is not None:
                 raise ValueError(
                     "window is not supported with attention='ulysses'; "
                     "use 'local' or 'ring'.")
@@ -472,9 +583,13 @@ class Attention(nn.Module):
                                         causal=True, **segs)
         elif cfg.attention == "local":
             out = hvd.local_attention(q, k, v, causal=True,
-                                      window=cfg.window, **segs)
+                                      window=window, **segs)
         else:
             raise ValueError(f"Unknown attention strategy {cfg.attention!r}.")
+        if cfg.attn_gate:
+            # Every head's output times a sigmoid of the layer's input.
+            gate = jax.nn.sigmoid(dense("gate", h)(x).astype(jnp.float32))
+            out = (out.astype(jnp.float32) * gate).astype(cfg.dtype)
         return nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
                                use_bias=False, name="out")(out)
 
@@ -515,9 +630,17 @@ class ShortConv(nn.Module):
         return dense(cfg.embed_dim, "out_proj")(out)
 
 
-def _attention_mixer(cfg, y, positions, segment_ids, kv_view):
-    return Attention(cfg, name="attn")(y, positions, segment_ids,
-                                       kv_view=kv_view)
+def _attention_mixer(kind: str):
+    scope = ATTENTION_KINDS[kind].scope
+
+    def mixer(cfg, y, positions, segment_ids, kv_view):
+        attend = lambda: Attention(cfg, kind=kind, name="attn")(
+            y, positions, segment_ids, kv_view=kv_view)
+        if scope is None:
+            return attend()
+        with jax.named_scope(scope):
+            return attend()
+    return mixer
 
 
 def _conv_mixer(cfg, y, positions, segment_ids, kv_view):
@@ -526,8 +649,10 @@ def _conv_mixer(cfg, y, positions, segment_ids, kv_view):
 
 # The block's mixer slot: a kind -> (cfg, y, positions, segment_ids,
 # kv_view) -> y, building its module in the calling Block's scope under the
-# kind's own name (``attn``, ``conv``: the scopes the tracing reads).
-MIXER = {"attention": _attention_mixer, "conv": _conv_mixer}
+# kind's own name (``attn``, ``conv``: the scopes the tracing reads; an
+# attention kind's own scope, ``sliding_attn`` / ``full_attn``, holds it).
+MIXER = {**{kind: _attention_mixer(kind) for kind in ATTENTION_KINDS},
+         "conv": _conv_mixer}
 
 
 def mixer_of_layer(cfg: TransformerConfig, i: int) -> str:
@@ -737,16 +862,32 @@ class Transformer(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype,
                          embedding_init=nn.initializers.normal(0.02))
         x = embed(tokens)
+        if cfg.embed_scale is not None:
+            x = x * cfg.embed_scale
         # Into the record of the hvd.spmd program being traced, a step, a
-        # rank (core/timeline.py count_plan; dropped where none is).
-        applied = cfg.num_layers * cfg.recurrent_steps \
-            + (cfg.mtp is not None)  # the MTP module is one more block
-        convs = cfg.recurrent_steps * sum(
-            mixer_of_layer(cfg, i) == "conv" for i in range(cfg.num_layers))
+        # rank (core/timeline.py count_plan; dropped where none is). The
+        # mixer kind of every block application, the MTP module's last.
+        applied_kinds = [mixer_of_layer(cfg, i)
+                         for i in range(cfg.num_layers)] \
+            * cfg.recurrent_steps + ["attention"] * (cfg.mtp is not None)
+        attends = [k for k in applied_kinds if k in ATTENTION_KINDS]
+        windows = [window_of(cfg, k) for k in attends]
+        applied = len(applied_kinds)
+        convs = applied - len(attends)
         tl = _timeline.session()
         tl.count_plan("model.block_applications", applied)
-        tl.count_plan("model.attention_layers", applied - convs)
+        tl.count_plan("model.attention_layers", len(attends))
         tl.count_plan("model.conv_layers", convs)
+        # The attention block applications by what their kind does: with a
+        # window, full causal (rotary or not), rotary, gated.
+        tl.count_plan("model.windowed_attention_layers",
+                      sum(w is not None for w in windows))
+        tl.count_plan("model.full_attention_layers",
+                      sum(w is None for w in windows))
+        tl.count_plan("model.rotary_attention_layers",
+                      sum(ATTENTION_KINDS[k].rotary for k in attends))
+        tl.count_plan("model.gated_attention_layers",
+                      len(attends) if cfg.attn_gate else 0)
         # Of them, those whose gate-and-tap pass is the Pallas kernel pair
         # (ops/short_conv.py runs_kernels: a TPU, bfloat16, whole lanes).
         tl.count_plan("model.conv_kernel_layers", convs if convs and (
@@ -762,22 +903,23 @@ class Transformer(nn.Module):
         # Of the attention blocks, the ones whose backward reads the
         # kernel's output and log-sum-exp back and does not run it again.
         tl.count_plan("model.kept_attention_outputs",
-                      applied - convs if looped and kernel else 0)
+                      len(attends) if looped and kernel else 0)
         if kernel:
             # What the mask leaves visible of the kernels' scores and what
             # they compute (ops/flash_attention.score_counts, the kernels'
             # own classification): a forward and a backward call a block
-            # application, every batch row and head. Segment ids are data
+            # application, every batch row and head, each application at
+            # its own window and the heads' width. Segment ids are data
             # and not counted.
             t = _kernel_tokens(cfg, t_local)  # ulysses: g x the tokens,
             heads = cfg.num_heads * t_local // t  # a g-th of the heads
-            visible, computed = _flash.score_counts(
-                t, t, cfg.embed_dim // cfg.num_heads if cfg.mla is None
-                else max(cfg.mla.nope_dim + cfg.mla.rope_dim,
-                         cfg.mla.v_dim), window=cfg.window)
-            calls = (applied - convs) * tokens.shape[0] * heads
-            tl.count_plan("flash.scores_visible", calls * visible)
-            tl.count_plan("flash.scores_computed", calls * computed)
+            counts = {w: _flash.score_counts(t, t, head_width(cfg), window=w)
+                      for w in set(windows)}
+            calls = tokens.shape[0] * heads
+            tl.count_plan("flash.scores_visible",
+                          calls * sum(counts[w][0] for w in windows))
+            tl.count_plan("flash.scores_computed",
+                          calls * sum(counts[w][1] for w in windows))
         moe_layers = 0 if cfg.moe is None else max(
             cfg.num_layers - cfg.moe.dense_layers, 0) + (cfg.mtp is not None)
         # Of the expert layers, those whose backward reads back the

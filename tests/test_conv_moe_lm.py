@@ -516,4 +516,5 @@ def test_mixer_of_layer_reads_the_pattern():
     plain = CFG._replace(layer_types=None)
     assert {transformer.mixer_of_layer(plain, i) for i in range(4)} \
         == {"attention"}
-    assert sorted(transformer.MIXER) == ["attention", "conv"]
+    assert sorted(transformer.MIXER) == ["attention", "conv", "full",
+                                         "sliding"]

@@ -187,6 +187,11 @@ def test_exchange_wire_bytes_are_the_plan(compression, share):
         # ... all of them attention (no ``layer_types``: PR 33)
         "model.attention_layers": 2, "model.conv_layers": 0,
         "model.conv_kernel_layers": 0,
+        # ... by what their kind does: rotary, no window, no gate
+        "model.windowed_attention_layers": 0,
+        "model.full_attention_layers": 2,
+        "model.rotary_attention_layers": 2,
+        "model.gated_attention_layers": 0,
         # the build's own: no persistent cache here, so neither
         "build.cache_hits": 0, "build.cache_misses": 0}
 
@@ -244,6 +249,10 @@ def test_a_looped_steps_record_counts_the_kept_attention_outputs(
         "model.attention_layers": 6 - convs, "model.conv_layers": convs,
         # (a CPU step: the gate-and-tap pass is the plain form, PR 37)
         "model.conv_kernel_layers": 0,
+        "model.windowed_attention_layers": 0,
+        "model.full_attention_layers": 6 - convs,
+        "model.rotary_attention_layers": 6 - convs,
+        "model.gated_attention_layers": 0,
         "model.kept_attention_outputs": kept,
         "model.moe_kept_products": 0,
         "model.head_applications": 1}
