@@ -141,6 +141,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+import numpy as np
 import optax
 
 from horovod_tpu.core import state as _state
@@ -228,20 +229,64 @@ def _rotary(x, positions, theta=10000.0):
     (B, T) per-row positions — the paged decode path serves ragged
     requests whose current indices differ per batch slot. The (T,) case
     computes exactly what it always did; (B, T) broadcasts per row.
+    The head is rotated whole (:func:`_rotate`), never split in halves.
     """
-    d = x.shape[-1]
-    half = d // 2
+    half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., T, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    cos2 = jnp.concatenate([cos, cos], -1)[..., None, :]        # (..., T, 1, D)
+    sin2 = jnp.concatenate([sin, sin], -1)[..., None, :]
     if angles.ndim == 2:            # (T, half): shared positions
-        cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    else:                           # (B, T, half): per-row positions
-        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf1 * sin + xf2 * cos], axis=-1).astype(x.dtype)
+        cos2, sin2 = cos2[None], sin2[None]
+    return _rotate(x, cos2, sin2)
+
+
+def _rotate_by(x, cos2, sin2):
+    """``x·[cos|cos] + (x P)·[sin|sin]`` in float32, cast back to x's dtype.
+
+    P is the (D, D) signed permutation that maps ``[x1 | x2]`` to
+    ``[-x2 | x1]``, a product on the MXU in x's own dtype: each of its
+    outputs is one input times ±1, so it is exact, and op by op the sums
+    are those of the half-split form ``[x1 cos - x2 sin | x1 sin + x2
+    cos]``. No ``D/2``-wide half of a head is ever a value, which XLA
+    would keep in HBM at twice its bytes in 128-lane tiles and in passes
+    of its own between the q/k projection and the attention kernel
+    (``jnp.roll`` lowers to slices and a concatenate: the halves again).
+    """
+    d = x.shape[-1]
+    perm = np.zeros((d, d), np.float32)
+    perm[d // 2:, :d // 2] = -np.eye(d // 2, dtype=np.float32)  # -x2 first
+    perm[:d // 2, d // 2:] = np.eye(d // 2, dtype=np.float32)   # x1 second
+    swap = jnp.einsum(
+        "...d,de->...e", x, jnp.asarray(perm, x.dtype),
+        preferred_element_type=jnp.float32,
+        precision=(None if x.dtype == jnp.bfloat16
+                   else jax.lax.Precision.HIGHEST))
+    return (x.astype(jnp.float32) * cos2 + swap * sin2).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _rotate(x, cos2, sin2):
+    return _rotate_by(x, cos2, sin2)
+
+
+def _rotate_fwd(x, cos2, sin2):
+    return _rotate_by(x, cos2, sin2), (cos2, sin2)
+
+
+def _rotate_bwd(tables, g):
+    """The rotation's transpose is the rotation by the opposite angle
+    (``Pᵀ = -P``, and swapping the halves leaves ``[sin|sin]`` as it is),
+    so it is the same pass on the cotangent in its own dtype: one
+    rounding, as the half-split form's. Autodiff would put the float32
+    ``g·sin`` through the product instead, which the MXU rounds to
+    bfloat16, and add the two terms' bfloat16 casts in bfloat16."""
+    cos2, sin2 = tables
+    return _rotate_by(g, cos2, -sin2), None, None
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def _mla_attention(cfg, x, positions, segs):

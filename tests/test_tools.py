@@ -14,7 +14,7 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
 
-from tools import profile_resnet  # noqa: E402
+from tools import aot_step, profile_resnet  # noqa: E402
 
 
 class TestProfileResnet:
@@ -42,3 +42,23 @@ class TestProfileResnet:
         assert out.index("`convolution`") < out.index("`fusion`")
         assert "| 6.00 |" in out and "| 4.00 |" in out
         assert "60.0%" in out and "40.0%" in out
+
+
+class TestAotStep:
+    def test_half_head_fusions(self):
+        """Counts the top-level fusions that hold half a rotary head:
+        an output (..., T, H > 1, D/2), alone or in a tuple; not the
+        (T, 1, D/2) tables, another length, a whole head, or an
+        instruction that is no fusion."""
+        text = "\n".join([
+            "  %fusion.446 = (bf16[1,8192,32,64]{3,2,1,0:T(8,128)(2,1)}, "
+            "bf16[1,8192,32,64]{3,2,1,0}) fusion(%a), kind=kLoop",
+            "  %add_convert_fusion.5 = bf16[8192,4,64]{2,1,0} "
+            "fusion(%b, %c), kind=kLoop",
+            "  %fusion.9 = f32[1,8192,1,64]{3,2,1,0} fusion(%d)",
+            "  %fusion.10 = bf16[1,4096,32,64]{3,2,1,0} fusion(%e)",
+            "  %fusion.742 = bf16[1,32,8192,128]{3,2,1,0} fusion(%f)",
+            "  %copy.3 = bf16[1,8192,32,64]{3,2,1,0} copy(%g)",
+        ])
+        assert aot_step.half_head_fusions(text, 8192, 64) == 2
+        assert aot_step.half_head_fusions(text, 8192, 32) == 0

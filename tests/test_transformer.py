@@ -71,6 +71,60 @@ class TestTransformerModel:
         assert losses[-1] < losses[0]
 
 
+def _half_split_rotary(x, positions, theta=10000.0):
+    """Rotary by halves of the head: the formula the model's full-width
+    rotary has to reproduce."""
+    half = x.shape[-1] // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if angles.ndim == 2:
+        cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    else:
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    xf1 = x[..., :half].astype(jnp.float32)
+    xf2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([xf1 * cos - xf2 * sin, xf1 * sin + xf2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["T", "BT"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_rotary_full_width_matches_half_split(d, dtype, per_row):
+    """The full-width rotary (a signed permutation on the MXU) is the
+    half-split formula: bit for bit op by op, to rounding under a
+    compiler's fusion, its VJP too — at (T,) and (B, T) positions."""
+    kx, kg = jax.random.split(jax.random.PRNGKey(d))
+    x = jax.random.normal(kx, (2, 24, 3, d), jnp.float32).astype(dtype)
+    g = jax.random.normal(kg, x.shape, jnp.float32).astype(dtype)
+    pos = (jnp.stack([jnp.arange(24) + 3, jnp.arange(24) * 37 + 1000])
+           if per_row else jnp.arange(24) + 5)
+
+    def out_and_vjp(f):
+        y, pull = jax.vjp(lambda v: f(v, pos, 500000.0), x)
+        return y, pull(g)[0]
+
+    with jax.disable_jit():
+        eager = out_and_vjp(transformer._rotary)
+        oracle = out_and_vjp(_half_split_rotary)
+    for a, b in zip(eager, oracle):
+        assert a.dtype == b.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    # a compiler's fused multiply-adds may round the float32 sums
+    # otherwise (<= 5e-7 on unit inputs), and so the cast to bfloat16 by
+    # one step of its 8 bits
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -7
+    compiled = jax.jit(lambda: out_and_vjp(transformer._rotary))()
+    oracle = jax.jit(lambda: out_and_vjp(_half_split_rotary))()
+    for a, b in zip(compiled, oracle):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert (np.abs(a - b)
+                <= tol * np.abs(b) + 1e-6 * np.abs(b).max()).all()
+
+
 class TestSequenceParallelTransformer:
     @pytest.mark.parametrize("attention", ["ring", "ulysses"])
     def test_sp_forward_matches_local(self, world, attention):

@@ -8,7 +8,9 @@ runner's ``TransformerConfig`` at the cell's sizes — lowered on abstract
 arguments for a ``v5e:2x2`` topology's first chip(s) (``jax.experimental.
 topologies``: the installed libtpu compiles for a TPU from the CPU
 sandbox). One line a cell: the optimized text's lines, Pallas calls and
-``while`` loops, the compile's memory (arguments and temporaries) and an
+``while`` loops, the fusions that hold half a rotary head
+(``half_head_fusions``: an output of (..., T, H > 1, D/2), D the rotary
+width), the compile's memory (arguments and temporaries) and an
 md5 of the text less what names a checkout and not the program
 (``metadata=``, the location tables, the Pallas bodies) — two trees whose
 digests agree run the same instructions. ``--root`` takes the program and
@@ -137,7 +139,23 @@ def lower_cell(root: str, name: str):
     lowered = hvd.spmd(train_step, donate_argnums=(0, 1)).lower(
         params, state, tokens)
     hvd.shutdown()
-    return lowered
+    rotary = (mcfg.mla.rope_dim if mcfg.mla is not None
+              else mcfg.head_dim or mcfg.embed_dim // mcfg.num_heads)
+    return lowered, (traffic["seq_len"], rotary // 2)
+
+
+def half_head_fusions(text: str, seq_len: int, half: int) -> int:
+    """The top-level fusions one of whose outputs is a (..., T, H > 1,
+    half) tensor: half of a rotary head, held in HBM between passes."""
+    count = 0
+    for shape in re.findall(r"= (\S.*?) fusion\(", text):
+        for dims in re.findall(r"\w+\[([\d,]+)\]", shape):
+            d = [int(v) for v in dims.split(",")]
+            if len(d) >= 3 and d[-3] == seq_len and d[-2] > 1 \
+                    and d[-1] == half:
+                count += 1
+                break
+    return count
 
 
 def main():
@@ -153,7 +171,7 @@ def main():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     for name in args.cells:
         t0 = time.perf_counter()
-        lowered = lower_cell(root, name)
+        lowered, (seq_len, half) = lower_cell(root, name)
         if args.lowered:
             text = lowered.as_text()
             print(json.dumps({
@@ -171,6 +189,7 @@ def main():
             "md5": hashlib.md5(text.encode()).hexdigest(),
             "pallas_calls": text.count('custom_call_target="tpu_custom_call"'),
             "whiles": len(re.findall(r"= [^\n]* while\(", text)),
+            "half_head_fusions": half_head_fusions(text, seq_len, half),
             "argument_gb": gb(mem.argument_size_in_bytes),
             "temp_gb": gb(mem.temp_size_in_bytes)}), flush=True)
     if args.text:
